@@ -37,7 +37,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..nn.module import Module
 from ..nn.init import Xavier, init_tensor
-from ..parallel._compat import shard_map
 from ..observability.collectives import account_collective
 
 
@@ -275,13 +274,14 @@ class ShardedEmbeddingBag(Module):
             return _combine(emb, wts, segs, lb, combiner)
 
         if per_id_weights is None:
-            fn = shard_map(local, mesh,
-                           in_specs=(P(self.axis), P(self.axis)),
-                           out_specs=P(self.axis))
+            fn = jax.shard_map(local, mesh=mesh,
+                               in_specs=(P(self.axis), P(self.axis)),
+                               out_specs=P(self.axis), check_vma=False)
             return fn(w, ids)
-        fn = shard_map(local, mesh,
-                       in_specs=(P(self.axis), P(self.axis), P(self.axis)),
-                       out_specs=P(self.axis))
+        fn = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(self.axis), P(self.axis), P(self.axis)),
+            out_specs=P(self.axis), check_vma=False)
         return fn(w, ids, per_id_weights)
 
     def _apply_dedup(self, w, uniq_ids, inverse, mesh, n, rows):
@@ -325,9 +325,10 @@ class ShardedEmbeddingBag(Module):
             segs = jnp.repeat(jnp.arange(lb, dtype=jnp.int32), l)
             return _combine(emb, wts, segs, lb, combiner)
 
-        fn = shard_map(local, mesh,
-                       in_specs=(P(self.axis), P(self.axis), P(self.axis)),
-                       out_specs=P(self.axis))
+        fn = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(self.axis), P(self.axis), P(self.axis)),
+            out_specs=P(self.axis), check_vma=False)
         return fn(w, uniq_ids, inverse)
 
 

@@ -163,19 +163,19 @@ def plan_fleet(n_devices: int,
 
 
 def enable_shared_compile_cache(path: str) -> str:
-    """Point jax's persistent compilation cache at ``path`` (created if
-    missing) and cache every program, however fast it compiled — the
-    fleet's warm-start seam: a re-placed job's rebuild reuses the XLA
-    programs its previous placement (or any same-topology job) already
-    paid for, so a displacement costs a cache read, not a compile."""
+    """Turn on jax's persistent compilation cache for the fleet and
+    return the directory in use — the warm-start seam: a re-placed
+    job's rebuild reuses the XLA programs its previous placement (or
+    any same-topology job) already paid for, so a displacement costs a
+    cache read, not a compile.  ``path`` is where the cache goes unless
+    ``JAX_COMPILATION_CACHE_DIR`` places it from outside, which wins
+    (:func:`bigdl_tpu.utils.engine.enable_compile_cache`)."""
     import os
 
-    import jax
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    return path
+    from ..utils.engine import enable_compile_cache
+    used = enable_compile_cache(path)
+    os.makedirs(used, exist_ok=True)
+    return used
 
 
 class PoolExhaustedError(RuntimeError):

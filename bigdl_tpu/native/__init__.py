@@ -30,14 +30,15 @@ _lib_lock = threading.Lock()
 
 
 def build(force: bool = False) -> bool:
-    """Compile the native library in place. Returns True on success."""
+    """Compile the native library in place. Returns True on success.
+    ``force`` rebuilds from ``src/*.cpp`` even when a library is there."""
     if _LIB_OVERRIDE is not None:
         return os.path.exists(_LIB_PATH)
     if os.path.exists(_LIB_PATH) and not force:
         return True
     try:
-        subprocess.run(["make", "-C", _HERE], check=True,
-                       capture_output=True, timeout=120)
+        subprocess.run(["make", "-C", _HERE] + (["-B"] if force else []),
+                       check=True, capture_output=True, timeout=120)
         return os.path.exists(_LIB_PATH)
     except (subprocess.SubprocessError, FileNotFoundError):
         return False

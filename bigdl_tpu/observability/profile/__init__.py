@@ -6,7 +6,8 @@ what fraction of the hardware a step uses and where each serving
 request's latency went:
 
   * :mod:`specs` — device peak table (TPU v2–v5p, A100/H100/V100;
-    env-overridable) replacing the scripts' magic ``197e12``.
+    env-overridable): the one source of peaks; ``require_chip()`` is
+    the measured path's gate (a TPU with known peaks, or an error).
   * :mod:`capture` — XLA ``cost_analysis``/``memory_analysis`` harvest
     from compiled executables, the :class:`StepCostModel` deriving
     per-step ``perf/mfu`` / ``perf/hbm_bw_util`` /

@@ -1,10 +1,9 @@
 """Device peak-spec table: the denominators of every efficiency number.
 
 MFU, HBM-bandwidth utilization and "how close to the memory wall" all
-divide a measured quantity by a *hardware peak*.  The perf scripts used
-to hardcode one magic constant (``197e12`` — TPU v5e bf16) and silently
-report nonsense on any other backend; this table is the single source
-of truth, resolved from ``jax.local_devices()[0].device_kind`` and
+divide a measured quantity by a *hardware peak*.  This table is the
+single source of those peaks (no script or benchmark carries its own
+constant), resolved from ``jax.local_devices()[0].device_kind`` and
 overridable per run via environment variables:
 
   ``BIGDL_PEAK_FLOPS``            peak dense FLOP/s (the MFU denominator)
@@ -21,7 +20,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, replace
-from typing import Optional
+from typing import Dict, Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -98,6 +97,28 @@ def device_spec(device=None) -> DeviceSpec:
     except Exception:
         pass
     return _apply_env(lookup(kind))
+
+
+def require_chip() -> Tuple[Dict[str, object], DeviceSpec]:
+    """The gate of the measured path (``bench.py``, ``chip_smoke.py``):
+    the device as jax reports it — ``{"platform", "kind", "count"}`` —
+    and its spec, or a ``RuntimeError``.  Unlike :func:`device_spec`
+    this raises: a measurement never falls back to the CPU and never
+    divides by a peak the table does not know."""
+    import jax
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"no TPU: jax's default backend is {jax.default_backend()!r}; "
+            "this runs on the chip (through the chip tool) or not at all")
+    devices = jax.devices()
+    info = {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+    spec = device_spec(devices[0])
+    if not spec.complete():
+        raise RuntimeError(
+            f"no peaks for device kind {info['kind']!r} in "
+            f"observability/profile/specs.py ({spec})")
+    return info, spec
 
 
 def peak_flops(default: Optional[float] = None) -> Optional[float]:

@@ -1,10 +1,10 @@
-"""Proxy-regression sentinel over the goodput/bench trajectory.
+"""Regression sentinel over the goodput ledger (and driver round files).
 
-The ROADMAP's standing constraint — the hardware bench backend has been
-unreachable since BENCH_r02 — makes the CPU proxies (smoke scripts,
-and now the goodput ledger) the ONLY performance signal this repo has.
-A proxy trajectory nobody checks rots silently; this module is the
-check, run by ``scripts/goodput_smoke.py`` in CI.
+Counts and conservation properties that a CPU smoke can state exactly —
+the goodput ledger's bucket fractions, bit-parity flags — rot silently
+if nobody checks them; this module is the check, run by
+``scripts/goodput_smoke.py`` in CI.  It guards no device number: those
+live in ``PERF_LEDGER.jsonl`` once ROADMAP S1 lands (ROADMAP D2).
 
 Discipline mirrors the graftlint baseline: a proxy metric may only
 regress past its committed bound when the baseline entry carries a

@@ -1,14 +1,17 @@
-"""Hand-tuned TPU kernels (Pallas) and their XLA fallbacks.
+"""Hand-tuned TPU kernels (Pallas) and their blockwise-XLA routes.
 
 The reference gets its hot-loop speed from Intel MKL primitives
 (spark/dl ... tensor/TensorNumeric + the mkl native wrappers); on TPU the
 equivalent role is played by Pallas kernels feeding the MXU, with pure-XLA
-blockwise fallbacks so every op also runs (and is differentiable) on CPU.
+blockwise routes so every op also runs (and is differentiable) on CPU;
+``attention_path`` says which one a shape takes.
 """
 # keep a non-shadowed module alias: the next line rebinds the package
 # attribute `flash_attention` to the *function*, so consumers that need
-# module internals (_Config, _pallas_ok, _INTERPRET) import this alias
+# module internals (_Config, _INTERPRET) import this alias
 from . import flash_attention as flash_attention_mod  # noqa: F401
-from .flash_attention import flash_attention, attention_reference
+from .flash_attention import (flash_attention, attention_reference,
+                              attention_path)
 
-__all__ = ["flash_attention", "attention_reference", "flash_attention_mod"]
+__all__ = ["flash_attention", "attention_reference", "attention_path",
+           "flash_attention_mod"]

@@ -1,4 +1,4 @@
-"""Flash attention: Pallas TPU kernel + blockwise-XLA fallback.
+"""Flash attention: Pallas TPU kernel + blockwise-XLA route.
 
 The reference framework has no fused attention (its RNN era predates it);
 this kernel is the core primitive of our long-context flagship
@@ -8,13 +8,14 @@ Design:
   * forward — Pallas kernel on TPU: grid over (batch*heads, q blocks),
     online-softmax ``fori_loop`` over key blocks held in VMEM; scores and
     accumulators in fp32 on the MXU, inputs may be bf16.
-  * forward fallback — same blockwise math as a ``lax.scan`` over key
-    blocks (O(seq * block) memory); used on CPU and for shapes the kernel
-    does not tile.
-  * backward — blockwise ``lax.scan`` recomputation from the saved
-    (q, k, v, out, lse) residuals: flash-style O(seq * block) memory, no
-    materialised (seq, seq) attention matrix; XLA fuses the elementwise
-    neighbourhood of each block matmul.
+  * backward — two Pallas kernels (dk/dv over q blocks, dq over kv
+    blocks) recomputing p from the saved (q, k, v, out, lse) residuals:
+    flash-style O(seq * block) memory, no materialised (seq, seq) matrix.
+  * blockwise route — the same math as a ``lax.scan`` over key blocks,
+    forward and backward; what runs on CPU and for shapes the kernel
+    does not tile.  :func:`attention_path` is the ONE place that decides
+    which route a call takes and says why, so a benchmark (and
+    ``chip_smoke.py``) can assert the kernel is what ran.
 
 Both paths share masking logic: a key is attended iff
 ``k_pos < kv_len  and  (not causal or q_pos >= k_pos)`` where the position
@@ -24,27 +25,33 @@ positions for its rotating key/value chunks.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+import warnings
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-
-try:  # Pallas is TPU-only at runtime; import lazily-guarded for CPU tests
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 # Finite "minus infinity": keeps exp()/max() NaN-free for fully-masked rows
 # (the same trick as jax.nn and the original flash kernels).
 DEFAULT_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 # Test hook: when True, Pallas kernels run in interpret mode so the TPU
-# code path itself (not the XLA fallback) is exercised on CPU.
+# code path itself (not the blockwise route) is exercised on CPU.
 _INTERPRET = False
+
+# VMEM the resident operands may take.  The forward and dq kernels keep
+# the whole K and V of one head resident (the dkv kernel: Q and dO),
+# double-buffered by the Mosaic pipeline, under a 16 MiB scoped-VMEM
+# limit on a TPU v5e; past it the compile fails ("exceeded scoped vmem
+# limit").  15 MiB leaves room for the blocked operands and the kernel's
+# temporaries: measured on the chip at head_dim 128 (NOTES.md "Bring-up
+# on the chip"), all three kernels compile and match the blockwise route
+# at seq 15360 in bf16 and 7680 in f32, and the forward — the tightest
+# of the three — stops compiling at 15872 / 8064.
+_VMEM_RESIDENT_BYTES = 15 * 2 ** 20
 
 
 class _Config(NamedTuple):
@@ -411,14 +418,49 @@ def _bwd_pallas(q, k, v, out, lse, do, cfg: _Config):
             dv.reshape(b, h, sk, d))
 
 
+def attention_path(q_shape, k_shape, dtype, *, block_q: int = 128,
+                   block_k: int = 128, use_pallas: bool = True,
+                   backend: Optional[str] = None) -> Tuple[str, str]:
+    """Which route ``flash_attention`` takes for these (B, H, S, D)
+    shapes, and why: ``("pallas", reason)`` or ``("blockwise", reason)``.
+    Forward and backward always take the same route.  ``backend``
+    defaults to ``jax.default_backend()``."""
+    if not use_pallas:
+        return "blockwise", "use_pallas=False"
+    if backend is None:
+        backend = jax.default_backend()
+    if backend != "tpu" and not _INTERPRET:
+        return "blockwise", f"backend {backend!r} is not tpu"
+    sq, d = int(q_shape[2]), int(q_shape[3])
+    sk = int(k_shape[2])
+    if d % 128:
+        return "blockwise", (f"head_dim {d} is not a multiple of 128 "
+                             "(one lane tile)")
+    if sq % block_q or sk % block_k:
+        return "blockwise", (f"seq_q {sq} / seq_k {sk} not multiples of "
+                             f"block_q {block_q} / block_k {block_k}")
+    # the resident pair (K, V in fwd/dq; Q, dO in dkv), double-buffered
+    need = 4 * d * jnp.dtype(dtype).itemsize * max(sq, sk)
+    if need > _VMEM_RESIDENT_BYTES:
+        return "blockwise", (
+            f"seq {max(sq, sk)} keeps {need / 2 ** 20:.1f} MiB resident "
+            f"in VMEM, over the {_VMEM_RESIDENT_BYTES >> 20} MiB the "
+            "kernel compiles under")
+    return "pallas", ("interpret mode" if backend != "tpu"
+                      else "tpu backend, shape tiles")
+
+
 def _pallas_ok(q, k, cfg: _Config) -> bool:
-    if not (cfg.use_pallas and _HAS_PALLAS):
-        return False
-    sq, d = q.shape[2], q.shape[3]
-    sk = k.shape[2]
-    return (sq % cfg.block_q == 0 and sk % cfg.block_k == 0
-            and d % 128 == 0
-            and (jax.default_backend() == "tpu" or _INTERPRET))
+    path, why = attention_path(q.shape, k.shape, q.dtype,
+                               block_q=cfg.block_q, block_k=cfg.block_k,
+                               use_pallas=cfg.use_pallas)
+    if (path == "blockwise" and cfg.use_pallas
+            and jax.default_backend() == "tpu"):
+        # on the chip the scan route is never the intended one: say so
+        # (once per call site — Python's default warning filter)
+        warnings.warn(f"flash_attention takes the blockwise scan, not "
+                      f"the Pallas kernel: {why}", stacklevel=2)
+    return path == "pallas"
 
 
 # --------------------------------------------------------------------- #
@@ -487,8 +529,9 @@ def flash_attention(q, k, v, causal: bool = False,
                     use_pallas: bool = True):
     """Fused attention. q, k, v: (batch, heads, seq, head_dim).
 
-    Pallas kernel on TPU (falls back to a blockwise lax.scan elsewhere);
-    memory-efficient blockwise backward either way.
+    Pallas kernels on TPU, forward and backward; the blockwise lax.scan
+    elsewhere and for shapes the kernel does not take —
+    :func:`attention_path` says which and why.
     """
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])

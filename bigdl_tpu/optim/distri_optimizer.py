@@ -36,7 +36,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..parallel._compat import shard_map
 from ..nn.module import Ctx
 from ..parallel import mesh as mesh_lib
 from ..parallel.allreduce import (allreduce_gradients,
@@ -205,7 +204,8 @@ class DistriOptimizer(Optimizer):
             specs_in = (P(), P(), P(), P("dp"), P("dp"), P())
             specs_out = (P(), P(), P(), P()) + ((P(),) if telemetry else ())
             return jax.jit(
-                shard_map(step, self.mesh, specs_in, specs_out),
+                jax.shard_map(step, mesh=self.mesh, in_specs=specs_in,
+                              out_specs=specs_out, check_vma=False),
                 donate_argnums=(0, 1, 2)), None
 
         # ---- FSDP: params sharded on dim 0 where divisible -------------- #
@@ -237,7 +237,8 @@ class DistriOptimizer(Optimizer):
         specs_out = (p_specs, o_specs, P(), P()) \
             + ((P(),) if telemetry else ())
         return jax.jit(
-            shard_map(step, self.mesh, specs_in, specs_out),
+            jax.shard_map(step, mesh=self.mesh, in_specs=specs_in,
+                          out_specs=specs_out, check_vma=False),
             donate_argnums=(0, 1, 2)), shardable
 
     # ---- ZeRO-1: replicated params, sharded update + optimizer state -- #
@@ -285,7 +286,8 @@ class DistriOptimizer(Optimizer):
         specs_out = (P(), o_specs, P(), P()) \
             + ((P(),) if telemetry else ())
         return jax.jit(
-            shard_map(step, self.mesh, specs_in, specs_out),
+            jax.shard_map(step, mesh=self.mesh, in_specs=specs_in,
+                          out_specs=specs_out, check_vma=False),
             donate_argnums=(0, 1, 2)), None
 
     def _shard_params_host(self, params, shardable):

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..observability import collectives as _acct
-from ._compat import axis_size
 
 log = logging.getLogger(__name__)
 
@@ -65,7 +64,7 @@ def _axis_size_or_none(axis_name):
     """Static axis size when called under shard_map/pmap tracing; None
     outside a binding context (pure-function unit tests)."""
     try:
-        return axis_size(axis_name)
+        return lax.axis_size(axis_name)
     except Exception:
         return None
 
@@ -129,7 +128,7 @@ def reduce_scatter_gradients(grads, axis_name: str = "dp", mean: bool = True,
 
     Trace-time accounting: scattered leaves ride a reduce-scatter
     (S*(n-1)/n wire bytes), unscattered ones a full all-reduce."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if group is None and isinstance(axis_name, str):
         group = axis_name
     rs_bytes, ar_bytes = [0], [0]

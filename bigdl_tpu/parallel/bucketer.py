@@ -33,7 +33,6 @@ import numpy as np
 from jax import lax
 
 from ..observability import collectives as _acct
-from ._compat import axis_size
 
 _CAST = {"fp16": jnp.float16, "float16": jnp.float16,
          "bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16}
@@ -117,7 +116,7 @@ class GradBucketer:
         attributes the volume to its parallelism group's
         ``comm/group.<axis>.*`` family — on a composed mesh each axis
         runs its own bucket stream, accounted separately."""
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         if group is None and isinstance(axis_name, str):
             group = axis_name
         cast_to = _CAST.get(compress)

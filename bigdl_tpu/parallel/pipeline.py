@@ -27,7 +27,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import axis_size, shard_map as _shard_map
 
 
 def pipeline_run(stage_fn: Callable, stage_params, microbatches,
@@ -41,7 +40,7 @@ def pipeline_run(stage_fn: Callable, stage_params, microbatches,
     Returns (M, mb, ...) outputs, valid on the *last* stage (zeros
     elsewhere); weight per-stage reductions with :func:`last_stage_mask`.
     """
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     n_micro = microbatches.shape[0]
     mb_shape = microbatches.shape[1:]
@@ -75,7 +74,7 @@ def last_stage_mask(axis_name: str = "pp"):
     """1.0 on the last pp rank, 0.0 elsewhere — multiply the loss by this
     and psum over pp so earlier stages contribute zero."""
     idx = lax.axis_index(axis_name)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return (idx == n - 1).astype(jnp.float32)
 
 
@@ -100,7 +99,9 @@ def pipelined(stage_fn: Callable, mesh: Mesh, n_microbatches: int,
 
         in_specs = (jax.tree_util.tree_map(lambda _: P(axis_name),
                                            stacked_params), P())
-        return _shard_map(local, mesh, in_specs, P())(stacked_params, x)
+        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(),
+                             check_vma=False)(stacked_params, x)
 
     return global_fn
 
@@ -561,9 +562,10 @@ class PipelineLMTrainer:
         # stage's matmuls over tp (megatron layout from the template
         # pspecs) and the sequence dim over sp, inserting the collectives
         # — pp x tp / pp x sp composition without hand-written psums
-        manual = None
+        manual_kw = {}
         if self._has_tp() or has_sp:
-            manual = {"pp"} | ({"dp"} if has_dp else set())
+            manual_kw["axis_names"] = frozenset(
+                {"pp"} | ({"dp"} if has_dp else set()))
 
         if zero1:
             # the whole step — fwd/bwd, dp scatter, 1/dp-sharded update,
@@ -593,11 +595,11 @@ class PipelineLMTrainer:
                          self._o_specs["rest"], self._o_specs["blocks"])
             if telemetry:
                 out_specs += (P(),)
-            mapped = _shard_map(
-                local, mesh,
-                (rest_specs, blk_specs, self._o_specs["rest"],
-                 self._o_specs["blocks"], tok_spec, tok_spec),
-                out_specs, manual_axes=manual)
+            mapped = jax.shard_map(
+                local, mesh=mesh,
+                in_specs=(rest_specs, blk_specs, self._o_specs["rest"],
+                          self._o_specs["blocks"], tok_spec, tok_spec),
+                out_specs=out_specs, check_vma=False, **manual_kw)
 
             def step(params, opt_state, tokens, targets):
                 out = mapped(params["rest"], params["blocks"],
@@ -626,10 +628,10 @@ class PipelineLMTrainer:
             out_specs = (P(), (rest_specs, blk_specs))
             if telemetry:
                 out_specs += (P(),)
-            mapped = _shard_map(
-                local, mesh,
-                (rest_specs, blk_specs, tok_spec, tok_spec),
-                out_specs, manual_axes=manual)
+            mapped = jax.shard_map(
+                local, mesh=mesh,
+                in_specs=(rest_specs, blk_specs, tok_spec, tok_spec),
+                out_specs=out_specs, check_vma=False, **manual_kw)
 
             def step(params, opt_state, tokens, targets):
                 out = mapped(params["rest"], params["blocks"], tokens,

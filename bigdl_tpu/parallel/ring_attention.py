@@ -30,7 +30,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import (chunk_merge, chunk_merge_blockwise,
                                    finalize, DEFAULT_MASK_VALUE)
-from ._compat import axis_size, shard_map as _shard_map
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,
@@ -46,7 +45,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     """
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, s_local, d = q.shape
     s_total = sp * s_local
@@ -112,4 +111,5 @@ def ring_attention_shmap(q, k, v, mesh: Mesh, causal: bool = False,
     spec = P(ax(batch_axis), ax(head_axis), ax(seq_axis), None)
     fn = partial(ring_attention, axis_name=seq_axis, causal=causal,
                  sm_scale=sm_scale, block_k=block_k)
-    return _shard_map(fn, mesh, (spec, spec, spec), spec)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -11,12 +11,19 @@ the complementary *compiler-partitioned* path — the idiomatic TPU recipe:
   3. jit the whole train step and let the XLA partitioner insert the
      collectives (all-gather for fsdp params, psum after row-parallel
      matmuls, reduce-scatter in the backward)
-  4. the one manual island: ring attention over 'sp' via shard_map
-     (parallel/ring_attention.py), wired into MultiHeadAttention.
+  4. the one manual island: attention, via shard_map, wired into
+     MultiHeadAttention — the ring over 'sp' (parallel/ring_attention.py)
+     or, without one, the flash kernel over the batch and head axes (a
+     Mosaic custom call is opaque to the partitioner, which would
+     otherwise gather Q/K/V whole onto every chip).
 
-Optimizer state sharding is *propagated*, not spelled out: ``init_state``
-is jitted with sharded params, so every moment tensor inherits its
-parameter's sharding.
+Optimizer state sharding is *propagated*, not spelled out: every moment
+tensor takes its parameter's sharding from the step that updates it.
+(Measured on the chip, PR 21: ``jit(init_state)``'s own outputs do not
+depend on the params' data and come back uncommitted on one device, so
+the layout only holds from the first step's outputs on — and that change
+of sharding type makes the second ``step()`` compile again.  Not fixed;
+NOTES.md "Bring-up on the chip".)
 """
 from __future__ import annotations
 
@@ -33,6 +40,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import mesh as mesh_lib
 from .ring_attention import ring_attention_shmap
 from ..models.transformer import TransformerLM
+from ..ops.flash_attention import flash_attention
 from ..observability import collectives as _acct
 from ..observability import (DivergenceError, Recorder, null_recorder,
                              set_recorder)
@@ -70,6 +78,30 @@ def _add_axis(spec: P, shape, mesh: Mesh, axis: str,
 def _add_fsdp(spec: P, shape, mesh: Mesh, min_size: int = 2 ** 16) -> P:
     """Layer 'fsdp' onto the first free, divisible dim of a large param."""
     return _add_axis(spec, shape, mesh, "fsdp", min_size)
+
+
+def flash_attention_shmap(q, k, v, mesh: Mesh, causal: bool = True):
+    """``flash_attention`` on (B, H, S, D) global arrays as a manual
+    island: batch over the mesh's ``dp``/``fsdp`` axes, heads over
+    ``tp``, sequence whole.  Batch rows and heads are embarrassingly
+    parallel, so each device runs the kernel on its own block and no
+    collective is needed — which GSPMD cannot work out by itself for a
+    Pallas call (it has no partitioning rule, so operands would be
+    replicated).  shard_map needs even blocks: a batch or head count the
+    axes do not divide (a grad-accum microbatch smaller than dp) takes
+    the plain call and the partitioner's own plan."""
+    batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
+    heads = "tp" if "tp" in mesh.axis_names else None
+    n_batch = int(np.prod([mesh.shape[a] for a in batch]))
+    if q.shape[0] % n_batch or q.shape[1] % mesh.shape.get("tp", 1):
+        return flash_attention(q, k, v, causal=causal)
+    spec = P(batch or None, heads, None, None)
+    return jax.shard_map(partial(flash_attention, causal=causal),
+                         mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+_MESH_ATTENTION = (ring_attention_shmap, flash_attention_shmap)
 
 
 class SpmdTrainer:
@@ -205,26 +237,37 @@ class SpmdTrainer:
 
     # ------------------------------------------------------------------ #
     def attach(self):
-        """Wire the sp ring into the model's attention modules (rebinding
-        any hook a previous trainer left), remembering the old hooks so
-        :meth:`detach` can restore standalone/other-mesh use of the model."""
-        if not self.ring:
-            return self
-        fn = partial(ring_attention_shmap, mesh=self.mesh, causal=True)
+        """Wire this mesh's attention island into the model's attention
+        modules (rebinding any hook a previous trainer left), remembering
+        the old hooks so :meth:`detach` can restore standalone/other-mesh
+        use of the model.  With an sp ring that is the ring; otherwise,
+        on more than one device, the flash kernel sharded over batch and
+        heads.  One device needs no island — only another trainer's
+        taken back out."""
+        fn = None
+        if self.ring:
+            fn = partial(ring_attention_shmap, mesh=self.mesh, causal=True)
+        elif self.mesh.devices.size > 1:
+            fn = partial(flash_attention_shmap, mesh=self.mesh)
         for blk in self.model.blocks:
             cur = blk.attn.attention_fn
+            foreign = isinstance(cur, partial) \
+                and cur.func in _MESH_ATTENTION
             # stash the model's TRUE original on the module itself; never
-            # stash another trainer's ring hook (interleaved trainers would
-            # otherwise "restore" a foreign mesh's ring fn on detach)
-            if not (isinstance(cur, partial)
-                    and cur.func is ring_attention_shmap):
+            # stash another trainer's mesh hook (interleaved trainers would
+            # otherwise "restore" a foreign mesh's island on detach)
+            if not foreign:
                 blk.attn._pre_ring_attention_fn = cur
-            blk.attn.attention_fn = fn
-        self._attached = True
+            if fn is not None:
+                blk.attn.attention_fn = fn
+            elif foreign:
+                blk.attn.attention_fn = blk.attn._pre_ring_attention_fn
+        self._attached = fn is not None
         return self
 
     def detach(self):
-        """Restore the model's original attention hooks (pre any ring)."""
+        """Restore the model's original attention hooks (pre any mesh
+        island)."""
         if getattr(self, "_attached", False):
             for blk in self.model.blocks:
                 if hasattr(blk.attn, "_pre_ring_attention_fn"):

@@ -32,10 +32,11 @@ decodes at **slot** granularity instead:
 
 Slot membership changes every step, shapes never do: dead slots ride
 along as masked rows (page-table ``-1`` = gather zeros / scatter
-drops), so join/leave churn is data, not a recompile.  Measured decode
+drops), so join/leave churn is data, not a recompile.  Decode
 throughput scales with slot occupancy, not with the slowest request in
-a static batch — ``scripts/decode_smoke.py`` pins the ≥ 1.5× CPU-proxy
-win (BENCH_r09) and zero post-warmup recompiles under churn.
+a static batch — ``scripts/decode_smoke.py`` pins the ordering (≥ 1.5×
+as a CPU timing) and zero post-warmup recompiles under churn; device
+numbers are ``chip_smoke.py``'s serve stage and ROADMAP S1/S3.
 
 Per-token SLO accounting (families in docs/observability.md):
 ``decode/ttft_ms`` (submit → first token) and ``decode/intertoken_ms``
@@ -502,9 +503,9 @@ class DecodeEngine:
 
     def _compile(self, kind: str, bucket: Optional[int]):
         """AOT jit → lower → compile (at avals, so no buffers move and
-        nothing is donated at build time); falls back to the plain
-        jitted callable on backends without the AOT API — the program
-        cache still keeps the recompile counter exact."""
+        nothing is donated at build time).  A trace or compile failure
+        propagates: warmup must not report success over a program that
+        does not build."""
         model, kv = self.model, self.kv
         base_key = self._base_key
         if kind == "decode":
@@ -562,17 +563,8 @@ class DecodeEngine:
                     jax.ShapeDtypeStruct((n_pages,), jnp.int32),
                     jax.ShapeDtypeStruct((), jnp.float32),
                     jax.ShapeDtypeStruct((), jnp.int32))
-        jitted = jax.jit(fn, donate_argnums=(1,))
         with self.recorder.span("decode.compile"):
-            try:
-                prog = jitted.lower(*args).compile()
-            except (AttributeError, NotImplementedError):
-                # no AOT lower/compile on this backend/jax: the jitted
-                # wrapper still serves and the program cache keeps the
-                # recompile counter exact.  Genuine trace failures
-                # propagate — warmup must not report success over a
-                # broken model
-                prog = jitted
+            prog = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
         if not self._warmed:
             self.recorder.inc("decode/warmup_compiles")
         return prog

@@ -443,9 +443,9 @@ class ServingEngine:
     def _compile(self, entry: ModelEntry, bucket: int, feature_shape,
                  warm: bool = False):
         """AOT-compile ``entry``'s eval fn at ``(bucket, *feature_shape)``
-        and cache the executable.  Falls back to a per-bucket ``jax.jit``
-        wrapper on backends without the lower/compile AOT API (the
-        bucket cache still makes our recompile counter exact)."""
+        and cache the executable.  A trace or compile failure
+        propagates: warmup reporting success over a model that does not
+        build would make the zero-recompile contract vacuous."""
         model = entry.model
 
         def fn(params, state, xx):
@@ -455,18 +455,9 @@ class ServingEngine:
         snap = entry.snapshot
         dummy = jnp.asarray(np.zeros((bucket,) + tuple(feature_shape),
                                      entry.dtype))
-        jitted = jax.jit(fn)
         with self.recorder.span("serving.compile"):
-            try:
-                ex = jitted.lower(snap.params, snap.state, dummy).compile()
-            except (AttributeError, NotImplementedError):
-                # jax version/backend without the AOT lower/compile API:
-                # the jitted wrapper still serves, and the bucket-keyed
-                # cache keeps the recompile counter exact.  Genuine
-                # trace/compile FAILURES must propagate — warmup
-                # reporting success over a broken model would make the
-                # zero-recompile contract vacuous
-                ex = jitted
+            ex = jax.jit(fn).lower(snap.params, snap.state,
+                                   dummy).compile()
         entry.compiled[bucket] = ex
         self._capture_bucket_cost(entry, bucket, ex)
         if entry.input_shape is None:
@@ -477,16 +468,12 @@ class ServingEngine:
 
     def _capture_bucket_cost(self, entry: ModelEntry, bucket: int, ex):
         """Harvest XLA cost/memory analysis from a freshly compiled
-        bucket executable (AOT path only — the jit fallback exposes no
-        analysis) into ``entry.cost[bucket]`` plus one ``profile``
+        bucket executable into ``entry.cost[bucket]`` plus one ``profile``
         record, so per-bucket compute cost is attributable next to the
         batch-fill metrics.  Best-effort: never raises."""
         from ..observability import profile as _profile
         if not _profile.capture_enabled():
             return
-        if not (hasattr(ex, "cost_analysis")
-                or hasattr(ex, "memory_analysis")):
-            return              # jit-fallback wrapper, nothing to read
         try:
             cost = _profile.capture_compiled(ex)
         except Exception:

@@ -299,7 +299,7 @@ def _mk_bn1d(a):
 
 
 # --------------------------------------------------------------------- #
-# recurrent modules — one-way READ transform (VERDICT r3 item 3).        #
+# recurrent modules — one-way READ transform.                            #
 # nn/Recurrent.scala:604 serializes topology/preTopology as module       #
 # attrs; cells go through Cell.scala:242 CellSerializer (ctor attrs +    #
 # the internal Linear-graph under the "cell" attr + flat parameters).    #

@@ -50,6 +50,32 @@ def is_initialized() -> bool:
     return _initialized
 
 
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache(default_dir: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache and return its
+    directory — the one place in the repo that may name one.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
+    this sets NO directory in code (whoever runs the program places the
+    cache).  Otherwise the cache goes to ``default_dir`` or, failing
+    that, ``<checkout>/.jax_cache``, as a normalised absolute path: the
+    path is part of every cache key, so two spellings of one directory
+    never hit each other.  Every program is cached, however fast it
+    compiled."""
+    path = os.environ.get(_CACHE_ENV)
+    if not path:
+        path = os.path.abspath(default_dir
+                               or os.path.join(_CHECKOUT, ".jax_cache"))
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
+
+
 def core_number() -> int:
     """≙ Engine.coreNumber (host cores for data workers)."""
     return _core_number

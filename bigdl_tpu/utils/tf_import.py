@@ -369,8 +369,8 @@ _OP_IMPLS = {
     "FusedBatchNorm": _fused_bn,
     "FusedBatchNormV2": _fused_bn,
     "FusedBatchNormV3": _fused_bn,
-    # -- breadth for real exported GraphDefs (VERDICT r2 item 5;
-    #    ≙ utils/tf/loaders/ 159 op loaders) ------------------------------ #
+    # -- breadth for real exported GraphDefs
+    #    (≙ utils/tf/loaders/ 159 op loaders) ----------------------------- #
     "Fill": lambda a, at: jnp.full(
         tuple(int(d) for d in np.asarray(a[0])), a[1]),
     "Pack": lambda a, at: jnp.stack(a, axis=int(at.get("axis") or 0)),

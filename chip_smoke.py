@@ -1,0 +1,601 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, one command, run through the chip tool:
+
+    chiprun -- python chip_smoke.py              # one chip
+    chiprun --chips 4 -- python chip_smoke.py    # adds the four-chip stage
+
+It drives the main path once through the entry points a user calls, at the
+full width of models the repo ships (depth cut, random weights from a seed),
+checks what comes out by the repo's own means, and exits non-zero on the
+first stage that fails.  With no TPU it fails at the first stage and prints
+no result line; there is no CPU route (debug on the CPU with
+``tests/test_chip_contract.py::test_chip_smoke_stages_tiny_on_cpu``, which
+runs these same stage functions at toy sizes in interpret mode).
+
+Stages, on however many chips the process sees:
+
+  device       TPU backend, complete peak spec, native runtime built from
+               native/src/*.cpp
+  conv         LocalOptimizer(ResNet-50 NHWC b256, bf16 mixed, SGD momentum)
+               .optimize() on one repeated batch
+  transformer  SpmdTrainer(TransformerLM bench width, AdamW, dp=1).step()
+               with the Pallas flash kernels, forward and backward
+  serve        those weights through ModelRegistry + DecodeEngine.stream()
+  kernels      each Pallas kernel natively against its reference
+  four_chips   DistriOptimizer dp4 / dp4+fsdp, fused optimizer under
+               shard_map, SpmdTrainer dp2×tp2 and fsdp4 — runs when >= 4
+               devices are visible, otherwise reports `skipped: N device(s)`
+
+`python chip_smoke.py transformer four_chips` runs a subset (a debugging
+aid; the summary line lists the stages that ran).  Compile seconds (the
+first call of each program, which also executes once) are printed apart
+from run seconds, so a cold and a warm compile cache can be told apart.
+
+The last two lines of stdout are one JSON object each: ``summary {...}``
+(stages run, stages skipped, compile and wall seconds, ``"claim": null``),
+then the result the driver parses, these keys and no others:
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+"""
+import json
+import re
+import sys
+import time
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+
+# Full sizes.  The test that debugs these stages on the CPU passes toy ones.
+SIZES = dict(
+    # conv: the driver's old headline model through the entry point
+    conv=dict(depth=50, class_num=1000, batch=256, image=224, iters=5),
+    # transformer: bench.py's width; depth already cut to 8 layers there
+    lm=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=8,
+            d_ff=4096, max_len=2048, dtype="bfloat16"),
+    lm_batch=8, lm_seq=2048, lm_steps=4,
+    # serve: the prefill ladder is powers of two up to max_prompt, one
+    # compile each; 256 caps warm-up at 9 prefill programs + 1 decode so
+    # it fits the smoke's time (the engine's own default would be 2047)
+    serve=dict(slots=8, max_context=2048, max_prompt=256,
+               prompts=(5, 17, 33, 64, 100, 129, 200, 256),
+               new_tokens=(8, 24, 16, 32, 8, 24, 16, 32)),
+    flash_shape=(8, 8, 2048, 128),
+    optim_leaf=(32000, 1024),
+    four_conv_batch=1024, four_conv_iters=3,
+)
+
+# Tolerances, as measured on a TPU v5e (NOTES.md "Bring-up on the chip"):
+FLASH_FWD_TOL = 1e-2     # bf16 vs f32 reference: measured 3.7e-3
+FLASH_BWD_TOL = 2e-2     # measured 6.0e-3 (dv), 5.2e-3 (dq), 4.5e-3 (dk)
+FUSED_ULPS = 4           # measured 0: bitwise on Adam, AdamW, SGD-momentum
+FOUR_CHIP_LOSS_TOL = 5e-2   # first-step loss (~10.9), sharded vs one chip:
+                            # bf16 sums in another order; measured <= 2e-3
+
+
+class Ctx:
+    """What later stages need from earlier ones."""
+
+    def __init__(self, sizes, native=True):
+        self.sz = sizes
+        self.native = native      # False only in the CPU debugging test
+        self.device = None
+        self.lm_model = None
+        self.lm_params = None     # host copy of the trained weights
+        self.lm_batch = None
+        self.lm_first_loss = None
+
+
+def _device_line():
+    d = jax.devices()[0]
+    return (f"platform={d.platform} device_kind={d.device_kind!r} "
+            f"devices={len(jax.devices())}")
+
+
+def _check(cond, msg):
+    if not cond:
+        raise AssertionError(msg)
+
+
+def _rel_err(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-6))
+
+
+def _mosaic_calls(hlo_text):
+    """The Mosaic custom-call lines of a compiled program's HLO."""
+    return [l for l in hlo_text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in l]
+
+
+def _timed(fn):
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn())
+    return out, time.perf_counter() - t0
+
+
+# --------------------------------------------------------------------- #
+def stage_device(ctx):
+    from bigdl_tpu import native
+    from bigdl_tpu.observability.profile import specs
+    info, spec = specs.require_chip()
+    ctx.device = info
+    stats = jax.devices()[0].memory_stats()
+    _check(stats and "bytes_in_use" in stats,
+           f"memory_stats() reports nothing: {stats}")
+    t0 = time.perf_counter()
+    _check(native.build(force=True),
+           "native runtime did not build from native/src/*.cpp (make)")
+    _check(native.available(), "native runtime built but does not load")
+    return dict(compile_s=time.perf_counter() - t0, run_s=0.0,
+                spec=spec.name, hbm_limit=stats.get("bytes_limit"),
+                detail="native build seconds under compile_s")
+
+
+def stage_conv(ctx):
+    from bigdl_tpu import nn
+    from bigdl_tpu.models import resnet
+    from bigdl_tpu.observability import InMemorySink, Recorder
+    from bigdl_tpu.optim import LocalOptimizer, SGD, Trigger
+    c = ctx.sz["conv"]
+    rng = np.random.RandomState(0)
+    x = rng.rand(c["batch"], c["image"], c["image"], 3).astype(np.float32)
+    y = rng.randint(1, c["class_num"] + 1, c["batch"]).astype(np.float32)
+    model = resnet.build(class_num=c["class_num"], depth=c["depth"],
+                         dataset="imagenet", format="NHWC")
+    sink = InMemorySink()
+    opt = (LocalOptimizer(model, (x, y), nn.ClassNLLCriterion(),
+                          batch_size=c["batch"])
+           .set_mixed_precision()
+           .set_optim_method(SGD(learning_rate=0.1, momentum=0.9))
+           .set_telemetry(Recorder(sinks=[sink]))
+           .set_end_when(Trigger.max_iteration(c["iters"])))
+    opt.optimize()
+    return _conv_verdict(sink, c["iters"])
+
+
+def _conv_verdict(sink, iters):
+    steps = sink.steps()
+    losses = [s["scalars"]["loss"] for s in steps]
+    _check(len(losses) == iters, f"{len(losses)} step records, not {iters}")
+    _check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    compiles = sum(s["span_counts"].get("train_step_compile", 0)
+                   for s in steps)
+    _check(compiles == 1, f"{compiles} train_step_compile spans, not 1")
+    profiles = [r for r in sink.records if r.get("type") == "profile"]
+    _check(len(profiles) == 1, f"{len(profiles)} profile records, not 1")
+    cost = profiles[0]["cost"]
+    _check(not cost.get("unavailable"),
+           f"cost capture incomplete: {cost.get('unavailable')} "
+           f"{cost.get('error', '')}")
+    return dict(compile_s=steps[0]["dur"],
+                run_s=sum(s["dur"] for s in steps[1:]),
+                losses=[round(l, 4) for l in losses],
+                cost_flops=cost.get("flops"),
+                peak_hbm_bytes=cost.get("peak_hbm_bytes"))
+
+
+def _lm(ctx):
+    from bigdl_tpu.models.transformer import TransformerConfig, TransformerLM
+    return TransformerLM(TransformerConfig(dropout=0.0, **ctx.sz["lm"]))
+
+
+def _lm_first_steps(trainer, tok, n):
+    """n timed steps on one repeated batch -> (losses, seconds each)."""
+    losses, secs = [], []
+    for _ in range(n):
+        loss, s = _timed(lambda: trainer.step(tok[:, :-1], tok[:, 1:]))
+        losses.append(float(loss))
+        secs.append(s)
+    return losses, secs
+
+
+def _step_hlo(trainer, tok):
+    """(compiled HLO of the trainer's step at this batch, seconds).  Call
+    it BEFORE the first step(): this is then the compile, and the step's
+    own is served from the compile cache."""
+    sh = trainer._batch_sharding()
+    tokens = jax.device_put(jnp.asarray(tok[:, :-1]), sh)
+    targets = jax.device_put(jnp.asarray(tok[:, 1:]), sh)
+    t0 = time.perf_counter()
+    hlo = trainer._step_fn.lower(
+        trainer.params, trainer.opt_state, tokens, targets,
+        jax.random.PRNGKey(0)).compile().as_text()
+    return hlo, time.perf_counter() - t0
+
+
+def stage_transformer(ctx):
+    from bigdl_tpu.observability import InMemorySink, Recorder
+    from bigdl_tpu.ops import attention_path
+    from bigdl_tpu.optim import AdamW
+    from bigdl_tpu.parallel.mesh import create_mesh
+    from bigdl_tpu.parallel.spmd import SpmdTrainer
+    sz = ctx.sz
+    model = _lm(ctx)
+    cfg = model.cfg
+    B, T = sz["lm_batch"], sz["lm_seq"]
+    shape = (B, cfg.n_heads, T, cfg.head_dim)
+    path, why = attention_path(shape, shape, jnp.dtype(cfg.dtype))
+    _check(path == "pallas", f"attention takes {path}: {why}")
+    sink = InMemorySink()
+    trainer = (SpmdTrainer(model, AdamW(learning_rate=1e-3),
+                           mesh=create_mesh({"dp": 1},
+                                            devices=jax.devices()[:1]))
+               .set_telemetry(Recorder(sinks=[sink])).init())
+    tok = np.random.RandomState(1).randint(
+        0, cfg.vocab_size, (B, T + 1)).astype(np.int32)
+    hlo, hlo_s = _step_hlo(trainer, tok)
+    losses, secs = _lm_first_steps(trainer, tok, sz["lm_steps"])
+    _check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    # step_s shows every step: the second one compiles AGAIN (the state
+    # init() placed and the state a step returns differ in sharding type,
+    # a second jit signature — NOTES.md lists it as a gap, not fixed here)
+    out = dict(compile_s=hlo_s + secs[0], run_s=sum(secs[1:]),
+               step_s=[round(s, 2) for s in secs],
+               losses=[round(l, 4) for l in losses])
+    if ctx.native:
+        calls = _mosaic_calls(hlo)
+        # forward, dk/dv and dq kernels, once per layer
+        _check(len(calls) >= 3 * cfg.n_layers,
+               f"{len(calls)} Mosaic calls in the compiled step, expected "
+               f">= {3 * cfg.n_layers}: the Pallas flash kernels are not "
+               "what ran")
+        out["mosaic_calls"] = len(calls)
+    profile = [r for r in sink.records if r.get("type") == "profile"]
+    _check(len(profile) == 1, f"{len(profile)} profile records, not 1")
+    # XLA's cost_analysis() does not see inside a Mosaic call: record what
+    # it reports next to the model's own count (for ROADMAP S1)
+    n_params = sum(int(np.prod(p.shape)) for p in
+                   jax.tree_util.tree_leaves(trainer.params))
+    out["cost_flops_xla"] = profile[0]["cost"].get("flops")
+    out["model_flops"] = float(
+        B * T * (6 * n_params + 12 * cfg.n_layers * cfg.d_model * T))
+    ctx.lm_model, ctx.lm_batch, ctx.lm_first_loss = model, tok, losses[0]
+    ctx.lm_params = jax.device_get(trainer.params)
+    trainer.detach()
+    return out
+
+
+def stage_serve(ctx):
+    from bigdl_tpu.serving import DecodeEngine, ModelRegistry
+    _check(ctx.lm_params is not None, "serve needs the transformer stage")
+    s = ctx.sz["serve"]
+    model = ctx.lm_model
+    model.set_params(ctx.lm_params, {})
+    reg = ModelRegistry()
+    reg.register("lm", model)
+    eng = DecodeEngine(reg, "lm", slots=s["slots"],
+                       max_context=s["max_context"],
+                       max_prompt=s["max_prompt"],
+                       max_new_tokens=max(s["new_tokens"]))
+    try:
+        t0 = time.perf_counter()
+        eng.warmup()
+        warm_s = time.perf_counter() - t0
+        rng = np.random.RandomState(2)
+        t0 = time.perf_counter()
+        streams = [eng.stream("lm", rng.randint(0, model.cfg.vocab_size, n),
+                              max_new_tokens=m)
+                   for n, m in zip(s["prompts"], s["new_tokens"])]
+        streamed = [list(st.tokens()) for st in streams]     # every token
+        results = [st.result(timeout=600) for st in streams]
+        run_s = time.perf_counter() - t0
+    finally:
+        eng.shutdown(drain=False, timeout=60)
+    for n, m, toks, res in zip(s["prompts"], s["new_tokens"], streamed,
+                               results):
+        _check(len(toks) == m and len(res) == n + m
+               and list(res[n:]) == toks,
+               f"prompt {n}: streamed {len(toks)} of {m} tokens, "
+               f"result length {len(res)}")
+    st = eng.stats()
+    rec = eng.recorder
+    _check(rec.counter_value("decode/nonfinite") == 0,
+           "a slot was flagged bad (non-finite logits)")
+    _check(st["errors"] == 0, f"decode/errors = {st['errors']}")
+    _check(st["recompiles"] == 0,
+           f"decode/recompiles = {st['recompiles']} after warm-up")
+    _check(st["finished"] == len(streams),
+           f"{st['finished']} of {len(streams)} requests finished")
+    return dict(compile_s=warm_s, run_s=run_s,
+                warmup_compiles=int(st["warmup_compiles"]),
+                max_prompt=s["max_prompt"], requests=len(streams),
+                tokens=sum(len(t) for t in streamed),
+                decode_steps=int(st["steps"]))
+
+
+def stage_kernels(ctx):
+    from bigdl_tpu.kernels import fused_optim
+    from bigdl_tpu.ops import flash_attention_mod as fa
+    from bigdl_tpu.optim import Adam, AdamW, SGD
+    if ctx.native:
+        _check(fa._INTERPRET is False, "flash_attention._INTERPRET is set")
+        _check(fused_optim._interpret() is False,
+               "fused_optim is in interpret mode")
+    out = dict(compile_s=0.0, run_s=0.0)
+
+    def both(f, *args):
+        """First call (compile + run), second call (run)."""
+        r, first = _timed(lambda: f(*args))
+        r, again = _timed(lambda: f(*args))
+        out["compile_s"] += first - again
+        out["run_s"] += again
+        return r
+
+    # -- flash attention, forward and backward ------------------------ #
+    rng = np.random.RandomState(0)
+    shape = ctx.sz["flash_shape"]
+    q, k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+               for _ in range(3))
+    path, why = fa.attention_path(q.shape, k.shape, q.dtype)
+    _check(path == "pallas", f"attention takes {path}: {why}")
+
+    def total(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=True).astype(jnp.float32))
+
+    fwd = jax.jit(lambda q, k, v: fa.flash_attention(q, k, v, causal=True))
+    bwd = jax.jit(jax.grad(total(fa.flash_attention), argnums=(0, 1, 2)))
+    o = both(fwd, q, k, v)
+    g = both(bwd, q, k, v)
+    o_ref = jax.jit(lambda q, k, v: fa.attention_reference(
+        q, k, v, causal=True))(q, k, v)
+    g_ref = jax.jit(jax.grad(total(fa.attention_reference),
+                             argnums=(0, 1, 2)))(q, k, v)
+    out["flash_fwd_rel_err"] = _rel_err(o, o_ref)
+    out["flash_bwd_rel_err"] = [_rel_err(a, b) for a, b in zip(g, g_ref)]
+    _check(out["flash_fwd_rel_err"] < FLASH_FWD_TOL,
+           f"flash fwd vs reference: {out['flash_fwd_rel_err']}")
+    _check(max(out["flash_bwd_rel_err"]) < FLASH_BWD_TOL,
+           f"flash bwd vs reference: {out['flash_bwd_rel_err']}")
+    if ctx.native:
+        n_fwd = len(_mosaic_calls(fwd.lower(q, k, v).compile().as_text()))
+        n_bwd = len(_mosaic_calls(bwd.lower(q, k, v).compile().as_text()))
+        _check(n_fwd == 1 and n_bwd == 3,
+               f"Mosaic calls: fwd {n_fwd} (want 1), grad {n_bwd} (want "
+               "3: forward, dk/dv, dq)")
+
+    # -- fused optimizer updates vs the tree-map update() -------------- #
+    leaf = ctx.sz["optim_leaf"]
+    p0 = {"w": jnp.asarray(rng.randn(*leaf).astype(np.float32))}
+    grad = {"w": jnp.asarray(rng.randn(*leaf).astype(np.float32) * 0.01)}
+    out["fused_ulps"] = {}
+    for name, make in (
+            ("adam", lambda f: Adam(1e-3, fused=f)),
+            ("adamw", lambda f: AdamW(1e-3, weight_decay=0.01, fused=f)),
+            ("sgd", lambda f: SGD(0.1, momentum=0.9, fused=f))):
+        finals = []
+        for fused in (False, True):
+            method = make(fused)
+            upd = jax.jit(method.update)
+            p, state = p0, method.init_state(p0)
+            if fused and ctx.native:
+                hlo = upd.lower(grad, p, state).compile().as_text()
+                _check(_mosaic_calls(hlo),
+                       f"fused {name}: no Mosaic call in the update")
+            for _ in range(3):
+                if fused:
+                    p, state = both(upd, grad, p, state)
+                else:
+                    p, state = upd(grad, p, state)
+            finals.append(jax.tree_util.tree_leaves((p, state)))
+        # distance in representable floats, params and every moment
+        out["fused_ulps"][name] = worst = max(
+            int(np.testing.assert_array_max_ulp(
+                np.asarray(a), np.asarray(b), maxulp=np.inf).max())
+            for a, b in zip(*finals) if a.dtype == jnp.float32)
+        _check(worst <= FUSED_ULPS or not ctx.native,
+               f"fused {name} vs update(): {worst} ulps")
+    return out
+
+
+# --------------------------------------------------------------------- #
+def _on_all(tree, devices, what):
+    """Every array leaf has an addressable shard on each of `devices`."""
+    want = set(devices)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        if not hasattr(leaf, "addressable_shards"):
+            continue
+        got = {s.device for s in leaf.addressable_shards}
+        _check(got == want,
+               f"{what}{jax.tree_util.keystr(path)} lives on "
+               f"{sorted(d.id for d in got)}, not on all of "
+               f"{sorted(d.id for d in want)}")
+
+
+def _bytes_in_use(devices):
+    """memory_stats() bytes in use per device; all must hold something
+    (the CPU backend of the debugging test reports no stats: None)."""
+    stats = [d.memory_stats() for d in devices]
+    if not all(stats):
+        _check(devices[0].platform == "cpu", f"memory_stats(): {stats}")
+        return None
+    used = [s["bytes_in_use"] for s in stats]
+    _check(all(u > 0 for u in used), f"bytes_in_use per device: {used}")
+    return used
+
+
+def stage_four_chips(ctx):
+    from bigdl_tpu import nn
+    from bigdl_tpu.models import resnet
+    from bigdl_tpu.observability import InMemorySink, Recorder
+    from bigdl_tpu.optim import AdamW, DistriOptimizer, SGD, Trigger
+    from bigdl_tpu.parallel.mesh import create_mesh
+    from bigdl_tpu.parallel.spmd import SpmdTrainer
+    if len(jax.devices()) < 4:
+        return dict(skipped=f"{len(jax.devices())} device(s)")
+    _check(ctx.lm_first_loss is not None,
+           "four_chips needs the transformer stage (its first loss and "
+           "batch are the one-chip reference)")
+    devs = jax.devices()[:4]
+    sz = ctx.sz
+    out = dict(compile_s=0.0, run_s=0.0)
+    mesh = create_mesh({"dp": 4}, devices=devs)
+    out["mesh_dp4"] = [[d.id, list(getattr(d, "coords", ()))]
+                       for d in mesh.devices.flat]
+
+    # -- DistriOptimizer: ResNet-50 on dp4, then with fsdp ------------- #
+    c = dict(sz["conv"], batch=sz["four_conv_batch"],
+             iters=sz["four_conv_iters"])
+    rng = np.random.RandomState(0)
+    x = rng.rand(c["batch"], c["image"], c["image"], 3).astype(np.float32)
+    y = rng.randint(1, c["class_num"] + 1, c["batch"]).astype(np.float32)
+    for fsdp in (False, True):
+        model = resnet.build(class_num=c["class_num"], depth=c["depth"],
+                             dataset="imagenet", format="NHWC")
+        sink = InMemorySink()
+        opt = (DistriOptimizer(model, (x, y), nn.ClassNLLCriterion(),
+                               batch_size=c["batch"], mesh=mesh, fsdp=fsdp)
+               .set_mixed_precision()
+               .set_optim_method(SGD(learning_rate=0.1, momentum=0.9))
+               .set_telemetry(Recorder(sinks=[sink]))
+               .set_end_when(Trigger.max_iteration(c["iters"])))
+        opt.optimize()
+        v = _conv_verdict(sink, c["iters"])
+        out["compile_s"] += v["compile_s"]
+        out["run_s"] += v["run_s"]
+        key = "conv_dp4_fsdp" if fsdp else "conv_dp4"
+        out[key] = v["losses"]
+        _on_all(model._params, devs, f"{key} param ")
+        _on_all(opt._place_batch(x, y), devs, f"{key} batch ")
+        if fsdp:
+            # the training-time layout: dim 0 split four ways
+            laid = opt._layout_params(model.init_params(0)[0])
+            _on_all(laid, devs, f"{key} laid-out param ")
+            split = [l for l in jax.tree_util.tree_leaves(laid)
+                     if l.addressable_shards[0].data.shape != l.shape]
+            _check(split, "fsdp sharded no parameter")
+            out["conv_fsdp_sharded_leaves"] = len(split)
+        out[f"{key}_bytes_in_use"] = _bytes_in_use(devs)
+
+    # -- the fused optimizer kernel under DistriOptimizer's shard_map -- #
+    xs = rng.rand(256, 64).astype(np.float32)
+    ys = rng.rand(256, 1).astype(np.float32)
+    mlp = nn.Sequential(nn.Linear(64, 512), nn.Tanh(), nn.Linear(512, 1))
+    sink = InMemorySink()
+    (DistriOptimizer(mlp, (xs, ys), nn.MSECriterion(), batch_size=256,
+                     mesh=mesh, fused_optim=True)
+     .set_optim_method(SGD(learning_rate=0.01, momentum=0.9))
+     .set_telemetry(Recorder(sinks=[sink]))
+     .set_end_when(Trigger.max_iteration(4))).optimize()
+    fl = [s["scalars"]["loss"] for s in sink.steps()]
+    _check(all(np.isfinite(fl)) and fl[-1] < fl[0],
+           f"fused SGD under shard_map: losses {fl}")
+    out["fused_shard_map_losses"] = [round(l, 5) for l in fl]
+
+    # -- SpmdTrainer at the transformer width on two meshes ------------ #
+    tok = ctx.lm_batch
+    B, T = tok.shape[0], tok.shape[1] - 1
+    for axes in ({"dp": 2, "tp": 2}, {"fsdp": 4}):
+        model = _lm(ctx)
+        cfg = model.cfg
+        mesh = create_mesh(axes, devices=devs)
+        trainer = SpmdTrainer(model, AdamW(learning_rate=1e-3),
+                              mesh=mesh).init()
+        hlo, hlo_s = _step_hlo(trainer, tok)
+        losses, secs = _lm_first_steps(trainer, tok, 1)
+        out["compile_s"] += hlo_s + secs[0]
+        key = "lm_" + "x".join(f"{a}{n}" for a, n in axes.items())
+        delta = abs(losses[0] - ctx.lm_first_loss)
+        out[key] = dict(first_loss=round(losses[0], 5),
+                        one_chip=round(ctx.lm_first_loss, 5),
+                        delta=round(delta, 6),
+                        mesh=[[d.id, list(getattr(d, "coords", ()))]
+                              for d in mesh.devices.flat])
+        _check(np.isfinite(losses).all() and delta <= FOUR_CHIP_LOSS_TOL,
+               f"{key}: first loss {losses[0]} vs one chip "
+               f"{ctx.lm_first_loss} (tolerance {FOUR_CHIP_LOSS_TOL})")
+        _on_all(trainer.params, devs, f"{key} param ")
+        _on_all(jax.device_put(jnp.asarray(tok[:, :-1]),
+                               trainer._batch_sharding()), devs,
+                f"{key} batch ")
+        out[key]["bytes_in_use"] = _bytes_in_use(devs)
+        if ctx.native:
+            # each chip must run the kernels on ITS block of batch*heads:
+            # operands gathered whole onto every chip would show B*H here
+            calls = _mosaic_calls(hlo)
+            _check(len(calls) >= 3 * cfg.n_layers,
+                   f"{key}: {len(calls)} Mosaic calls in the compiled step")
+            local = B * cfg.n_heads // 4
+            lead = {int(m) for l in calls for m in re.findall(
+                rf"bf16\[(\d+),{T},{cfg.head_dim}\]", l)}
+            _check(lead == {local},
+                   f"{key}: kernel operands lead with {sorted(lead)}, "
+                   f"want {{{local}}} (= B*H/4; {B * cfg.n_heads} means "
+                   "gathered whole)")
+            out[key]["kernel_block"] = [local, T, cfg.head_dim]
+        trainer.detach()
+        del trainer
+    return out
+
+
+STAGES = {
+    "device": stage_device,
+    "conv": stage_conv,
+    "transformer": stage_transformer,
+    "serve": stage_serve,
+    "kernels": stage_kernels,
+    "four_chips": stage_four_chips,
+}
+
+
+def run(names, ctx):
+    """Run stages in order; returns {name: result}.  Raises on the first
+    failure, after printing what ran."""
+    results = {}
+    for name in names:
+        print(f"[{name}] start {_device_line()}", flush=True)
+        t0 = time.perf_counter()
+        res = STAGES[name](ctx)
+        res["wall_s"] = time.perf_counter() - t0
+        results[name] = res
+        shown = {k: (float(f"{v:.4g}") if isinstance(v, float) else v)
+                 for k, v in res.items()}
+        print(f"[{name}] {'skipped' if 'skipped' in res else 'ok'} "
+              f"{_device_line()} {json.dumps(shown)}", flush=True)
+    return results
+
+
+def result_line(device):
+    """The last line of stdout: the device as jax reports it, and nothing
+    the driver's contract does not name."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
+
+
+def main(argv):
+    from bigdl_tpu.utils.engine import enable_compile_cache
+    names = argv or list(STAGES)
+    unknown = [n for n in names if n not in STAGES]
+    if unknown:
+        sys.exit(f"unknown stage(s) {unknown}; choose from {list(STAGES)}")
+    if "device" not in names:
+        names = ["device"] + names          # the gate always runs
+    cache = enable_compile_cache()
+    print(f"compile cache: {cache}", flush=True)
+    ctx = Ctx(SIZES)
+    t0 = time.perf_counter()
+    results = run(names, ctx)
+    print("stage         compile_s    run_s   wall_s")
+    for name, r in results.items():
+        if "skipped" in r:
+            print(f"{name:12s}  skipped: {r['skipped']}")
+        else:
+            print(f"{name:12s} {r['compile_s']:10.1f} {r['run_s']:8.1f} "
+                  f"{r['wall_s']:8.1f}")
+    print("summary " + json.dumps({
+        "stages": list(results),
+        "skipped": {n: r["skipped"] for n, r in results.items()
+                    if "skipped" in r},
+        "compile_s": round(sum(r.get("compile_s", 0.0)
+                               for r in results.values()), 1),
+        "wall_s": round(time.perf_counter() - t0, 1), "claim": None}))
+    print(result_line(ctx.device), flush=True)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])

@@ -32,10 +32,11 @@ Asserted per the acceptance bar:
   4. no job was killed by a fleet decision: both complete,
      ``fleet/failed`` == 0.
 
-All three cases share one persistent compile-cache directory, so the
-contention case's displacement rebuilds warm-start from the solo runs'
-compiles — the fleet's re-placement cost claim, exercised on every CI
-run.
+All three cases share the repo's one persistent compile cache
+(``utils.engine.enable_compile_cache``: ``JAX_COMPILATION_CACHE_DIR``
+where set, else ``<checkout>/.jax_cache``), so the contention case's
+displacement rebuilds warm-start from the solo runs' compiles — the
+fleet's re-placement cost claim, exercised on every CI run.
 
 Usage: python scripts/fleet_chaos_smoke.py           # run the matrix
        python scripts/fleet_chaos_smoke.py --worker <case>   # internal
@@ -143,10 +144,11 @@ def _emit(fl, rec, digests):
     print("FLEET_RESULT " + json.dumps(out), flush=True)
 
 
-def worker(case, work_dir, cache_dir):
+def worker(case, work_dir):
     import jax
     from bigdl_tpu.fleet import FleetScheduler
     from bigdl_tpu.observability import JsonlSink, Recorder
+    from bigdl_tpu.utils.engine import enable_compile_cache
 
     def rec_for(name):
         return Recorder(sinks=[JsonlSink(
@@ -154,7 +156,7 @@ def worker(case, work_dir, cache_dir):
 
     rec = rec_for("fleet")
     fl = FleetScheduler(jax.devices()[:8], recorder=rec,
-                        compile_cache_dir=cache_dir,
+                        compile_cache_dir=enable_compile_cache(),
                         handle_sigterm=False)
     if case == "solo_a":
         _admit_a(fl, work_dir, rec_for("job_a"))
@@ -220,7 +222,7 @@ def _run_case(name, tmp, timeout):
     t0 = time.time()
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--worker", name,
-         "--dir", work, "--cache", os.path.join(tmp, "xla_cache")],
+         "--dir", work],
         env=env, capture_output=True, text=True, timeout=timeout)
     wall = time.time() - t0
     if proc.returncode != 0:
@@ -251,13 +253,12 @@ def main():
     ap.add_argument("--worker",
                     choices=["solo_a", "solo_b", "contention"])
     ap.add_argument("--dir")
-    ap.add_argument("--cache")
     args = ap.parse_args()
     if args.worker:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import jax
         jax.config.update("jax_platforms", "cpu")
-        worker(args.worker, args.dir, args.cache)
+        worker(args.worker, args.dir)
         return
 
     tmp = tempfile.mkdtemp(prefix="fleet_chaos_")

@@ -30,15 +30,13 @@ claimed → ``pool_idle``, kept disjoint from job badput) roll into one
 fleet document, written to disk and rendered by
 ``trace_summary.py goodput`` — the render is asserted, not just run.
 
-**Regression sentinel.**  The BENCH_r01–r10 rounds (normalized by
-``bench_trend.normalize_rounds``) and both ledger snapshots become one
-trajectory, checked against the committed bounds in
-``artifacts/goodput_baseline.json``: a proxy metric may only regress
+**Regression sentinel.**  Both ledger snapshots become sentinel rows,
+checked against the committed bounds in
+``artifacts/goodput_baseline.json``: a ledger metric may only regress
 past its bound with a committed justification, and a badput bucket
 growing past its recorded ceiling fails CI.  Emits ONE
 machine-parseable JSON line last (the CI contract).
 """
-import importlib.util
 import json
 import os
 import subprocess
@@ -100,14 +98,6 @@ def wait_for(cond, timeout, msg):
             return True
         time.sleep(0.05)
     return check(False, f"timed out waiting: {msg}")
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(_REPO, "scripts", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # ===================================================================== #
@@ -342,11 +332,9 @@ def main():
           and "top gap" in ts.stdout,
           "trace_summary goodput renders the waterfall")
 
-    # -- regression sentinel: bench trajectory + ledger fractions ----- #
-    bt = _load_script("bench_trend")
-    rows = regress.bench_rows(bt.normalize_rounds(bt.load_rounds(_REPO)))
-    rows.append(regress.ledger_row("train", tr["snap"]))
-    rows.append(regress.ledger_row("serve", sv["set"]))
+    # -- regression sentinel: ledger fractions ------------------------ #
+    rows = [regress.ledger_row("train", tr["snap"]),
+            regress.ledger_row("serve", sv["set"])]
     baseline = regress.load_baseline(
         os.path.join(_REPO, "artifacts", "goodput_baseline.json"))
     findings = regress.check(rows, baseline)
@@ -361,15 +349,11 @@ def main():
         else:
             rec.inc("regress/advisories")
     check(regress.gate(findings),
-          f"regression sentinel passes: no proxy metric regressed past "
+          f"regression sentinel passes: no ledger metric regressed past "
           f"its committed bound without justification "
           f"({len(findings)} findings, "
           f"{sum(1 for f in findings if f.severity == 'waived')} "
           f"waived)")
-    check(len([r for r in rows if r['source'].startswith('bench:')])
-          >= 10,
-          "trajectory covers every BENCH round (divergent schemas "
-          "normalized)")
 
     summary = {
         "metric": "goodput_smoke",

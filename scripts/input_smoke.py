@@ -47,11 +47,16 @@ import jax
 from bigdl_tpu import nn
 from bigdl_tpu.data.device_augment import DeviceAugment
 from bigdl_tpu.data.sharded import ShardedRecordDataSet
+from bigdl_tpu.kernels import fused_optim
 from bigdl_tpu.observability import InMemorySink, Recorder
 from bigdl_tpu.optim import Adam, Trigger
 from bigdl_tpu.optim.distri_optimizer import DistriOptimizer
 from bigdl_tpu.parallel import mesh as mesh_lib
 from bigdl_tpu.utils.tfrecord import write_tfrecords
+
+# CPU smoke: the fused optimizer kernels run through the Pallas
+# interpreter (they lower through Mosaic unless told otherwise)
+fused_optim._FORCE_INTERPRET = True
 
 DP = 8
 HW, CROP, C = 32, 28, 3            # the reference's 256->224 proportions

@@ -22,21 +22,7 @@ import numpy as np
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
-
-def _init_with_retry(tries=5, wait=90):
-    for i in range(tries):
-        try:
-            import jax
-            jax.devices()
-            return jax
-        except Exception as e:
-            print(f"# backend init attempt {i + 1} failed: {e}", flush=True)
-            time.sleep(wait)
-    print("# backend unreachable, giving up", flush=True)
-    sys.exit(2)
-
-
-jax = _init_with_retry()
+import jax                                                 # noqa: E402
 import jax.numpy as jnp                                    # noqa: E402
 from jax import lax                                        # noqa: E402
 
@@ -45,6 +31,11 @@ from bigdl_tpu.models import resnet                        # noqa: E402
 from bigdl_tpu.optim import SGD                            # noqa: E402
 from bigdl_tpu.optim.optimizer import make_train_step      # noqa: E402
 from bigdl_tpu.nn.module import Ctx                        # noqa: E402
+from bigdl_tpu.observability.profile import specs          # noqa: E402
+
+# MFU denominator from the one peak table; no TPU or an unknown device
+# kind is an error here, never a default
+PEAK_FLOPS = specs.require_chip()[1].peak_flops
 
 
 def lat():
@@ -141,7 +132,7 @@ def exp_E():
             t = _threaded(*args, k=8)
             print(f"E threaded b{batch:<5d}: {t*1e3:7.2f} ms  "
                   f"{batch/t:8.0f} img/s  "
-                  f"({batch*12.3e9/t/197e12*100:4.1f}% MFU)", flush=True)
+                  f"({batch*12.3e9/t/PEAK_FLOPS*100:4.1f}% MFU)", flush=True)
         except Exception as e:
             print(f"# E b{batch} FAILED: {type(e).__name__}: {e}",
                   flush=True)
@@ -220,7 +211,7 @@ def exp_H(batch=512):
     uflops = sum(2.0 * batch * (hw // s) ** 2 * co * ci * kh * kw
                  for (co, ci, kh, kw, s, hw, m) in R50_CONVS)
     print(f"H conv floor b{batch}: {t*1e3:7.2f} ms 1x-each "
-          f"-> {uflops/t/197e12*100:5.1f}% MFU", flush=True)
+          f"-> {uflops/t/PEAK_FLOPS*100:5.1f}% MFU", flush=True)
 
 
 if __name__ == "__main__":

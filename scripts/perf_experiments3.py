@@ -20,21 +20,7 @@ import numpy as np
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
-
-def _init_with_retry(tries=5, wait=90):
-    for i in range(tries):
-        try:
-            import jax
-            jax.devices()
-            return jax
-        except Exception as e:
-            print(f"# backend init attempt {i + 1} failed: {e}", flush=True)
-            time.sleep(wait)
-    print("# backend unreachable, giving up", flush=True)
-    sys.exit(2)
-
-
-jax = _init_with_retry()
+import jax                                                 # noqa: E402
 import jax.numpy as jnp                                    # noqa: E402
 from jax import lax                                        # noqa: E402
 

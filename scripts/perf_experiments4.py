@@ -16,26 +16,7 @@ import numpy as np
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
-
-def _init_with_retry(tries=5, wait=90):
-    for i in range(tries):
-        try:
-            import jax
-            jax.devices()
-            return jax
-        except Exception as e:
-            print(f"# backend init attempt {i + 1} failed: {e}", flush=True)
-            time.sleep(wait)
-    print("# backend unreachable, giving up", flush=True)
-    sys.exit(2)
-
-
-jax = _init_with_retry()
-try:
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_comp_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
-except Exception:
-    pass
+import jax                                                 # noqa: E402
 import jax.numpy as jnp                                    # noqa: E402
 from jax import lax                                        # noqa: E402
 
@@ -43,11 +24,13 @@ from bigdl_tpu import nn                                   # noqa: E402
 from bigdl_tpu.models import resnet                        # noqa: E402
 from bigdl_tpu.optim import SGD                            # noqa: E402
 from bigdl_tpu.optim.optimizer import make_train_step      # noqa: E402
-from bigdl_tpu.observability.profile import peak_flops     # noqa: E402
+from bigdl_tpu.observability.profile import specs          # noqa: E402
+from bigdl_tpu.utils.engine import enable_compile_cache    # noqa: E402
 
-# MFU denominator: env override (BIGDL_PEAK_FLOPS) > device peak-spec
-# table > the historical TPU-v5e constant these scripts assumed
-PEAK_FLOPS = peak_flops(default=197e12)
+# MFU denominator from the one peak table; no TPU or an unknown device
+# kind is an error here, never a default
+PEAK_FLOPS = specs.require_chip()[1].peak_flops
+enable_compile_cache()
 
 
 def lat():

@@ -34,11 +34,16 @@ import jax
 import jax.numpy as jnp
 
 from bigdl_tpu import nn
+from bigdl_tpu.kernels import fused_optim
 from bigdl_tpu.observability.collectives import hlo_collective_ops
 from bigdl_tpu.observability.profile.capture import capture_compiled
 from bigdl_tpu.optim import Adam, SGD, Trigger
 from bigdl_tpu.optim.distri_optimizer import DistriOptimizer
 from bigdl_tpu.parallel import mesh as mesh_lib
+
+# CPU smoke: the fused optimizer kernels run through the Pallas
+# interpreter (they lower through Mosaic unless told otherwise)
+fused_optim._FORCE_INTERPRET = True
 
 DP = 8
 

@@ -1,4 +1,5 @@
-"""ResNet-50 MFU localization + tuning matrix (run on the real TPU).
+"""ResNet-50 MFU localization + tuning matrix (needs the chip: run it
+through the chip tool).
 
 PROTOCOL WARNING (the r2 lesson): any timing whose scan body does not
 consume EVERY output of the step lets XLA dead-code-eliminate the

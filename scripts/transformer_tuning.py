@@ -1,4 +1,5 @@
-"""TransformerLM train-step tuning matrix (run on the real TPU).
+"""TransformerLM train-step tuning matrix (needs the chip: run it
+through the chip tool).
 
 Sweeps flash-attention block sizes and batch/seq shapes for the bench.py
 transformer config and prints tokens/sec + MFU per point, so the bench
@@ -20,11 +21,11 @@ from bigdl_tpu.models.transformer import (TransformerLM,        # noqa: E402
                                           TransformerConfig,
                                           lm_cross_entropy)
 from bigdl_tpu.optim import SGD                                 # noqa: E402
-from bigdl_tpu.observability.profile import peak_flops          # noqa: E402
+from bigdl_tpu.observability.profile import specs               # noqa: E402
 
-# MFU denominator: env override (BIGDL_PEAK_FLOPS) > device peak-spec
-# table > the historical TPU-v5e constant this script assumed
-PEAK_FLOPS = peak_flops(default=197e12)
+# MFU denominator from the one peak table; no TPU or an unknown device
+# kind is an error here, never a default
+PEAK_FLOPS = specs.require_chip()[1].peak_flops
 
 
 def lat():

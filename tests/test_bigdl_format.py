@@ -1,5 +1,5 @@
-"""Reference-format .bigdl reader/writer (VERDICT r2 item 6;
-≙ utils/serializer/ModuleSerializer.scala, serialization/bigdl.proto).
+"""Reference-format .bigdl reader/writer
+(≙ utils/serializer/ModuleSerializer.scala, serialization/bigdl.proto).
 
 The fixture in test_hand_encoded_linear is built with raw bigdl.proto
 field numbers, independent of the writer, so reader and writer cannot
@@ -333,7 +333,7 @@ def test_shape_and_table_ops_roundtrip():
 def test_bn_running_stats_roundtrip():
     """Running mean/var ride the BN module's attr map
     (nn/BatchNormalization.scala:346 doSerializeModule) and must survive
-    save->load so eval-mode inference matches (VERDICT r3 item 2)."""
+    save->load so eval-mode inference matches."""
     m = nn.Sequential(nn.SpatialConvolution(2, 3, 3, 3, 1, 1, 1, 1),
                       nn.SpatialBatchNormalization(3), nn.ReLU())
     m.reset(5)
@@ -1470,7 +1470,7 @@ def test_recurrent_lstm_bnorm_read():
     (Recurrent.scala:111-119); BN gamma/beta/stats are in the
     REFERENCE's [i, g, f, o] gate order and must ride the same
     permutation as the projection weights.  Was an honest raise
-    through r4 (VERDICT r4 missing-item 4)."""
+    through r4."""
     rng = np.random.RandomState(31)
     nin, h = 3, 4
     w_pre = rng.randn(4 * h, nin).astype(np.float32)

@@ -1,4 +1,4 @@
-"""Caffe converter breadth (VERDICT r2 item 4): Deconvolution, dilation,
+"""Caffe converter breadth: Deconvolution, dilation,
 ELU, PReLU, Power, Exp, Log, AbsVal, Reshape, Slice, Threshold, Tile,
 RNN, Eltwise coefficients — mirroring utils/caffe/Converter.scala:632 and
 LayerConverter.scala:39 layer coverage."""

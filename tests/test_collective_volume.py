@@ -1,5 +1,5 @@
-"""Bytes-on-wire analysis of the compiled DistriOptimizer step
-(VERDICT r2 item 10): the partitioned HLO's collective traffic must
+"""Bytes-on-wire analysis of the compiled DistriOptimizer step:
+the partitioned HLO's collective traffic must
 match the ring all-reduce theory 2*G*(n-1)/n that BASELINE.md's
 scaling-efficiency row relies on."""
 import os
@@ -65,9 +65,11 @@ def test_fsdp_step_has_gather_and_scatter():
 
 def test_flagship_spmd_step_collective_budget():
     """Layout regression guard: the tiny-preset SpmdTrainer step on the
-    dp2 x fsdp2 x tp2 mesh compiles to a bounded set of collectives
-    (snapshot: 31 all-reduce + 1 collective-permute, a few MB on wire
-    with replica-group-aware ring accounting).
+    dp2 x fsdp2 x tp2 mesh, batch sharded the way step() shards it,
+    compiles to a bounded set of collectives (snapshot on jax 0.9.0:
+    40 all-gather + 16 all-reduce — fsdp's parameter gathers — and 2.8 MB
+    on wire with replica-group-aware ring accounting; the same plan
+    before and after attention became a shard_map island in PR 21).
     A silently broken pspec (e.g. losing the megatron pairing so GSPMD
     all-gathers activations everywhere) shows up here as a big jump."""
     import jax.numpy as jnp
@@ -82,15 +84,17 @@ def test_flagship_spmd_step_collective_budget():
                      fsdp=True, seed=0, min_fsdp_size=1).init()
     x = np.zeros((4, 64), np.int32)
     y = np.ones((4, 64), np.int32)
-    lowered = tr._step_fn.lower(tr.params, tr.opt_state, jnp.asarray(x),
-                                jnp.asarray(y), jax.random.PRNGKey(0))
+    sh = tr._batch_sharding()
+    lowered = tr._step_fn.lower(
+        tr.params, tr.opt_state, jax.device_put(jnp.asarray(x), sh),
+        jax.device_put(jnp.asarray(y), sh), jax.random.PRNGKey(0))
     hlo = lowered.compile().as_text()
     ops = collective_bytes(hlo, 8)
     counts = Counter(op for op, _, _ in ops)
     wire = sum(w for _, _, w in ops)
-    # snapshot is partitioner-version dependent (31 on jax 0.9.0, 44 on
-    # 0.4.37); the guard's job is catching order-of-magnitude jumps from
-    # a broken pspec, so the bound sits above known-good snapshots
+    # snapshot is partitioner-version dependent; the guard's job is
+    # catching order-of-magnitude jumps from a broken pspec, so the bound
+    # sits above known-good snapshots
     assert counts["all-reduce"] <= 50, counts
-    assert sum(counts.values()) <= 55, counts
+    assert sum(counts.values()) <= 70, counts
     assert wire < 8e6, wire

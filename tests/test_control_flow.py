@@ -98,7 +98,7 @@ def test_while_loop_scan_gradient_matches_unrolled():
 
 def test_while_loop_scan_trains():
     """A model with a bounded loop inside takes a gradient step end to
-    end (authored loops are trainable, VERDICT r4 missing-1)."""
+    end (authored loops are trainable)."""
     body = nn.Sequential(nn.Linear(3, 3), nn.Tanh())
     m = nn.Sequential(
         nn.Linear(5, 3),

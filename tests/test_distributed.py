@@ -98,18 +98,10 @@ def test_allreduce_primitives():
                                               allgather_params)
     from jax.sharding import PartitionSpec as P
     mesh = mesh_lib.create_mesh({"dp": 8})
-    try:
-        from jax import shard_map as smap
 
-        def wrap(f, in_specs, out_specs):
-            return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                        check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as smap
-
-        def wrap(f, in_specs, out_specs):
-            return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                        check_rep=False)
+    def wrap(f, in_specs, out_specs):
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     x = jnp.arange(8.0)
 
@@ -131,7 +123,7 @@ def test_allreduce_primitives():
 def test_fsdp_opt_state_specs_by_tree_path():
     """Moments inherit their OWN param's sharding, derived by tree-path
     correspondence: a replicated param sharing shape+dtype with a sharded
-    one must NOT get its moments dim-0-sharded (VERDICT r2 weak 5)."""
+    one must NOT get its moments dim-0-sharded."""
     from bigdl_tpu.optim.distri_optimizer import fsdp_opt_state_specs
     from bigdl_tpu.optim import SGD
     from jax.sharding import PartitionSpec as P

@@ -400,16 +400,28 @@ def test_fleet_place_giveup_applies_plan_anyway():
     assert rec.counter_value("retry/giveups.fleet") == 1
 
 
-def test_shared_compile_cache_config(tmp_path):
+def test_shared_compile_cache_config(tmp_path, monkeypatch):
+    """The fleet's cache goes where it is told — unless
+    JAX_COMPILATION_CACHE_DIR places it from outside, which wins and
+    leaves jax's own setting untouched (utils.engine's one rule)."""
     prev = jax.config.jax_compilation_cache_dir
     try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         path = enable_shared_compile_cache(str(tmp_path / "cache"))
         assert os.path.isdir(path)
         assert jax.config.jax_compilation_cache_dir == path
         fl = FleetScheduler(jax.devices()[:1], handle_sigterm=False,
                             compile_cache_dir=str(tmp_path / "cache2"))
         assert jax.config.jax_compilation_cache_dir == \
-            fl.compile_cache_dir
+            fl.compile_cache_dir == str(tmp_path / "cache2")
+
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        assert enable_shared_compile_cache(
+            str(tmp_path / "cache3")) == outside
+        assert os.path.isdir(outside)
+        assert jax.config.jax_compilation_cache_dir == \
+            str(tmp_path / "cache2")          # not set in code
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
 

@@ -170,7 +170,7 @@ def test_metrics_trace_writes_profile(tmp_path):
 
 
 def test_pipeline_image_to_classifier():
-    """Spark-ML Pipeline contract (VERDICT r3 weak-6): image transform
+    """Spark-ML Pipeline contract: image transform
     stage -> tensor bridge -> classifier estimator, fitted end-to-end;
     the PipelineModel then transforms raw rows to predictions."""
     import numpy as np

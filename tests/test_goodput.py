@@ -398,19 +398,58 @@ def _bench_trend():
     return mod
 
 
-def test_normalize_rounds_unifies_divergent_schemas():
+def _round_docs():
+    """One document per schema the driver has banked: a parsed chip line,
+    a failed round, and the three smoke shapes (compose matrix, decode
+    with no metric key anywhere, rec_smoke)."""
+    return {
+        1: {"n": 1, "cmd": "python bench.py", "rc": 0,
+            "tail": "2026-07-29 18:29:49 ...",
+            "parsed": {"metric": "resnet50_train_images_per_sec_per_chip",
+                       "value": 2609.02, "unit": "images/sec",
+                       "vs_baseline": 45.772}},
+        2: {"n": 2, "cmd": "python bench.py", "rc": 2,
+            "tail": "2026-07-30 15:49:33 backend unreachable",
+            "parsed": None},
+        8: {"bench": "compose_proxy_smoke", "proxy": True, "devices": 8,
+            "configs": {
+                "dp2_tp2_pp2": {"status": "trained"},
+                **{f"cfg{i}": {"losses": [4.0 - i, 3.0 - i],
+                               "wire": 1000.0 * i, "bitwise": True}
+                   for i in range(6)}}},
+        9: {"n": 9, "cmd": "python scripts/decode_smoke.py", "rc": 0,
+            "proxy": True,
+            "decode_throughput": {"speedup": 1.6, "recompiles": 0,
+                                  "continuous_tokens_per_s": 4000.0},
+            "churn": {"requests": 32, "tokens": 586},
+            "weight_stream": {"published": 3, "client_errors": 0}},
+        10: {"metric": "rec_smoke", "proxy": True, "rc": 0, "ok": True,
+             "lookup_exchange": {"bitwise_vs_dense": True,
+                                 "dedup_ratio": 0.404},
+             "table_bytes": {"f32": 6400, "int8": 2000, "ratio": 3.2},
+             "two_tower": {"loss_first": 0.69333, "loss_last": 0.69236}},
+    }
+
+
+def test_normalize_rounds_unifies_divergent_schemas(tmp_path):
     bt = _bench_trend()
-    rows = bt.normalize_rounds(bt.load_rounds(_REPO))
-    assert len(rows) >= 10
+    for n, doc in _round_docs().items():
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
+    rows = bt.normalize_rounds(bt.load_rounds(str(tmp_path)))
+    assert [r["round"] for r in rows] == [1, 2, 8, 9, 10]
     by_round = {r["round"]: r for r in rows}
     # r08 (compose matrix), r09 (no metric key), r10 (rec_smoke):
     # three different document shapes, one row schema
-    assert len(by_round[8]["metrics"]) > 20
+    assert by_round[8]["metrics"]["configs.total"] == 7.0
+    assert by_round[8]["metrics"]["configs.cfg3.wire"] == 3000.0
+    assert by_round[8]["metrics"]["configs.cfg3.bitwise"] == 1.0
+    assert by_round[9]["metric"] == "decode_smoke"
     assert by_round[9]["metrics"], "r09 metrics empty"
     assert by_round[10]["metrics"], "r10 metrics empty"
-    for r in rows:                      # wedged rounds keep their gap
-        if r["mode"] == "FAILED":
-            assert r["metrics"] == {}
+    assert by_round[1]["mode"] == "hardware"
+    assert by_round[1]["metrics"]["value"] == 2609.02
+    assert by_round[2]["mode"] == "FAILED"     # a failed round keeps
+    assert by_round[2]["metrics"] == {}        # its gap in the table
     bench = regress.bench_rows(rows)
     assert all(b["source"].startswith("bench:r") for b in bench)
 

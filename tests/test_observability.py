@@ -207,7 +207,6 @@ def test_static_byte_accounting():
 def test_allreduce_accounts_to_active_recorder():
     from bigdl_tpu.parallel.allreduce import allreduce_gradients
     from bigdl_tpu.parallel import mesh as mesh_lib
-    from bigdl_tpu.parallel._compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = mesh_lib.create_mesh({"dp": 4})
@@ -217,7 +216,8 @@ def test_allreduce_accounts_to_active_recorder():
         def f(g):
             return allreduce_gradients({"w": g}, "dp",
                                        compress="bf16")["w"]
-        out = jax.jit(shard_map(f, mesh, (P(),), P()))(
+        out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(),),
+                                    out_specs=P(), check_vma=False))(
             jnp.ones((8, 4), jnp.float32))
         np.testing.assert_allclose(np.asarray(out), 1.0)
     finally:

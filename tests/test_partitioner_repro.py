@@ -1,5 +1,5 @@
 """Checked-in repro for the GSPMD partitioner miscompile that forced the
-TokenEmbedding fsdp exemption (VERDICT r2 item 3 / NOTES r2 item 2).
+TokenEmbedding fsdp exemption.
 
 Minimal form, no shard_map, forward only, fp32:
 

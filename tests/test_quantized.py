@@ -169,8 +169,8 @@ def test_quantize_graph_dag_model():
 
 
 def test_quantized_dilated_conv_close_to_float_and_serde():
-    """QuantizedSpatialDilatedConvolution (VERDICT r2 item 9;
-    ≙ nn/quantized/SpatialDilatedConvolution.scala:30) + v2-serde
+    """QuantizedSpatialDilatedConvolution
+    (≙ nn/quantized/SpatialDilatedConvolution.scala:30) + v2-serde
     round-trip for quantized models (≙ QuantSerializer.scala)."""
     import os
     import tempfile

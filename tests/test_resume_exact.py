@@ -1,5 +1,5 @@
 """Kill-and-resume reproduces the uninterrupted loss curve EXACTLY
-(VERDICT r3 item 6; ≙ DistriOptimizer.scala:878-914 retry-from-cache).
+(≙ DistriOptimizer.scala:878-914 retry-from-cache).
 
 The checkpoint carries the iterator position (epoch, batch_in_epoch) and
 the loop rng; datasets shuffle with an epoch-seeded stateless

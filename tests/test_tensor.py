@@ -208,7 +208,7 @@ def test_jit_sparse_flows():
 
 
 def test_sparse_tensor_surface():
-    """Widened SparseTensor ops (VERDICT r2 weak 4; the reference's
+    """Widened SparseTensor ops (the reference's
     implemented subset: narrow/select/concat/transpose/numNonZeroByRow/
     apply1 — tensor/SparseTensor.scala)."""
     import numpy as np

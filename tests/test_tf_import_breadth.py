@@ -1,4 +1,4 @@
-"""TF GraphDef importer breadth (VERDICT r2 item 5): a generated
+"""TF GraphDef importer breadth: a generated
 slim-style graph with 50+ nodes exercising Split/Pack/Unpack/Fill/
 Conv2DBackpropInput/StridedSlice/Cast/Shape/GatherV2/Select and
 constant-folded Switch/Merge control flow whose untaken branch contains

@@ -1,4 +1,4 @@
-"""TF v1 while-loop import (VERDICT r3 item 5): Enter/Merge/LoopCond/
+"""TF v1 while-loop import: Enter/Merge/LoopCond/
 Switch/NextIteration/Exit frames lower to ONE lax.while_loop
 (≙ nn/tf/ControlOps.scala:182-229 + nn/FrameManager.scala:31, which
 interpret the same frames at runtime).
@@ -270,7 +270,7 @@ def test_imported_loop_trains():
 
 def test_strided_slice_ellipsis_new_axis_masks():
     """x[1, ..., tf.newaxis, ::2] — ellipsis + new_axis + shrink masks
-    against real TF numerics (VERDICT r3 item 9)."""
+    against real TF numerics."""
     tf = pytest.importorskip("tensorflow")
     x0 = np.arange(2 * 3 * 4 * 6, dtype=np.float32).reshape(2, 3, 4, 6)
 
@@ -314,8 +314,8 @@ def test_strided_slice_newaxis_leading():
 
 
 def test_topk_and_fused_bn_side_outputs():
-    """Multi-output slots beyond Split/Unpack/Switch (VERDICT r3
-    missing-6): TopKV2 values+indices, FusedBatchNorm batch_mean slot."""
+    """Multi-output slots beyond Split/Unpack/Switch: TopKV2
+    values+indices, FusedBatchNorm batch_mean slot."""
     tf = pytest.importorskip("tensorflow")
     x0 = np.random.RandomState(3).rand(2, 8).astype(np.float32)
 

@@ -378,7 +378,6 @@ def test_unsharded_leaf_counter_and_log(caplog):
     import logging
     from bigdl_tpu.parallel.allreduce import (allgather_params,
                                               reduce_scatter_gradients)
-    from bigdl_tpu.parallel._compat import shard_map
     mesh = mesh_lib.create_mesh({"dp": 8})
     rec = Recorder(sinks=[InMemorySink()])
     set_recorder(rec)
@@ -392,7 +391,9 @@ def test_unsharded_leaf_counter_and_log(caplog):
 
         with caplog.at_level(logging.DEBUG,
                              logger="bigdl_tpu.parallel.allreduce"):
-            jax.jit(shard_map(f, mesh, (P(),), P()))(grads)
+            jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(),),
+                                  out_specs=P(),
+                                  check_vma=False))(grads)
         snap = rec.snapshot()["counters"]
         assert snap.get("comm/unsharded_leaves") == 1.0     # 'odd'
         assert snap.get("comm/ungathered_leaves") == 1.0
