@@ -102,7 +102,9 @@ class TraceRing:
         trace id so one id spans admission → failover → decode."""
         if ctx is not None:
             return RequestTrace(ctx.trace_id, model, ctx=ctx)
-        return RequestTrace(uuid.uuid4().hex[:16], model)
+        # a whole W3C trace id, so the request's spans in the SpanStore
+        # (a TraceContext takes no shorter one) carry this very id
+        return RequestTrace(uuid.uuid4().hex, model)
 
     def finish(self, trace: RequestTrace):
         with self._lock:

@@ -7,9 +7,12 @@ One :class:`Recorder` instance aggregates four primitive kinds:
   gauges      last-written values (queue depth, bytes-per-step) —
               ``gauge``
   spans       wall-clock timed regions (``with rec.span("data_fetch")``),
-              accumulated per step and mirrored as
+              accumulated per step, mirrored as
               ``jax.profiler.TraceAnnotation`` so they line up with
-              device events on an XLA trace
+              device events on an XLA trace, and kept one by one (name,
+              start, end, parent) in the process default
+              :class:`~.tracing.Tracer`'s store, where a reader with no
+              handle on the recorder finds them after the run
   histograms  per-step value distributions kept as count/min/max/
               sum/sumsq plus a bounded recent-sample window for
               p50/p95/p99 quantiles — ``observe``; read back via
@@ -28,6 +31,7 @@ shallow view.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -36,6 +40,10 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 from . import context as _trace_clock
+from . import tracing as _tracing
+
+# ids of recorder spans: a counter, not uuid4 (a decode tick mints eight)
+_span_ids = itertools.count(1)
 
 
 class _NullSpan:
@@ -48,34 +56,62 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
+    def discard(self):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_rec", "_name", "_t0", "_ann")
+    """One timed region.  Its interval takes in the span's own
+    bookkeeping (the start is read first and the end last), so spans
+    opened back to back leave no gap that nothing accounts for."""
 
-    def __init__(self, rec: "Recorder", name: str):
+    __slots__ = ("_rec", "_name", "_annotate", "_trace_id", "_args",
+                 "_t0", "_ann", "_ctx", "_keep")
+
+    def __init__(self, rec: "Recorder", name: str, annotate: bool,
+                 trace_id: Optional[str], args: Dict[str, Any]):
         self._rec = rec
         self._name = name
+        self._annotate = annotate
+        self._trace_id = trace_id
+        self._args = args
         self._ann = None
+        self._keep = True
+
+    def set(self, **args):
+        """Attach arguments known only once the region is under way."""
+        self._args.update(args)
+
+    def discard(self):
+        """Leave nothing behind: no sum, no stored span (the region
+        turned out not to be what its name says)."""
+        self._keep = False
 
     def __enter__(self):
-        if self._rec.annotate:
-            import jax
-            self._ann = jax.profiler.TraceAnnotation(self._name)
-            self._ann.__enter__()
         # one trace clock across the repo (context.trace_now =
         # time.monotonic); perf_counter here used to skew merged
         # Perfetto timelines against the serving TraceRing's stamps
         self._t0 = _trace_clock.trace_now()
+        self._ctx = self._rec._open_span(self._trace_id)
+        if self._annotate:
+            import jax
+            self._ann = jax.profiler.TraceAnnotation(self._name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        dt = _trace_clock.trace_now() - self._t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        self._rec._add_span(self._name, dt)
+        self._rec._close_span(self._ctx)
+        if self._keep:
+            self._rec._record_span(self._name, self._ctx, self._t0,
+                                   _trace_clock.trace_now(), self._args)
         return False
 
 
@@ -85,7 +121,8 @@ class Recorder:
     ``sinks`` is any iterable of objects with ``emit(record: dict)``
     (see :mod:`~bigdl_tpu.observability.sinks`).  ``annotate`` mirrors
     spans onto the jax profiler timeline (cheap; only meaningful while
-    a trace is being captured).
+    a trace is being captured).  Every span is also kept as a
+    :class:`~.tracing.Span` in the process default tracer's store.
     """
 
     def __init__(self, sinks=(), enabled: bool = True,
@@ -101,6 +138,11 @@ class Recorder:
         # pending per-step state, reset by end_step
         self._spans: Dict[str, float] = {}
         self._span_counts: Dict[str, int] = {}
+        # stored spans: the trace they belong to unless a span names
+        # its own, and per thread the contexts of the spans now open
+        # (the innermost is the parent of the next one)
+        self._trace_id = f"{next(_span_ids):032x}"
+        self._open = threading.local()
         self._scalars: Dict[str, float] = {}
         self._hists: Dict[str, List[float]] = {}
         # bounded raw-sample window per histogram so percentiles
@@ -363,22 +405,70 @@ class Recorder:
         with self._lock:
             return list(self._hists)
 
-    def span(self, name: str):
-        """Context manager timing a region into the current step."""
+    def span(self, name: str, *, annotate: bool = True,
+             trace_id: Optional[str] = None, **args):
+        """Context manager timing a region into the current step and
+        into the default tracer's store.  Its parent there is the span
+        open on this thread when it begins; ``trace_id`` files it under
+        another trace than the recorder's own (a request's), ``args``
+        ride on the stored span.  ``annotate=False`` keeps a span that
+        only groups others off the profiler's timeline, where it would
+        cover every gap its children explain.  The handle's ``set()``
+        adds arguments later and ``discard()`` drops the span."""
         if not self._enabled:
             return _NULL_SPAN
-        return _Span(self, name)
+        return _Span(self, name, annotate and self.annotate, trace_id, args)
 
-    def _add_span(self, name: str, dt: float):
-        with self._lock:
-            self._spans[name] = self._spans.get(name, 0.0) + dt
-            self._span_counts[name] = self._span_counts.get(name, 0) + 1
-
-    def add_span(self, name: str, seconds: float):
-        """Record an externally-timed duration as a span."""
+    def add_span(self, name: str, seconds: float, *,
+                 trace_id: Optional[str] = None, **args):
+        """Record an externally-timed duration as a span whose interval
+        ends now."""
         if not self._enabled:
             return
-        self._add_span(name, seconds)
+        t1 = _trace_clock.trace_now()
+        self._record_span(name, self._span_context(trace_id),
+                          t1 - seconds, t1, args)
+
+    def _open_stack(self) -> list:
+        """Contexts of the spans now open on this thread."""
+        try:
+            return self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+            return stack
+
+    def _span_context(self, trace_id: Optional[str]):
+        """Identity of a span that begins on this thread now: a child
+        of the innermost open span, in its trace unless told another."""
+        stack = self._open_stack()
+        parent = stack[-1] if stack else None
+        if trace_id is None:
+            trace_id = parent.trace_id if parent is not None \
+                else self._trace_id
+        return _trace_clock.TraceContext(
+            trace_id, f"{next(_span_ids):016x}",
+            None if parent is None else parent.span_id)
+
+    def _open_span(self, trace_id: Optional[str]):
+        ctx = self._span_context(trace_id)
+        self._open.stack.append(ctx)
+        return ctx
+
+    def _close_span(self, ctx):
+        stack = self._open.stack
+        if stack[-1] is ctx:
+            stack.pop()
+        else:                       # spans closed out of order
+            stack.remove(ctx)
+
+    def _record_span(self, name: str, ctx, t0: float, t1: float,
+                     args: Dict[str, Any]):
+        with self._lock:
+            self._spans[name] = self._spans.get(name, 0.0) + (t1 - t0)
+            self._span_counts[name] = self._span_counts.get(name, 0) + 1
+        # the tracer is looked up now, so set_tracer redirects spans
+        _tracing.get_tracer().store.add(
+            _tracing.Span(name, ctx, t0, t1, args=args))
 
     # -- step lifecycle -------------------------------------------------- #
     def start_step(self, step: Optional[int] = None):

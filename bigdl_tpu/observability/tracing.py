@@ -41,7 +41,10 @@ A process-global default tracer (:func:`get_tracer` /
 :func:`set_tracer`, mirroring the Recorder's accessors) lets deep
 call sites — the checkpoint writer thread, the device-pool ledger —
 record spans without threading a tracer through every signature;
-components that take an explicit ``tracer=`` still win over it.
+components that take an explicit ``tracer=`` still win over it.  Every
+span a :class:`~.recorder.Recorder` times lands in it as well (the
+decode tick's ``decode.*`` family among them), which is how a reader
+with no handle on an engine finds the program's timeline after a run.
 
 Counters: a full store increments ``trace/spans_dropped`` semantics on
 the store itself (``SpanStore.dropped``); the ``trace/*`` recorder
@@ -246,7 +249,13 @@ class Tracer:
 
 
 # -- process-global default tracer (mirrors recorder.get_recorder) ------ #
-_default_tracer = Tracer()
+# Every Recorder span lands here, so the default store holds a whole
+# serving run for a reader that comes after it: 30 s and a drain at about
+# 55 decode ticks a second and 8 spans a tick, plus 3 a request, is
+# about 20k spans; 64k leaves three times that (a Span is about 0.5 kB).
+# Beyond it the ring drops its oldest and counts them in `dropped`.
+DEFAULT_CAPACITY = 65536
+_default_tracer = Tracer(DEFAULT_CAPACITY)
 _tracer_lock = threading.Lock()
 
 

@@ -1180,7 +1180,11 @@ class Optimizer:
             # from a mid-epoch checkpoint/validation now fold into the
             # NEXT step's record, same as epoch-boundary ones always did.)
             if rec.enabled:
-                self._emit_step_record(rec, size, loss, opt_state, health)
+                # the record floats the loss, which waits for the step:
+                # on the profiler's timeline that wait has this name
+                with rec.span("step_record"):
+                    self._emit_step_record(rec, size, loss, opt_state,
+                                           health)
             fired_stop = self._fire_mid_epoch(params, opt_state, model_state)
             if fired_stop:
                 stop = True

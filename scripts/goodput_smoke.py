@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""Goodput-ledger smoke + proxy-regression sentinel (ISSUE 20
-acceptance, CI ``goodput-smoke``).
+"""Goodput-ledger smoke (ISSUE 20 acceptance, CI ``goodput-smoke``).
 
 **Leg 1 — train: every preemption second lands in a named bucket.**
 An :class:`ElasticSupervisor` trains ``{"dp": 4}`` over a mutable
@@ -30,12 +29,7 @@ claimed → ``pool_idle``, kept disjoint from job badput) roll into one
 fleet document, written to disk and rendered by
 ``trace_summary.py goodput`` — the render is asserted, not just run.
 
-**Regression sentinel.**  Both ledger snapshots become sentinel rows,
-checked against the committed bounds in
-``artifacts/goodput_baseline.json``: a ledger metric may only regress
-past its bound with a committed justification, and a badput bucket
-growing past its recorded ceiling fails CI.  Emits ONE
-machine-parseable JSON line last (the CI contract).
+Emits ONE machine-parseable JSON line last (the CI contract).
 """
 import json
 import os
@@ -65,7 +59,6 @@ from bigdl_tpu.fleet import DevicePool                     # noqa: E402
 from bigdl_tpu.models import transformer as T              # noqa: E402
 from bigdl_tpu.observability import (JsonlSink,            # noqa: E402
                                      Recorder, SeriesStore)
-from bigdl_tpu.observability import regress                # noqa: E402
 from bigdl_tpu.observability.goodput import rollup         # noqa: E402
 from bigdl_tpu.serving import (DecodeEngine,               # noqa: E402
                                ModelRegistry)
@@ -332,29 +325,6 @@ def main():
           and "top gap" in ts.stdout,
           "trace_summary goodput renders the waterfall")
 
-    # -- regression sentinel: ledger fractions ------------------------ #
-    rows = [regress.ledger_row("train", tr["snap"]),
-            regress.ledger_row("serve", sv["set"])]
-    baseline = regress.load_baseline(
-        os.path.join(_REPO, "artifacts", "goodput_baseline.json"))
-    findings = regress.check(rows, baseline)
-    rec = Recorder(annotate=False)
-    rec.inc("regress/checks")
-    for f in findings:
-        print(f"# sentinel {f.render()}", flush=True)
-        if f.severity == "fail":
-            rec.inc("regress/failures")
-        elif f.severity == "waived":
-            rec.inc("regress/waived")
-        else:
-            rec.inc("regress/advisories")
-    check(regress.gate(findings),
-          f"regression sentinel passes: no ledger metric regressed past "
-          f"its committed bound without justification "
-          f"({len(findings)} findings, "
-          f"{sum(1 for f in findings if f.severity == 'waived')} "
-          f"waived)")
-
     summary = {
         "metric": "goodput_smoke",
         "ok": not FAILURES,
@@ -364,9 +334,6 @@ def main():
         "fleet_goodput_fraction": round(roll["goodput_fraction"], 4),
         "pool_idle_s": round(roll["pool_idle_s"], 3),
         "conservation_error": round(roll["conservation_error"], 5),
-        "sentinel_findings": len(findings),
-        "sentinel_failures": sum(
-            1 for f in findings if f.severity == "fail"),
         "goodput_doc": doc_path,
         "workdir": out_dir,
     }

@@ -27,7 +27,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from bigdl_tpu import faults
 from bigdl_tpu.models import transformer as T
+from bigdl_tpu.observability import Recorder, critical_path
+from bigdl_tpu.observability.tracing import Tracer, set_tracer
 from bigdl_tpu.serving import (CanaryPublisher, CanaryRejectedError,
                                DecodeEngine, LoadShedError,
                                ModelRegistry, PagePoolError, PagedKVCache,
@@ -487,6 +490,190 @@ def test_replica_predict_never_splits_a_prompt(lm):
     ok = rs.predict("lm", np.arange(1, 7, dtype=np.int32), timeout=60)
     assert ok.shape == (10,)
     rs.shutdown()
+
+
+# --------------------------------------------------------------------- #
+# the tick's timeline (decode.tick and its six leaves in the SpanStore)  #
+# --------------------------------------------------------------------- #
+LEAVES = ["decode.admit", "decode.schedule", "decode.stage",
+          "decode.dispatch", "decode.sync", "decode.emit"]
+
+
+@pytest.fixture
+def span_store():
+    """A tracer of the test's own as the process default, so the spans
+    of other tests' engines stay out of the count."""
+    tracer = Tracer(capacity=20000)
+    prev = set_tracer(tracer)
+    yield tracer.store
+    set_tracer(prev)
+
+
+def hand_driven(lm, **kw):
+    """An engine whose decode thread never starts: the test calls
+    `_tick()` itself, so what a tick leaves can be counted exactly."""
+    eng = small_engine(lm, **kw)
+    eng._ensure_loop_locked = lambda: None
+    return eng
+
+
+def drive(eng):
+    ticks = 0
+    while eng.pending_rows():
+        eng._tick()
+        ticks += 1
+        assert ticks < 1000
+    return ticks
+
+
+def tick_rows(store):
+    """[(tick span, its children in order of their start)]"""
+    spans = store.spans()
+    kids = {}
+    for s in spans:
+        kids.setdefault(s.context.parent_span_id, []).append(s)
+    return [(t, sorted(kids.get(t.context.span_id, []), key=lambda s: s.t0))
+            for t in spans if t.name == "decode.tick"]
+
+
+def test_every_stepped_tick_leaves_six_ordered_leaves_that_cover_it(
+        lm, span_store):
+    # a step of the toy model takes 2 ms, of which the spans' own
+    # bookkeeping is 3%: the fault plane's delay (it fires inside
+    # `decode.schedule`) makes a tick as long as one on the chip
+    faults.arm("serving.decode_step:delay:15")
+    eng = small_engine(lm)
+    try:
+        streams = [eng.stream("lm", np.arange(1, 5 + i, dtype=np.int32),
+                              max_new_tokens=6) for i in range(6)]
+        for st in streams:
+            assert len(list(st.tokens())) == 6
+    finally:
+        faults.disarm()
+        eng.shutdown()
+    assert span_store.dropped == 0
+    rows = tick_rows(span_store)
+    assert len(rows) == eng.recorder.counter_value("decode/steps") > 0
+    assert [t.args["step"] for t, _ in rows] == list(range(len(rows)))
+    covered = total = 0.0
+    coverage = []
+    for tick, kids in rows:
+        assert [k.name for k in kids] == LEAVES
+        assert {k.trace_id for k in kids} == {tick.trace_id}
+        assert tick.t0 <= kids[0].t0 and kids[-1].t1 <= tick.t1
+        for a, b in zip(kids, kids[1:]):
+            assert a.t0 <= a.t1 <= b.t0          # disjoint, in order
+        coverage.append(
+            critical_path([(k.name, k.t0, k.t1) for k in kids])["coverage"])
+        covered += sum(k.duration() for k in kids)
+        total += tick.duration()
+    # over the median tick and over all of them (one tick may be cut in
+    # two by the scheduler of a busy test machine)
+    assert sorted(coverage)[len(coverage) // 2] >= 0.98
+    assert covered / total >= 0.98
+    # the parent stays off the profiler's timeline, the leaves go onto it
+    assert eng.recorder.annotate
+
+
+def test_request_queue_and_prefill_share_its_trace_id(lm, span_store):
+    eng = small_engine(lm)
+    try:
+        st = eng.stream("lm", np.arange(1, 9, dtype=np.int32),
+                        max_new_tokens=3)
+        assert len(list(st.tokens())) == 3
+        st.result(5.0)
+    finally:
+        eng.shutdown()
+    (tr,) = eng.trace_ring.traces()
+    mine = span_store.by_trace(tr.trace_id)
+    assert sorted(s.name for s in mine) == ["decode.prefill", "decode.queue"]
+    queue, prefill = sorted(mine, key=lambda s: s.name, reverse=True)
+    assert prefill.args == {"bucket": 8}
+    by_name = {name: (t0, t1) for name, t0, t1, _ in tr.spans
+               if name in ("queue", "prefill")}
+    # the queue span runs from the request's arrival to the start of its
+    # prefill, and the prefill ends where the first token is stamped
+    assert queue.t1 == pytest.approx(by_name["queue"][1], abs=2e-3)
+    assert queue.t1 <= prefill.t0
+    first_token = min(t0 for name, t0, _, _ in tr.spans if name == "token")
+    assert prefill.t1 == pytest.approx(first_token, abs=2e-3)
+    # both lie inside the admit leaf of the tick that took the request in
+    admit = [s for s in span_store.spans()
+             if s.context.span_id == prefill.context.parent_span_id]
+    assert [s.name for s in admit] == ["decode.admit"]
+    # the runner's reading of the prefill span is the sum of its intervals
+    assert eng.recorder.span_value("decode.prefill") == \
+        pytest.approx(prefill.duration())
+
+
+def test_tick_path_mints_no_uuid_and_a_fixed_count_of_spans(
+        lm, span_store, monkeypatch):
+    import uuid
+    calls = []
+    real = uuid.uuid4
+
+    def counting():
+        calls.append(1)
+        return real()
+
+    monkeypatch.setattr(uuid, "uuid4", counting)
+    counts = []
+    for _ in range(2):          # the count repeats exactly
+        eng = hand_driven(lm)
+        span_store.clear()      # the warm-up's compile spans
+        try:
+            streams = [eng.stream("lm", np.arange(1, 6, dtype=np.int32),
+                                  max_new_tokens=5) for _ in range(3)]
+            assert calls            # a request's trace id is a uuid ...
+            del calls[:]
+            ticks = drive(eng)
+            assert not calls        # ... and nothing on the tick path is
+            for st in streams:
+                assert len(st.result(0)) == 10
+        finally:
+            eng.shutdown()
+        # all three are admitted by the first tick and finish together:
+        # every tick steps, and leaves its parent and six leaves; every
+        # request leaves its queue and prefill spans
+        assert ticks == 4
+        assert len(span_store) == 7 * ticks + 2 * len(streams)
+        counts.append(len(span_store))
+    assert counts[0] == counts[1]
+
+
+def test_a_tick_that_runs_no_step_leaves_no_tick_span(lm, span_store):
+    eng = hand_driven(lm)
+    span_store.clear()          # the warm-up's compile spans
+    try:
+        st = eng.stream("lm", np.arange(1, 6, dtype=np.int32),
+                        max_new_tokens=1)
+        assert drive(eng) == 1      # the prefill's token finishes it
+        assert len(st.result(0)) == 6
+    finally:
+        eng.shutdown()
+    names = sorted(s.name for s in span_store.spans())
+    assert names == ["decode.admit", "decode.prefill", "decode.queue"]
+
+
+def test_disabled_recorder_engine_leaves_no_spans(lm, span_store):
+    eng = hand_driven(lm, recorder=Recorder(enabled=False))
+    try:
+        st = eng.stream("lm", np.arange(1, 6, dtype=np.int32),
+                        max_new_tokens=3)
+        drive(eng)
+        assert len(st.result(0)) == 8
+    finally:
+        eng.shutdown()
+    assert len(span_store) == 0
+
+
+def test_replica_engines_annotate_their_leaves(lm):
+    rs = build_decode_replica_set(lm, 2, engine_kw=dict(
+        slots=2, page_size=8, max_context=32, max_prompt=16))
+    try:
+        assert all(r.engine.recorder.annotate for r in rs.replicas)
+    finally:
+        rs.shutdown()
 
 
 def test_trace_summary_decode_table():

@@ -1,12 +1,9 @@
 """Goodput ledger (ISSUE 20): exclusive-bucket conservation, span and
 split folding, phase nesting across threads, pool ownership roll-up,
-the /goodput endpoint, the proxy-regression sentinel, BENCH-round
-normalization, and the racecheck-harness proof that concurrent
+the /goodput endpoint, and the racecheck-harness proof that concurrent
 replica-kill + checkpoint-commit + autoscale-shrink attribution never
 double-books a device-second."""
-import importlib.util
 import json
-import os
 import threading
 import time
 import urllib.request
@@ -14,12 +11,10 @@ import urllib.request
 import pytest
 
 from bigdl_tpu.analysis.racecheck import RaceCheck, wrap_lock
-from bigdl_tpu.observability import Recorder, regress
+from bigdl_tpu.observability import Recorder
 from bigdl_tpu.observability.goodput import (BUCKETS, GoodputLedger,
                                              OwnershipLedger,
                                              ledger_phase, rollup)
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class FakeClock:
@@ -309,149 +304,6 @@ def test_device_pool_notes_occupancy_into_its_ownership_ledger():
     pool.release("train")
     snap2 = pool.goodput.snapshot()
     assert snap2["claimed"] == 0
-
-
-# --------------------------------------------------------------------- #
-# regression sentinel                                                   #
-# --------------------------------------------------------------------- #
-def _row(source, **metrics):
-    return {"source": source, "metrics": metrics}
-
-
-def test_sentinel_fails_undocumented_regression_waives_justified():
-    rows = [_row("bench:r09", tps=100.0)]
-    findings = regress.check(rows, {"metrics": {
-        "bench:r09/tps": {"min": 150.0}}})
-    assert [f.severity for f in findings] == ["fail"]
-    assert not regress.gate(findings)
-    findings = regress.check(rows, {"metrics": {
-        "bench:r09/tps": {"min": 150.0,
-                          "justification": "known CPU-proxy slowdown"}}})
-    assert [f.severity for f in findings] == ["waived"]
-    assert regress.gate(findings)
-
-
-def test_sentinel_bucket_ceiling_applies_to_every_ledger_row():
-    led, clk = _led()
-    with led.phase("checkpoint_blocking"):
-        clk.tick(8.0)
-    clk.tick(2.0)
-    rows = [_row("bench:r09", tps=1.0),
-            regress.ledger_row("train", led.snapshot())]
-    findings = regress.check(rows, {"buckets": {
-        "checkpoint_blocking": {"max_fraction": 0.5}}})
-    assert len(findings) == 1
-    f = findings[0]
-    assert f.severity == "fail" and not regress.gate(findings)
-    assert f.key == "ledger:train/buckets.checkpoint_blocking"
-    assert f.value == pytest.approx(0.8)
-
-
-def test_sentinel_stale_bound_and_change_point_are_advisory():
-    rows = [_row("bench:r07", x=10.0), _row("bench:r08", x=10.5),
-            _row("bench:r09", x=9.8), _row("bench:r10", x=95.0)]
-    findings = regress.check(
-        rows, {"metrics": {"bench:r10/x": {"min": 1.0}},
-               "watch": ["bench:*/x"]})
-    sev = sorted(f.severity for f in findings)
-    assert sev == ["info", "info"]          # stale bound + change-point
-    assert regress.gate(findings)
-    assert any("change-point" in f.message for f in findings)
-
-
-def test_sentinel_missing_source_or_metric_is_info_not_fail():
-    findings = regress.check([_row("bench:r09", tps=1.0)], {"metrics": {
-        "bench:r03/gone": {"min": 1.0},
-        "bench:r09/absent": {"max": 2.0}}})
-    assert all(f.severity == "info" for f in findings)
-    assert regress.gate(findings)
-
-
-def test_ledger_row_folds_buckets_to_fractions_of_owned():
-    led, clk = _led(devices=2)
-    clk.tick(5.0)
-    led.fold_split({"goodput": 3.0, "queue_wait": 2.0})
-    row = regress.ledger_row("serve", led.snapshot())
-    assert row["source"] == "ledger:serve"
-    assert row["metrics"]["buckets.goodput"] == pytest.approx(0.6)
-    assert row["metrics"]["buckets.queue_wait"] == pytest.approx(0.4)
-    assert row["metrics"]["conservation_error"] <= 1e-9
-    assert row["metrics"]["owned_s"] == pytest.approx(10.0)
-
-
-def test_committed_baseline_parses_and_names_real_buckets():
-    base = regress.load_baseline(
-        os.path.join(_REPO, "artifacts", "goodput_baseline.json"))
-    assert base["metrics"], "baseline must bound at least one metric"
-    for b in (base.get("buckets") or {}):
-        assert b in BUCKETS, f"unknown bucket {b!r} in baseline"
-
-
-# --------------------------------------------------------------------- #
-# BENCH-round normalization (bench_trend)                               #
-# --------------------------------------------------------------------- #
-def _bench_trend():
-    spec = importlib.util.spec_from_file_location(
-        "bench_trend", os.path.join(_REPO, "scripts", "bench_trend.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _round_docs():
-    """One document per schema the driver has banked: a parsed chip line,
-    a failed round, and the three smoke shapes (compose matrix, decode
-    with no metric key anywhere, rec_smoke)."""
-    return {
-        1: {"n": 1, "cmd": "python bench.py", "rc": 0,
-            "tail": "2026-07-29 18:29:49 ...",
-            "parsed": {"metric": "resnet50_train_images_per_sec_per_chip",
-                       "value": 2609.02, "unit": "images/sec",
-                       "vs_baseline": 45.772}},
-        2: {"n": 2, "cmd": "python bench.py", "rc": 2,
-            "tail": "2026-07-30 15:49:33 backend unreachable",
-            "parsed": None},
-        8: {"bench": "compose_proxy_smoke", "proxy": True, "devices": 8,
-            "configs": {
-                "dp2_tp2_pp2": {"status": "trained"},
-                **{f"cfg{i}": {"losses": [4.0 - i, 3.0 - i],
-                               "wire": 1000.0 * i, "bitwise": True}
-                   for i in range(6)}}},
-        9: {"n": 9, "cmd": "python scripts/decode_smoke.py", "rc": 0,
-            "proxy": True,
-            "decode_throughput": {"speedup": 1.6, "recompiles": 0,
-                                  "continuous_tokens_per_s": 4000.0},
-            "churn": {"requests": 32, "tokens": 586},
-            "weight_stream": {"published": 3, "client_errors": 0}},
-        10: {"metric": "rec_smoke", "proxy": True, "rc": 0, "ok": True,
-             "lookup_exchange": {"bitwise_vs_dense": True,
-                                 "dedup_ratio": 0.404},
-             "table_bytes": {"f32": 6400, "int8": 2000, "ratio": 3.2},
-             "two_tower": {"loss_first": 0.69333, "loss_last": 0.69236}},
-    }
-
-
-def test_normalize_rounds_unifies_divergent_schemas(tmp_path):
-    bt = _bench_trend()
-    for n, doc in _round_docs().items():
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
-    rows = bt.normalize_rounds(bt.load_rounds(str(tmp_path)))
-    assert [r["round"] for r in rows] == [1, 2, 8, 9, 10]
-    by_round = {r["round"]: r for r in rows}
-    # r08 (compose matrix), r09 (no metric key), r10 (rec_smoke):
-    # three different document shapes, one row schema
-    assert by_round[8]["metrics"]["configs.total"] == 7.0
-    assert by_round[8]["metrics"]["configs.cfg3.wire"] == 3000.0
-    assert by_round[8]["metrics"]["configs.cfg3.bitwise"] == 1.0
-    assert by_round[9]["metric"] == "decode_smoke"
-    assert by_round[9]["metrics"], "r09 metrics empty"
-    assert by_round[10]["metrics"], "r10 metrics empty"
-    assert by_round[1]["mode"] == "hardware"
-    assert by_round[1]["metrics"]["value"] == 2609.02
-    assert by_round[2]["mode"] == "FAILED"     # a failed round keeps
-    assert by_round[2]["metrics"] == {}        # its gap in the table
-    bench = regress.bench_rows(rows)
-    assert all(b["source"].startswith("bench:r") for b in bench)
 
 
 # --------------------------------------------------------------------- #

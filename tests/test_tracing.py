@@ -180,6 +180,108 @@ def test_recorder_spans_stamp_on_trace_clock(monkeypatch):
     assert abs(step["spans"]["work"] - 0.25) < 1e-9
 
 
+# --------------------------------------------------------------------- #
+# Recorder spans land in the SpanStore                                   #
+# --------------------------------------------------------------------- #
+@pytest.fixture
+def span_store():
+    tracer = Tracer(capacity=64)
+    prev = set_tracer(tracer)
+    yield tracer.store
+    set_tracer(prev)
+
+
+def test_recorder_span_leaves_a_span_with_parent_and_the_sum(span_store):
+    rec = Recorder(sinks=[InMemorySink()], annotate=False)
+    rec.start_step(0)
+    with rec.span("outer", annotate=False) as outer:
+        with rec.span("work", bucket=8):
+            time.sleep(0.002)
+        with rec.span("work"):
+            time.sleep(0.001)
+        outer.set(step=7)
+    step = rec.end_step()
+    by_name = {}
+    for s in span_store.spans():
+        assert s.t0 <= s.t1
+        by_name.setdefault(s.name, []).append(s)
+    (parent,) = by_name["outer"]
+    first, second = by_name["work"]
+    assert parent.context.parent_span_id is None
+    assert parent.args == {"step": 7}
+    assert first.args == {"bucket": 8} and second.args is None
+    for child in (first, second):
+        assert child.context.parent_span_id == parent.context.span_id
+        assert child.trace_id == parent.trace_id
+        assert parent.t0 <= child.t0 and child.t1 <= parent.t1
+    assert first.t1 <= second.t0
+    # the step record's sum is the sum of the stored intervals
+    assert step["spans"]["work"] == pytest.approx(
+        first.duration() + second.duration(), abs=1e-12)
+    assert step["span_counts"]["work"] == 2
+    assert step["spans"]["outer"] == pytest.approx(parent.duration(),
+                                                   abs=1e-12)
+
+
+def test_recorder_add_span_ends_at_the_call_under_a_given_trace(span_store):
+    rec = Recorder(annotate=False)
+    with rec.span("admit"):
+        before = trace_now()
+        rec.add_span("queue", 0.25, trace_id="ab" * 16)
+        after = trace_now()
+    queue, admit = span_store.spans()
+    assert (queue.name, admit.name) == ("queue", "admit")
+    assert queue.trace_id == "ab" * 16 != admit.trace_id
+    assert queue.context.parent_span_id == admit.context.span_id
+    assert before <= queue.t1 <= after
+    assert queue.duration() == pytest.approx(0.25)
+    assert rec.span_value("queue") == pytest.approx(0.25)
+    # a span of another thread has no parent here
+    with rec.span("admit"):
+        t = threading.Thread(target=lambda: rec.add_span("other", 0.0))
+        t.start()
+        t.join(5.0)
+        assert not t.is_alive()
+    other = [s for s in span_store.spans() if s.name == "other"]
+    assert other[0].context.parent_span_id is None
+
+
+def test_recorder_span_ids_are_counted_not_drawn(span_store, monkeypatch):
+    import uuid
+    monkeypatch.setattr(uuid, "uuid4", lambda: pytest.fail("uuid4 called"))
+    rec = Recorder(annotate=False)
+    for _ in range(3):
+        with rec.span("a"):
+            rec.add_span("b", 0.0)
+    ids = [s.context.span_id for s in span_store.spans()]
+    assert len(set(ids)) == 6 and all(len(i) == 16 for i in ids)
+    assert len({s.trace_id for s in span_store.spans()}) == 1
+
+
+def test_recorder_discarded_and_disabled_spans_leave_nothing(span_store):
+    rec = Recorder(annotate=False)
+    with rec.span("tick") as tick:
+        with rec.span("leaf"):
+            pass
+        tick.discard()
+    assert [s.name for s in span_store.spans()] == ["leaf"]
+    assert rec.span_value("tick") == 0.0
+    off = Recorder(enabled=False)
+    with off.span("tick") as tick:        # the shared null span
+        tick.set(step=1)
+        tick.discard()
+    off.add_span("queue", 1.0)
+    assert len(span_store) == 1 and off.span_value("queue") == 0.0
+
+
+def test_full_store_counts_what_recorder_spans_push_out(span_store):
+    rec = Recorder(annotate=False)
+    for _ in range(70):
+        with rec.span("s"):
+            pass
+    assert len(span_store) == 64 and span_store.dropped == 6
+
+
 def test_trace_now_is_monotonic_clock():
     # the documented contract: TRACE_CLOCK is time.monotonic — the
     # serving queue's native clock, so engine trace stamps match free
