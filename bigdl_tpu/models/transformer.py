@@ -228,7 +228,9 @@ class MultiHeadAttention(Module):
         offsets: x (B, 1, d_model), positions (B,).  Returns q, k, v
         each (B, H, 1, Dh) with RoPE applied to q/k at ``positions[b]``
         — the slot-batched half of :meth:`apply_cached`, split out so a
-        paged KV cache can own the write/gather in between."""
+        paged KV cache can own the write and the attention in between
+        (:func:`bigdl_tpu.ops.paged_attention.attend_window` is the
+        other half's math; :meth:`project_out` closes it)."""
         cfg = self.cfg
         p = self.own(params)
         b = x.shape[0]
@@ -244,36 +246,13 @@ class MultiHeadAttention(Module):
         v = proj(p["wv"])
         return q, k, v
 
-    def attend_window(self, params, q, k_win, v_win, positions):
-        """Single-token attention of q (B, H, 1, Dh) against an
-        externally gathered window k_win/v_win (B, H, W, Dh) — the
-        other half of :meth:`apply_cached`, with the same einsum /
-        scale / mask-value / softmax sequence so logits stay bitwise
-        comparable to the contiguous-cache path.  ``positions`` (B,)
-        is each row's token index; keys at ``k_pos > positions[b]``
-        (unwritten or other slots' future) are masked out."""
-        cfg = self.cfg
-        p = self.own(params)
-        b = q.shape[0]
-        dt = q.dtype
-        k_pos = jnp.arange(k_win.shape[2])
-        s_ = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        k_win.astype(jnp.float32)) / np.sqrt(cfg.head_dim)
-        # same semantics as _attn_mask(positions, k_pos, pos+1, True)
-        # per row: causal (k <= q) subsumes the kv_len bound at s=1
-        mask = k_pos[None, :] <= positions[:, None]          # (B, W)
-        s_ = jnp.where(mask[:, None, None, :], s_, DEFAULT_MASK_VALUE)
-        w_ = jax.nn.softmax(s_, axis=-1)
-        # masked weights are exactly 0, but 0 * NaN = NaN: a recycled
-        # KV page can hold non-finite rows from a poisoned/rejected
-        # publication, and they must not leak through the value sum —
-        # scrub masked V rows (a no-op for finite stale data, so the
-        # bitwise parity with the contiguous path is preserved)
-        v_ = jnp.where(mask[:, None, :, None],
-                       v_win.astype(jnp.float32), 0.0)
-        o = jnp.einsum("bhqk,bhkd->bhqd", w_, v_).astype(dt)
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, 1, cfg.d_model)
-        return jnp.dot(o, p["wo"].astype(dt))
+    def project_out(self, params, o):
+        """Output projection of a single-token attention result o
+        (B, H, 1, Dh) -> (B, 1, d_model): what follows the paged
+        cache's attention in :meth:`TransformerBlock.apply_decode`."""
+        b = o.shape[0]
+        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, 1, self.cfg.d_model)
+        return jnp.dot(o, self.own(params)["wo"].astype(o.dtype))
 
 
 class SwiGLU(Module):
@@ -346,15 +325,16 @@ class TransformerBlock(Module):
 
     def apply_decode(self, params, x, ctx, positions, kv_io):
         """Slot-batched single-token decode: x (B, 1, d_model),
-        positions (B,).  ``kv_io(attn_name, k_new, v_new) ->
-        (k_win, v_win)`` is the paged-KV seam — it writes this token's
-        k/v rows into the cache and returns the gathered attention
-        window (which must already contain the rows just written, the
-        same update-then-attend order :meth:`apply_cached` uses)."""
+        positions (B,).  ``kv_io(attn_name, q, k_new, v_new) -> o`` is
+        the paged-KV seam — it writes this token's k/v rows into the
+        cache and returns the attention of q (B, H, 1, Dh) over the
+        slot's keys, the rows just written included (the same
+        update-then-attend order :meth:`apply_cached` uses), as
+        (B, H, 1, Dh).  The cache owns the attention because how it is
+        computed depends on where the pages lie, not on the model."""
         h = self.norm1.apply(params, x, ctx)
         q, k, v = self.attn.project_qkv_rows(params, h, positions)
-        k_win, v_win = kv_io(self.attn.name, k, v)
-        h = x + self.attn.attend_window(params, q, k_win, v_win, positions)
+        h = x + self.attn.project_out(params, kv_io(self.attn.name, q, k, v))
         return h + self.mlp.apply(params, self.norm2.apply(params, h, ctx),
                                   ctx)
 
@@ -516,8 +496,8 @@ class TransformerLM(Module):
 
         ``tokens`` (B,) int32 are each slot's freshly emitted token,
         ``positions`` (B,) its global index (== the slot's current
-        sequence length), and ``kv_io(attn_name, k_new, v_new) ->
-        (k_win, v_win)`` the paged-cache write/gather seam (see
+        sequence length), and ``kv_io(attn_name, q, k_new, v_new) ->
+        o`` the paged-cache write/attend seam (see
         :meth:`TransformerBlock.apply_decode`).  Returns fp32 logits
         (B, V) for each slot's NEXT position.  Unlike
         :meth:`apply_with_cache` every batch row advances at its own

@@ -12,6 +12,9 @@ blockwise routes so every op also runs (and is differentiable) on CPU;
 from . import flash_attention as flash_attention_mod  # noqa: F401
 from .flash_attention import (flash_attention, attention_reference,
                               attention_path)
+from . import paged_attention as paged_attention_mod  # noqa: F401
+from .paged_attention import paged_attention, paged_attention_path
 
 __all__ = ["flash_attention", "attention_reference", "attention_path",
-           "flash_attention_mod"]
+           "flash_attention_mod", "paged_attention",
+           "paged_attention_path", "paged_attention_mod"]

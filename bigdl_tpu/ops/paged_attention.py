@@ -1,0 +1,258 @@
+"""Paged decode attention: Pallas TPU kernel + gathered-window route.
+
+One new token per slot attends to that slot's K and V where they lie in
+the page pool (``serving/kvcache.py``: ``(n_pages, page_size, n_heads,
+head_dim)``, one pool a layer), up to the slot's length, in the dtype
+they are stored in.
+
+  * kernel — one ``pallas_call`` a layer.  The pools stay in HBM; the
+    page tables and lengths arrive by scalar prefetch.  A scalar
+    prologue lists the work as (slot, block of pages) items, live slots
+    only, and one double-buffered loop walks the list: while item ``i``
+    is computed the pages of item ``i + 1`` (the next block of the same
+    slot, or the first of the next live slot) are already in flight.
+    One page is one contiguous copy for all heads.  Scores, softmax and
+    both accumulations are float32; K and V enter the MXU as stored.
+  * window route — :func:`attend_window` over a window gathered by
+    ``PagedKVCache.gather_window``: what runs on CPU, for an int8 pool,
+    and what the kernel is tested against.
+
+:func:`paged_attention_path` is the one place that decides which route a
+pool takes and says why (as ``ops.attention_path`` does for the flash
+kernel).
+
+The kernel keeps the pool's token-major layout, so K of a block is
+``(tokens * heads, head_dim)`` after a free reshape, and the scores of
+all heads come from ONE matmul ``q (heads, head_dim) @ K^T -> (heads,
+tokens * heads)`` of which only the entries whose column's head is the
+row's own are kept: ``heads`` times the useful FLOPs on an MXU that idles
+anyway, and no relayout of K or V.  The masked probabilities are exactly
+zero, so ``p @ V`` over the same flat axis is the per-head value sum.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import DEFAULT_MASK_VALUE
+
+# Test hook: when True the kernel runs in interpret mode, so the TPU
+# code path itself (not the window route) is exercised on CPU.
+_INTERPRET = False
+
+# Pages fetched per wait: 8 pages of 16 tokens are 128 tokens a block,
+# 512 KiB each of K and V in bf16 at 16 heads x 128, double-buffered.
+_PAGES_PER_BLOCK = 8
+
+
+def attend_window(q, k_win, v_win, positions):
+    """Single-token attention of q (B, H, 1, Dh) against a gathered
+    window k_win/v_win (B, H, W, Dh): the einsum / scale / mask-value /
+    softmax sequence of ``MultiHeadAttention.apply_cached``, in
+    float32.  ``positions`` (B,) is each row's token index; keys at
+    ``k_pos > positions[b]`` (unwritten, or a recycled page's stale
+    rows) are masked out.  Returns (B, H, 1, Dh) in q's dtype."""
+    k_pos = jnp.arange(k_win.shape[2])
+    s_ = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                    k_win.astype(jnp.float32)) / np.sqrt(q.shape[-1])
+    # same semantics as _attn_mask(positions, k_pos, pos+1, True) per
+    # row: causal (k <= q) subsumes the kv_len bound at s=1
+    mask = k_pos[None, :] <= positions[:, None]          # (B, W)
+    s_ = jnp.where(mask[:, None, None, :], s_, DEFAULT_MASK_VALUE)
+    w_ = jax.nn.softmax(s_, axis=-1)
+    # masked weights are exactly 0, but 0 * NaN = NaN: a recycled KV
+    # page can hold non-finite rows from a poisoned/rejected
+    # publication, and they must not leak through the value sum —
+    # scrub masked V rows (a no-op for finite stale data)
+    v_ = jnp.where(mask[:, None, :, None], v_win.astype(jnp.float32), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", w_, v_).astype(q.dtype)
+
+
+def paged_attention_path(pool_dtype, n_heads: int, head_dim: int, *,
+                         backend: Optional[str] = None) -> Tuple[str, str]:
+    """Which route decode attention takes over a pool of this dtype and
+    row geometry, and why: ``("pallas", reason)`` or ``("gather",
+    reason)``.  ``backend`` defaults to ``jax.default_backend()``."""
+    if backend is None:
+        backend = jax.default_backend()
+    if backend != "tpu" and not _INTERPRET:
+        return "gather", f"backend {backend!r} is not tpu"
+    dtype = jnp.dtype(pool_dtype)
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return "gather", (f"pool dtype {dtype.name} is not a float: the "
+                          "window route dequantizes")
+    if head_dim % 128:
+        return "gather", (f"head_dim {head_dim} is not a multiple of 128 "
+                          "(one lane tile)")
+    if n_heads % 8:
+        return "gather", (f"n_heads {n_heads} is not a multiple of 8 "
+                          "(one sublane tile)")
+    return "pallas", ("interpret mode" if backend != "tpu"
+                      else "tpu backend, float pool, rows tile")
+
+
+def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+            item_slot, item_blk, k_buf, v_buf, sem, *,
+            page_size, max_pages, sm_scale):
+    n_slots, n_heads, head_dim = q_ref.shape
+    ppb = k_buf.shape[1]
+    block = ppb * page_size               # tokens a block
+    flat = block * n_heads                # the matmuls' flat key axis
+    max_items = item_slot.shape[0]
+
+    def live_pages(s):
+        # a dead slot (no first page) has no work; a live one attends
+        # the token just written too: ceil((length + 1) / page_size)
+        return jnp.where(tables_ref[s * max_pages] >= 0,
+                         lengths_ref[s] // page_size + 1, 0)
+
+    def list_slot(s, n):
+        def put(b, n):
+            item_slot[n] = s
+            item_blk[n] = b
+            return n + 1
+        return lax.fori_loop(0, pl.cdiv(live_pages(s), ppb), put, n)
+
+    n_items = lax.fori_loop(0, n_slots, list_slot, 0)
+
+    def copies(i, buf, act):
+        s, b = item_slot[i], item_blk[i]
+        pages = live_pages(s)
+        for j in range(ppb):
+            pj = b * ppb + j
+            page = tables_ref[s * max_pages + jnp.minimum(pj, max_pages - 1)]
+
+            # a -1 entry is never dereferenced
+            @pl.when((pj < pages) & (page >= 0))
+            def _():
+                for hbm, vmem, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    act(pltpu.make_async_copy(
+                        hbm.at[page], vmem.at[buf, j], sem.at[which, buf]))
+
+    @pl.when(n_items > 0)
+    def _():
+        copies(0, 0, lambda c: c.start())
+
+    o_ref[...] = jnp.zeros_like(o_ref)    # dead slots read finite zeros
+
+    def body(i, carry):
+        m, l, acc = carry
+        buf = i % 2
+
+        @pl.when(i + 1 < n_items)
+        def _():
+            copies(i + 1, 1 - buf, lambda c: c.start())
+
+        copies(i, buf, lambda c: c.wait())
+        s, b = item_slot[i], item_blk[i]
+        # keys of this block that the slot attends: its first `keys`
+        keys = lengths_ref[s] + 1 - b * block
+        first = b == 0
+        m = jnp.where(first, DEFAULT_MASK_VALUE, m)
+        l = jnp.where(first, 0.0, l)
+        acc = jnp.where(first, 0.0, acc)
+
+        q = q_ref[s]                                      # (H, Dh)
+        k = k_buf[buf].reshape(flat, head_dim)
+        s_ = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32) * sm_scale
+        col = lax.broadcasted_iota(jnp.int32, (n_heads, flat), 1)
+        row = lax.broadcasted_iota(jnp.int32, (n_heads, flat), 0)
+        mask = (lax.rem(col, n_heads) == row) & (col < keys * n_heads)
+        s_ = jnp.where(mask, s_, DEFAULT_MASK_VALUE)
+        m_new = jnp.maximum(m, s_.max(axis=-1, keepdims=True))
+        p = jnp.exp(s_ - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+
+        # masked probabilities are exactly 0, but 0 * NaN = NaN: scrub
+        # the V rows past the slot's length (a recycled page's stale
+        # rows, or what an earlier item left in the buffer); only the
+        # slot's last block has any
+        @pl.when(keys < block)
+        def _():
+            shape = v_buf.shape[1:]
+            tok = (lax.broadcasted_iota(jnp.int32, shape, 0) * page_size
+                   + lax.broadcasted_iota(jnp.int32, shape, 1))
+            v_buf[buf] = jnp.where(tok < keys, v_buf[buf], 0)
+
+        v = v_buf[buf].reshape(flat, head_dim)
+        acc = alpha * acc + jnp.dot(p.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32)
+
+        @pl.when((i + 1 == n_items)
+                 | (item_slot[jnp.minimum(i + 1, max_items - 1)] != s))
+        def _():
+            o_ref[s] = (acc / l).astype(o_ref.dtype)
+
+        return m_new, l, acc
+
+    lax.fori_loop(0, n_items, body, (
+        jnp.full((n_heads, 1), DEFAULT_MASK_VALUE, jnp.float32),
+        jnp.zeros((n_heads, 1), jnp.float32),
+        jnp.zeros((n_heads, head_dim), jnp.float32)))
+
+
+def paged_attention(q, k_pool, v_pool, tables, lengths, *,
+                    pages_per_block: int = _PAGES_PER_BLOCK):
+    """Decode attention over a page pool, in place.
+
+    q (slots, heads, head_dim); k_pool / v_pool (n_pages, page_size,
+    heads, head_dim) as ``PagedKVCache`` lays them out; tables (slots,
+    max_pages) int32, ``-1`` where no page is held; lengths (slots,)
+    int32, each slot's length BEFORE the token just written, which is
+    attended too.  Returns (slots, heads, head_dim) in q's dtype; a
+    dead slot (``tables[s, 0] < 0``) reads zeros."""
+    return _paged_attention(q, k_pool, v_pool, tables, lengths,
+                            pages_per_block=int(pages_per_block),
+                            interpret=_INTERPRET)
+
+
+# jitted, so that a step which calls it once a layer traces the kernel
+# and lowers it to Mosaic once: sixteen lowerings of the same kernel were
+# 3 s of every DecodeEngine.warmup(), on a warm compile cache too
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+def _paged_attention(q, k_pool, v_pool, tables, lengths, *, pages_per_block,
+                     interpret):
+    n_slots, n_heads, head_dim = q.shape
+    _, page_size, _, _ = k_pool.shape
+    max_pages = tables.shape[1]
+    ppb = min(pages_per_block, max_pages)
+    max_items = n_slots * -(-max_pages // ppb)
+    buf = pltpu.VMEM((2, ppb, page_size, n_heads, head_dim), k_pool.dtype)
+    kernel = functools.partial(_kernel, page_size=page_size,
+                               max_pages=max_pages,
+                               sm_scale=head_dim ** -0.5)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec((n_slots, n_heads, head_dim),
+                             lambda i, *_: (0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((n_slots, n_heads, head_dim),
+                                   lambda i, *_: (0, 0, 0)),
+            scratch_shapes=[
+                pltpu.SMEM((max_items,), jnp.int32),
+                pltpu.SMEM((max_items,), jnp.int32),
+                buf, buf,
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+    )(tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      q, k_pool, v_pool)
+
+
+__all__ = ["paged_attention", "paged_attention_path", "attend_window"]

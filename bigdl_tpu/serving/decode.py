@@ -16,7 +16,9 @@ decodes at **slot** granularity instead:
                           prompt stream compiles NOTHING post-warmup)
                  step   → ONE jitted fixed-shape program advances every
                           live slot by one token (per-slot positions,
-                          page-table gather/scatter — kvcache.py)
+                          page-table scatter, then attention over the
+                          live pages — kvcache.py ``attend``: a Pallas
+                          kernel on a TPU, a gathered window elsewhere)
                  retire → eos / max_new / deadline: free the slot's
                           pages, complete the future, recycle the slot
                  evict  → a slot that cannot grow a page when the pool
@@ -31,12 +33,13 @@ decodes at **slot** granularity instead:
                           so greedy decode continues exactly)
 
 Slot membership changes every step, shapes never do: dead slots ride
-along as masked rows (page-table ``-1`` = gather zeros / scatter
-drops), so join/leave churn is data, not a recompile.  Decode
-throughput scales with slot occupancy, not with the slowest request in
-a static batch — ``scripts/decode_smoke.py`` pins the ordering (≥ 1.5×
-as a CPU timing) and zero post-warmup recompiles under churn; device
-numbers are ``chip_smoke.py``'s serve stage and ROADMAP S1/S3.
+along as masked rows (page-table ``-1`` = no page read, attention of
+zeros / scatter drops), so join/leave churn is data, not a recompile.
+Decode throughput scales with slot occupancy, not with the slowest
+request in a static batch — ``scripts/decode_smoke.py`` pins the
+ordering (≥ 1.5× as a CPU timing) and zero post-warmup recompiles
+under churn; device numbers are ``chip_smoke.py``'s serve stage and
+PERF.md section 5.
 
 Per-token SLO accounting (families in docs/observability.md):
 ``decode/ttft_ms`` (submit → first token) and ``decode/intertoken_ms``
@@ -170,6 +173,7 @@ class DecodeEngine:
                     (pool-exhaustion backpressure reaches the client
                     as queue growth, then as sheds)
     ``int8_kv``     store KV pages int8 with per-channel scales
+                    (attends by the gathered window, not the kernel)
     ``eos_id``      default stop token (None = run to max_new)
     ``seed``        sampling RNG seed (temperature > 0 requests)
     """
@@ -465,6 +469,11 @@ class DecodeEngine:
         out["kv_pool_fill"] = self.kv.fill()
         out["kv_peak_fill"] = rec.gauge_value("kv/peak_fill")
         out["evictions"] = rec.counter_value("kv/evictions")
+        # what the steps' attention had to read of what a gathered
+        # window holds: the live pages' share
+        out["kv_pages_read_share"] = rec.counter_value("kv/pages_read") \
+            / max(rec.counter_value("kv/pages_window"), 1.0)
+        out["attn_route"] = self.kv.attention_path()[0]
         for h, label in (("decode/ttft_ms", "ttft"),
                          ("decode/intertoken_ms", "intertoken")):
             q = rec.hist_quantiles(h, (50.0, 99.0))
@@ -508,13 +517,18 @@ class DecodeEngine:
         model, kv = self.model, self.kv
         base_key = self._base_key
         if kind == "decode":
+            # which way the step's attention goes (kvcache.attend picks
+            # it while tracing): 1 = pallas, 0 = gather
+            self.recorder.gauge("decode/attn_route", float(
+                kv.attention_path()[0] == "pallas"))
+
             def fn(params, pool, tokens, lengths, tables, temps, step):
                 new_pool = dict(pool)
 
-                def kv_io(name, k_new, v_new):
+                def kv_io(name, q, k_new, v_new):
                     new_pool[name] = kv.write_token(
                         new_pool[name], tables, lengths, k_new, v_new)
-                    return kv.gather_window(new_pool[name], tables)
+                    return kv.attend(new_pool[name], tables, lengths, q)
 
                 logits = model.decode_tokens(params, tokens, lengths,
                                              kv_io)
@@ -863,6 +877,13 @@ class DecodeEngine:
             for s in dead:
                 tokens[s] = 0
                 lengths[s] = 0
+            # the pages this step's attention has to read (each live
+            # slot's, the new token's included) of those a gathered
+            # window holds
+            rec.inc("kv/pages_read", int(
+                (lengths[live_slots] // self.kv.page_size + 1).sum()))
+            rec.inc("kv/pages_window",
+                    self.slots * self.kv.max_pages_per_slot)
             entry = self.registry.get(self.model_name)
             prog = self._program("decode")
             # chaos seam: delay = a wedged decode step (the replica wedge

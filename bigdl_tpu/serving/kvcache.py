@@ -18,12 +18,18 @@ step never sees the allocator — it takes the page tables as a plain
     ``(table[len // page_size], len % page_size)`` — a fixed-shape
     scatter; dead slots carry table entries of ``-1``, whose writes
     XLA **drops** (out-of-bounds scatter, ``mode="drop"``),
-  * **gathers** each slot's pages back into a contiguous attention
-    window ``(slots, heads, max_pages * page_size, head_dim)`` —
-    a fixed-shape gather; ``-1`` entries **fill** with zeros
-    (``mode="fill"``), exactly the zero rows an unwritten contiguous
-    cache would hold, which is what keeps paged logits bitwise equal
-    to the ``init_cache`` path (tests/test_decode.py pins this).
+  * **attends** the new token's q to the slot's pages
+    (:meth:`PagedKVCache.attend`), by one of two routes that
+    :func:`bigdl_tpu.ops.paged_attention_path` picks from what the code
+    can observe.  On a TPU, for a float pool whose rows tile, a Pallas
+    kernel reads the live pages where they lie, up to each slot's
+    length, in the dtype they are stored in.  Elsewhere (CPU, an int8
+    pool) the pages are **gathered** back into a contiguous window
+    ``(slots, heads, max_pages * page_size, head_dim)`` — a fixed-shape
+    gather; ``-1`` entries **fill** with zeros (``mode="fill"``),
+    exactly the zero rows an unwritten contiguous cache would hold, so
+    paged logits match the ``init_cache`` path to rounding
+    (tests/test_decode.py pins this) — and attended in float32.
 
 Page tables are data, not shapes: admissions, retirements and
 evictions change *values* only, so one compiled decode program serves
@@ -39,19 +45,24 @@ never hidden (see docs/serving.md § Token streaming).
 
 Telemetry (``kv/*`` family, registered in docs/observability.md):
 ``kv/page_allocs`` / ``kv/page_frees`` / ``kv/evictions`` counters,
-``kv/pages_in_use`` / ``kv/pool_fill`` / ``kv/peak_fill`` gauges.
+``kv/pages_in_use`` / ``kv/pool_fill`` / ``kv/peak_fill`` gauges; the
+decode engine counts ``kv/pages_read`` against ``kv/pages_window`` (what
+a step's attention has to read of what the gathered window holds).
 """
 from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, List, Optional, Sequence
+import warnings
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..observability import Recorder
+from ..ops.paged_attention import (attend_window, paged_attention,
+                                   paged_attention_path)
 from ..quantized import dequantize_rows, quantize_rows
 
 
@@ -60,7 +71,7 @@ class PagePoolError(RuntimeError):
 
 
 class PagedKVCache:
-    """Device page pool + host allocator + the jitted write/gather fns.
+    """Device page pool + host allocator + the jitted write/attend fns.
 
     ``layer_names``   attention-module names (one k/v pool each)
     ``n_heads`` / ``head_dim``  per-layer KV row geometry
@@ -68,11 +79,12 @@ class PagedKVCache:
     ``page_size``     token rows per page
     ``n_slots``       concurrent sequences (page-table rows)
     ``max_context``   longest sequence a slot may hold; rounded up to a
-                      page multiple; fixes the gather window
-                      ``max_pages_per_slot * page_size``
+                      page multiple; fixes the page-table width
+                      ``max_pages_per_slot`` (and the gathered window)
     ``dtype``         pool dtype for the fp path (int8 path stores
                       int8 + fp32 scales)
     ``int8``          quantize KV rows on write, dequantize on gather
+                      (an int8 pool always attends by the gather route)
 
     The allocator side (``alloc_for`` / ``free_slot``) is guarded by
     one lock and keeps the invariant ``free + sum(owned) == n_pages``
@@ -220,7 +232,7 @@ class PagedKVCache:
         if fill > rec.gauge_value("kv/peak_fill", 0.0):
             rec.gauge("kv/peak_fill", fill)
 
-    # -- jitted write/gather (fixed shapes, traced) ------------------------ #
+    # -- jitted write/attend (fixed shapes, traced) ------------------------ #
     def _oob(self, idx):
         """Map the host tables' ``-1`` free markers to ``n_pages`` —
         genuinely out of bounds.  jax scatter/gather WRAP negative
@@ -251,6 +263,38 @@ class PagedKVCache:
 
         return (one(layer_pool["k"], layer_pool.get("k_scale")),
                 one(layer_pool["v"], layer_pool.get("v_scale")))
+
+    def attention_path(self, backend: Optional[str] = None
+                       ) -> Tuple[str, str]:
+        """``(route, why)`` of :meth:`attend` for this pool: ``"pallas"``
+        (pages read in place) or ``"gather"`` (window, then float32
+        math) — :func:`~bigdl_tpu.ops.paged_attention_path` over the
+        pool's dtype and row geometry."""
+        return paged_attention_path(
+            jnp.int8 if self.int8 else self.dtype, self.n_heads,
+            self.head_dim, backend=backend)
+
+    def attend(self, layer_pool, tables, lengths, q):
+        """Single-token attention of q ``(slots, heads, 1, head_dim)``
+        over each slot's pages, the row :meth:`write_token` just wrote
+        at ``lengths[s]`` included (write, then attend).  Keys past it
+        are masked and their V rows zeroed, so a recycled page's stale
+        or non-finite rows cannot leak; a dead slot reads zeros (its
+        token is never emitted).  Returns ``(slots, heads, 1,
+        head_dim)`` in q's dtype."""
+        route, why = self.attention_path()
+        if route == "pallas":
+            return paged_attention(q[:, :, 0], layer_pool["k"],
+                                   layer_pool["v"], tables,
+                                   lengths)[:, :, None]
+        if jax.default_backend() == "tpu" and not self.int8:
+            # on the chip the window is never the intended route for a
+            # float pool: say so (once per call site)
+            warnings.warn("PagedKVCache.attend gathers every slot's "
+                          f"whole window, not the Pallas kernel: {why}",
+                          stacklevel=2)
+        k_win, v_win = self.gather_window(layer_pool, tables)
+        return attend_window(q, k_win, v_win, lengths)
 
     def write_token(self, layer_pool, tables, lengths, k_new, v_new):
         """Scatter one new k/v row per slot into the pool at

@@ -300,7 +300,12 @@ def stage_serve(ctx):
            f"decode/recompiles = {st['recompiles']} after warm-up")
     _check(st["finished"] == len(streams),
            f"{st['finished']} of {len(streams)} requests finished")
-    return dict(compile_s=warm_s, run_s=run_s,
+    if ctx.native:
+        _check(st["attn_route"] == "pallas",
+               "decode attention gathers the window on the chip: "
+               + eng.kv.attention_path()[1])
+    return dict(compile_s=warm_s, run_s=run_s, attn_route=st["attn_route"],
+                kv_pages_read_share=st["kv_pages_read_share"],
                 warmup_compiles=int(st["warmup_compiles"]),
                 max_prompt=s["max_prompt"], requests=len(streams),
                 tokens=sum(len(t) for t in streamed),

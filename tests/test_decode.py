@@ -6,8 +6,9 @@ The load-bearing claims, each pinned here:
     jax negative-index WRAP hazard (a raw ``-1`` table entry aliases
     the pool's LAST page instead of dropping/filling: a dead slot's
     write clobbered whichever request owned it)
-  * paged decode logits BITWISE equal to the contiguous ``init_cache``
-    path at a matched attention window
+  * paged decode logits equal to the contiguous ``init_cache`` path at
+    a matched attention window, to float32 rounding (the two programs
+    order their sums differently)
   * int8 KV drift bounded (and only bounded — never silently hidden)
   * eviction → readmission (re-prefill + replay) EXACT: a contended
     run with forced evictions produces bitwise the tokens of an
@@ -166,7 +167,7 @@ def test_negative_table_entries_never_alias_the_last_page():
 
 def _paged_reference(model, params, prompt, new_tokens, kv, slot):
     """Greedy decode through the paged path, eagerly (prefill bucket =
-    next pow2, per-step write+gather) — returns per-step logits."""
+    next pow2, per-step write+attend) — returns per-step logits."""
     L = prompt.shape[1]
     bucket = 1 << max(L - 1, 0).bit_length() if L > 1 else 1
     pool = kv.init_pool()
@@ -192,9 +193,9 @@ def _paged_reference(model, params, prompt, new_tokens, kv, slot):
         tb = jnp.asarray(kv.tables)
         ln = jnp.asarray(lengths)
 
-        def kv_io(name, k, v, _tb=tb, _ln=ln):
+        def kv_io(name, q, k, v, _tb=tb, _ln=ln):
             pool[name] = kv.write_token(pool[name], _tb, _ln, k, v)
-            return kv.gather_window(pool[name], _tb)
+            return kv.attend(pool[name], _tb, _ln, q)
 
         lg = model.decode_tokens(params, jnp.asarray(last), ln, kv_io)
         logits.append(np.asarray(lg[slot]))
@@ -203,9 +204,11 @@ def _paged_reference(model, params, prompt, new_tokens, kv, slot):
     return logits
 
 
-def test_paged_decode_bitwise_vs_contiguous_cache(lm):
-    """The gather-window path produces BITWISE the logits of the
-    contiguous init_cache path at a matched attention window."""
+def test_paged_decode_matches_contiguous_cache(lm):
+    """The gather-window path produces the logits of the contiguous
+    init_cache path at a matched attention window.  Not bitwise: the
+    slot-batched decode program and the batch-1 cached one round their
+    sums in another order (about 1e-6 from step 0 on CPU)."""
     params = lm._params
     prompt = np.random.RandomState(1).randint(0, 256, (1, 5)) \
         .astype(np.int32)
@@ -226,7 +229,8 @@ def test_paged_decode_bitwise_vs_contiguous_cache(lm):
         pos += 1
     got = _paged_reference(lm, params, prompt, NEW, kv, slot=1)
     for i, (a, b) in enumerate(zip(ref, got)):
-        assert np.array_equal(a, b), f"step {i} not bitwise"
+        assert np.allclose(a, b, rtol=0, atol=2e-5), \
+            f"step {i}: {np.abs(a - b).max()}"
 
 
 def test_int8_kv_drift_bounded_and_not_hidden(lm):

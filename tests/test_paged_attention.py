@@ -1,0 +1,224 @@
+"""The paged decode-attention kernel against the gathered-window route.
+
+The kernel (``ops/paged_attention.py``) runs here in Pallas interpret
+mode through its ``_INTERPRET`` hook; ``PagedKVCache.gather_window`` +
+``attend_window`` is the reference.  Pinned:
+
+  * kernel == reference over pool dtype, page size and ragged lengths
+    (a dead slot, 1 key, a page less one, a page, a page and one, the
+    whole context)
+  * non-finite rows past a slot's length (the tail of its last page, a
+    page it holds but has not reached, every free page) never leak
+  * ``paged_attention_path`` picks the route from backend, pool dtype
+    and row geometry, and says why
+  * one ``DecodeEngine`` stream through the kernel emits the greedy
+    tokens of the gather route, eviction and readmission included
+  * the kernel compiles under Mosaic for a described v5e at the serve
+    cell's widths (no chip needed)
+"""
+import functools
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+from bigdl_tpu.models import transformer as T
+from bigdl_tpu.ops import paged_attention_mod as pa
+from bigdl_tpu.ops import paged_attention_path
+from bigdl_tpu.serving import DecodeEngine, ModelRegistry, PagedKVCache
+
+H, D, CTX = 8, 128, 64
+
+
+@pytest.fixture()
+def interpret(monkeypatch):
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+
+
+def _lengths(page):
+    # slot 0 is dead (no page, length 0); the rest are live
+    return [0, 0, 1, page - 1, page, page + 1, CTX - 1]
+
+
+def _case(dtype, page, poison=False, pages_per_block=2):
+    """(kernel output, reference, live mask) for one pool: slot ``s``
+    holds ``_lengths(page)[s]`` tokens and attends one more."""
+    lens = _lengths(page)
+    n_slots = len(lens)
+    kv = PagedKVCache(["a"], n_heads=H, head_dim=D,
+                      n_pages=n_slots * CTX // page + 2, page_size=page,
+                      n_slots=n_slots, max_context=CTX, dtype=dtype)
+    rng = np.random.default_rng(page)
+    shape = (kv.n_pages, page, H, D)
+    k = rng.standard_normal(shape).astype(np.float32)
+    v = rng.standard_normal(shape).astype(np.float32)
+    for s, n in enumerate(lens):
+        if s:
+            # slot 3 also holds a page it has not reached yet
+            assert kv.alloc_for(s, n + 1 + (page if s == 3 else 0))
+    if poison:
+        for arr in (k, v):
+            arr[kv._free] = np.nan                       # freed pages
+            for s, n in enumerate(lens):
+                for j, p in enumerate(kv.tables[s]):
+                    if p >= 0:                           # rows past n
+                        arr[p, max(n + 1 - j * page, 0):] = np.nan
+    tables = jnp.asarray(kv.tables)
+    lengths = jnp.asarray(np.asarray(lens, np.int32))
+    k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+    q = jnp.asarray(rng.standard_normal((n_slots, H, 1, D)), dtype)
+    k_win, v_win = kv.gather_window({"k": k, "v": v}, tables)
+    ref = pa.attend_window(q, k_win, v_win, lengths)[:, :, 0]
+    out = pa.paged_attention(q[:, :, 0], k, v, tables, lengths,
+                             pages_per_block=pages_per_block)
+    return (np.asarray(out, np.float32), np.asarray(ref, np.float32))
+
+
+_cached_case = functools.lru_cache(maxsize=None)(_case)
+
+
+@pytest.mark.parametrize("slot", range(7))
+@pytest.mark.parametrize("page", [8, 16])
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+                                       ("bfloat16", 1.6e-2)])
+def test_kernel_matches_gathered_window(interpret, dtype, tol, page, slot):
+    out, ref = _cached_case(dtype, page)
+    assert np.isfinite(out).all()
+    if slot == 0:
+        assert not out[0].any()          # a dead slot reads zeros
+    else:
+        assert np.abs(out[slot] - ref[slot]).max() <= tol, \
+            f"length {_lengths(page)[slot]}"
+
+
+@pytest.mark.parametrize("pages_per_block", [1, 3, 8])
+def test_kernel_block_size_does_not_change_the_result(interpret,
+                                                      pages_per_block):
+    out, ref = _case("float32", 8, pages_per_block=pages_per_block)
+    assert np.abs(out[1:] - ref[1:]).max() <= 2e-6
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+                                       ("bfloat16", 1.6e-2)])
+def test_non_finite_rows_past_the_length_do_not_leak(interpret, dtype,
+                                                     tol):
+    """NaN in the masked tail of a slot's last page, in a page the slot
+    holds but has not reached, and in every free page: 0 * NaN would
+    be NaN, so both routes scrub the masked V rows."""
+    out, ref = _case(dtype, 8, poison=True)
+    clean, _ = _cached_case(dtype, 8)
+    assert np.isfinite(ref).all() and np.isfinite(out).all()
+    assert np.abs(out[1:] - ref[1:]).max() <= tol
+    assert np.array_equal(out, clean)    # the poison changed nothing
+
+
+@pytest.mark.parametrize("kw,route,why", [
+    (dict(pool_dtype="bfloat16", n_heads=16, head_dim=128, backend="cpu"),
+     "gather", "backend 'cpu' is not tpu"),
+    (dict(pool_dtype="int8", n_heads=16, head_dim=128, backend="tpu"),
+     "gather", "pool dtype int8 is not a float"),
+    (dict(pool_dtype="float32", n_heads=2, head_dim=64, backend="tpu"),
+     "gather", "head_dim 64 is not a multiple of 128"),
+    (dict(pool_dtype="bfloat16", n_heads=12, head_dim=128, backend="tpu"),
+     "gather", "n_heads 12 is not a multiple of 8"),
+    (dict(pool_dtype="bfloat16", n_heads=16, head_dim=128, backend="tpu"),
+     "pallas", "tpu backend"),
+    (dict(pool_dtype="float32", n_heads=8, head_dim=128, backend="tpu"),
+     "pallas", "tpu backend"),
+])
+def test_paged_attention_path_says_which_route_and_why(kw, route, why):
+    got, reason = paged_attention_path(**kw)
+    assert got == route and why in reason, (got, reason)
+
+
+def test_cache_routes_by_what_it_holds(interpret):
+    """``PagedKVCache.attention_path`` is ``paged_attention_path`` over
+    the pool it built: the hook stands in for the TPU backend, an int8
+    pool and the tiny preset's head_dim 64 stay on the window."""
+    mk = lambda **kw: PagedKVCache(["a"], n_pages=4, page_size=8,
+                                   n_slots=2, max_context=16, **kw)
+    assert mk(n_heads=H, head_dim=D).attention_path()[0] == "pallas"
+    assert mk(n_heads=H, head_dim=D, int8=True).attention_path()[0] \
+        == "gather"
+    assert mk(n_heads=2, head_dim=64).attention_path()[0] == "gather"
+    assert mk(n_heads=H, head_dim=D).attention_path(backend="tpu") \
+        == paged_attention_path("float32", H, D, backend="tpu")
+
+
+def _stream(lm, prompts, **kw):
+    reg = ModelRegistry()
+    reg.register("lm", lm)
+    eng = DecodeEngine(reg, "lm", slots=4, page_size=8, max_context=32,
+                       max_prompt=16, max_new_tokens=12, **kw).warmup()
+    try:
+        futs = [eng.submit("lm", p) for p in prompts]
+        outs = [f.result(300) for f in futs]
+        eng.kv.check_invariants()
+        return outs, eng.stats(), eng.recorder
+    finally:
+        eng.shutdown()
+
+
+def test_engine_stream_through_the_kernel_matches_the_gather_route(
+        monkeypatch):
+    """A pool of 6 pages under four requests evicts and readmits; the
+    kernel route's greedy tokens are the gather route's, the route is
+    what the engine reports, and the counters say what share of the
+    window's pages the steps had to read."""
+    lm = T.build("tiny", dropout=0.0, d_model=H * D, n_heads=H,
+                 n_layers=1, d_ff=128, max_len=64)
+    lm.ensure_initialized()
+    rs = np.random.RandomState(3)
+    prompts = [rs.randint(0, 256, (n,)) for n in (6, 10, 14, 8)]
+    ref, ref_stats, ref_rec = _stream(lm, prompts, pool_pages=6)
+    assert ref_stats["attn_route"] == "gather"
+    assert ref_rec.gauge_value("decode/attn_route", -1.0) == 0.0
+
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    outs, stats, rec = _stream(lm, prompts, pool_pages=6)
+    assert stats["attn_route"] == "pallas"
+    assert rec.gauge_value("decode/attn_route", -1.0) == 1.0
+    assert rec.counter_value("kv/evictions") > 0
+    assert rec.counter_value("decode/readmissions") > 0
+    for a, b in zip(ref, outs):
+        assert np.array_equal(a, b)
+    # 4 slots x 4 pages a window; the live pages are a part of it
+    assert rec.counter_value("kv/pages_window") \
+        == 16 * rec.counter_value("decode/steps")
+    assert 0.0 < stats["kv_pages_read_share"] < 1.0
+    # int8 stays on the window whatever the hook says
+    _, q8_stats, _ = _stream(lm, prompts[:1], int8_kv=True)
+    assert q8_stats["attn_route"] == "gather"
+
+
+# -- the kernel under the chip's compiler, at the serve cell's widths -- #
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dtype,heads", [("bfloat16", 16), ("bfloat16", 8),
+                                         ("float32", 8)])
+def test_kernel_compiles_for_v5e_without_a_pool_copy(one_chip, dtype,
+                                                     heads):
+    """32 slots x 512 tokens, 1,024 pages of 16 rows of ``heads`` x 128
+    (the serve cell's pool, and chip_smoke.py's 8 heads): Mosaic takes
+    the kernel as written, and XLA hands it the pool without a relayout
+    copy (no temporaries at all)."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    pool = sds((1024, 16, heads, 128), dtype)
+    compiled = jax.jit(pa.paged_attention).lower(
+        sds((32, heads, 128), dtype), pool, pool,
+        sds((32, 32), "int32"), sds((32,), "int32")).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
