@@ -37,6 +37,7 @@ from ..nn.module import Module, Ctx
 from ..nn.normalization import RMSNorm
 from ..ops.flash_attention import (flash_attention, DEFAULT_MASK_VALUE,
                                    _mask as _attn_mask)
+from ..ops.sparse_attention import attend as sparse_attend
 from ..nn import init as init_lib
 
 
@@ -54,14 +55,28 @@ class TransformerConfig:
     remat: bool = False             # per-block rematerialisation
     use_ring_attention: bool = False  # sp-sharded seq (needs mesh w/ 'sp')
     tie_embeddings: bool = False
-    moe_experts: int = 0            # >0: SwitchFFN experts ('ep'-sharded)
+    moe_experts: int = 0            # >0: routed experts ('ep'-sharded)
     moe_top_k: int = 1
-    moe_capacity_factor: float = 1.25
+    # a capacity per expert, overflow dropped (SwitchFFN); None: no
+    # capacity and nothing dropped (RoutedExperts), d_ff the expert width
+    moe_capacity_factor: Optional[float] = 1.25
+    n_kv_heads: Optional[int] = None  # grouped heads; None: n_heads
+    head_dim: Optional[int] = None    # None: d_model // n_heads
+    qk_norm: bool = False           # per-head RMSNorm of q and k, pre-rope
+    # learned sparse attention: an indexer of index_heads x index_dim
+    # against one index key a token picks the index_top_k keys a query
+    # attends (index_heads 0: dense causal attention)
+    index_heads: int = 0
+    index_dim: int = 64
+    index_top_k: int = 2048
 
-    @property
-    def head_dim(self):
-        assert self.d_model % self.n_heads == 0
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            assert self.d_model % self.n_heads == 0
+            self.head_dim = self.d_model // self.n_heads
+        if self.n_kv_heads is None:
+            self.n_kv_heads = self.n_heads
+        assert self.n_heads % self.n_kv_heads == 0
 
 
 def apply_rope(x, positions, theta: float = 10000.0):
@@ -138,11 +153,21 @@ class TokenEmbedding(Module):
 class MultiHeadAttention(Module):
     """Causal self-attention with RoPE + flash attention.
 
+    ``n_heads`` query heads of ``head_dim`` share ``n_kv_heads`` key/value
+    heads (grouped-query attention; equal by default); ``qk_norm`` puts a
+    per-head RMSNorm on q and k before the rotation; ``index_heads`` > 0
+    adds the learned selection of :mod:`bigdl_tpu.ops.sparse_attention`:
+    three small projections of the block's normed input (index queries,
+    ONE index key a token under a LayerNorm, a weight an index head) score
+    every earlier token, and a query attends its ``index_top_k`` best.
+
     tp layout (megatron): wq/wk/wv column-sharded on the head dim
     (P(None, 'tp')), wo row-sharded (P('tp', None)) — under GSPMD the
     partitioner emits exactly one psum after wo.  When
     ``cfg.use_ring_attention`` the spmd trainer swaps the attention core
-    for the sp ring (see parallel/spmd.py: _RING_HOOK).
+    for the sp ring (see parallel/spmd.py: _RING_HOOK).  The flash
+    kernel and the ring take q, k and v of equal heads: grouped K and V
+    are repeated for them (the cached paths never repeat).
     """
 
     def __init__(self, cfg: TransformerConfig, name=None):
@@ -153,106 +178,155 @@ class MultiHeadAttention(Module):
         # the spmd trainer injects a mesh-aware attention fn here
         self.attention_fn = None
 
+    @property
+    def sparse(self):
+        return self.cfg.index_heads > 0
+
     def init(self, rng):
         cfg = self.cfg
-        ks = jax.random.split(rng, 4)
+        ks = list(jax.random.split(rng, 4)) \
+            + [jax.random.fold_in(rng, i) for i in (4, 5, 6)]
         scale = cfg.d_model ** -0.5
-        mk = lambda k: jax.random.normal(
-            k, (cfg.d_model, cfg.d_model), jnp.float32) * scale
-        return {self.name: {"wq": mk(ks[0]), "wk": mk(ks[1]),
-                            "wv": mk(ks[2]), "wo": mk(ks[3])}}
+        mk = lambda k, n: jax.random.normal(
+            k, (cfg.d_model, n), jnp.float32) * scale
+        qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+        p = {"wq": mk(ks[0], qd), "wk": mk(ks[1], kvd), "wv": mk(ks[2], kvd),
+             "wo": jax.random.normal(ks[3], (qd, cfg.d_model), jnp.float32)
+             * qd ** -0.5}
+        if cfg.qk_norm:
+            p["q_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
+            p["k_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
+        if self.sparse:
+            p["wqi"] = mk(ks[4], cfg.index_heads * cfg.index_dim)
+            p["wki"] = mk(ks[5], cfg.index_dim)
+            p["wwi"] = mk(ks[6], cfg.index_heads)
+            p["ki_norm"] = jnp.ones((cfg.index_dim,), jnp.float32)
+            p["ki_bias"] = jnp.zeros((cfg.index_dim,), jnp.float32)
+        return {self.name: p}
 
-    def apply(self, params, x, ctx):
+    # -- projections ------------------------------------------------------ #
+    def project_qkv(self, params, x, rope):
+        """x (B, S, d_model) -> q (B, H, S, Dh), k and v (B, Hkv, S, Dh);
+        ``rope`` rotates a (B, heads, S, D) array at the call's positions
+        (one row of positions for a sequence, or one position a batch row
+        for slot-batched decode).  Split from the attention itself so a
+        paged KV cache can own the write and the attention in between
+        (:meth:`TransformerBlock.apply_decode`); :meth:`project_out`
+        closes it."""
         cfg = self.cfg
         p = self.own(params)
         b, s, _ = x.shape
         dt = x.dtype
 
-        def proj(w):
-            y = jnp.dot(x, w.astype(dt))
-            y = y.reshape(b, s, cfg.n_heads, cfg.head_dim)
-            return jnp.transpose(y, (0, 2, 1, 3))        # (B, H, S, Dh)
+        def proj(w, heads, gain=None):
+            y = jnp.dot(x, w.astype(dt)).reshape(b, s, heads, cfg.head_dim)
+            if gain is not None:
+                y = _rms(y, gain)
+            return jnp.transpose(y, (0, 2, 1, 3))
 
-        q, k, v = proj(p["wq"]), proj(p["wk"]), proj(p["wv"])
+        q = rope(proj(p["wq"], cfg.n_heads, p.get("q_norm")))
+        k = rope(proj(p["wk"], cfg.n_kv_heads, p.get("k_norm")))
+        return q, k, proj(p["wv"], cfg.n_kv_heads)
+
+    def project_index(self, params, x, rope):
+        """The indexer's reading of x (B, S, d_model): index queries
+        (B, S, Hi, Di) and the token's ONE index key (B, S, Di), both
+        rotated, and a weight an index head (B, S, Hi)."""
+        cfg = self.cfg
+        p = self.own(params)
+        b, s, _ = x.shape
+        dt = x.dtype
+        qi = jnp.dot(x, p["wqi"].astype(dt)).reshape(
+            b, s, cfg.index_heads, cfg.index_dim)
+        qi = jnp.swapaxes(rope(jnp.swapaxes(qi, 1, 2)), 1, 2)
+        ki = jnp.dot(x, p["wki"].astype(dt)).astype(jnp.float32)
+        mu = ki.mean(-1, keepdims=True)
+        ki = (ki - mu) * lax.rsqrt(
+            jnp.square(ki - mu).mean(-1, keepdims=True) + 1e-6) \
+            * p["ki_norm"] + p["ki_bias"]
+        ki = rope(ki.astype(dt)[:, None])[:, 0]
+        return qi, ki, jnp.dot(x, p["wwi"].astype(dt))
+
+    def project_out(self, params, o):
+        """Output projection of an attention result o (B, H, S, Dh) ->
+        (B, S, d_model)."""
+        b, _, s, _ = o.shape
+        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, -1)
+        return jnp.dot(o, self.own(params)["wo"].astype(o.dtype))
+
+    # -- the three entry points ------------------------------------------- #
+    def apply(self, params, x, ctx):
+        cfg = self.cfg
+        b, s, _ = x.shape
         positions = jnp.arange(s)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        if self.attention_fn is not None:
-            o = self.attention_fn(q, k, v)
+        rope = lambda t: apply_rope(t, positions, cfg.rope_theta)
+        q, k, v = self.project_qkv(params, x, rope)
+        if self.sparse:
+            qi, ki, w = self.project_index(params, x, rope)
+            o = sparse_attend(q, k, v, jnp.broadcast_to(positions, (b, s)),
+                              jnp.full((b,), s), (qi, ki, w),
+                              cfg.index_top_k)
         else:
-            o = flash_attention(q, k, v, causal=True)
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, cfg.d_model)
-        return jnp.dot(o, p["wo"].astype(dt))
+            if cfg.n_kv_heads != cfg.n_heads:
+                rep = cfg.n_heads // cfg.n_kv_heads
+                k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+            if self.attention_fn is not None:
+                o = self.attention_fn(q, k, v)
+            else:
+                o = flash_attention(q, k, v, causal=True)
+        return self.project_out(params, o)
 
     def apply_cached(self, params, x, cache, start):
         """Incremental attention for generation: project the ``s`` new
         positions (global offsets ``start + arange(s)``), write their k/v
-        into the static-length cache (``lax.dynamic_update_slice`` — the
-        compiled program is position-independent), and attend q against
-        the whole cache under a global causal mask.  One code path covers
-        prompt prefill (s = prompt length) and decode (s = 1)."""
+        (and index keys) into the static-length cache
+        (``lax.dynamic_update_slice`` — the compiled program is
+        position-independent), and attend q against the whole cache under
+        a global causal mask that also hides unwritten cache slots.  One
+        code path covers prompt prefill (s = prompt length) and decode
+        (s = 1)."""
         cfg = self.cfg
-        p = self.own(params)
         b, s, _ = x.shape
-        dt = x.dtype
-
-        def proj(w):
-            y = jnp.dot(x, w.astype(dt))
-            y = y.reshape(b, s, cfg.n_heads, cfg.head_dim)
-            return jnp.transpose(y, (0, 2, 1, 3))        # (B, H, s, Dh)
-
         positions = start + jnp.arange(s)
-        q = apply_rope(proj(p["wq"]), positions, cfg.rope_theta)
-        k = apply_rope(proj(p["wk"]), positions, cfg.rope_theta)
-        v = proj(p["wv"])
-        ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                      (0, 0, start, 0))
-        cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                      (0, 0, start, 0))
-        k_pos = jnp.arange(ck.shape[2])
-        s_ = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        ck.astype(jnp.float32)) / np.sqrt(cfg.head_dim)
-        # same mask primitive as the kernels; kv_len = start + s also
-        # masks unwritten cache slots explicitly
-        mask = _attn_mask(positions, k_pos, start + s, True)
-        s_ = jnp.where(mask[None, None], s_, DEFAULT_MASK_VALUE)
-        w_ = jax.nn.softmax(s_, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", w_,
-                       cv.astype(jnp.float32)).astype(dt)
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, cfg.d_model)
-        return jnp.dot(o, p["wo"].astype(dt)), {"k": ck, "v": cv}
+        rope = lambda t: apply_rope(t, positions, cfg.rope_theta)
+        q, k, v = self.project_qkv(params, x, rope)
+        new = {"k": lax.dynamic_update_slice(
+                   cache["k"], k.astype(cache["k"].dtype), (0, 0, start, 0)),
+               "v": lax.dynamic_update_slice(
+                   cache["v"], v.astype(cache["v"].dtype), (0, 0, start, 0))}
+        if not self.sparse and cfg.n_kv_heads == cfg.n_heads:
+            # one K/V head a query head and no selection: the plain
+            # sequence, kept as it was so that the programs of the models
+            # that have always taken it do not change
+            k_pos = jnp.arange(new["k"].shape[2])
+            s_ = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                            new["k"].astype(jnp.float32)) \
+                / np.sqrt(cfg.head_dim)
+            # same mask primitive as the kernels; kv_len = start + s also
+            # masks unwritten cache slots explicitly
+            mask = _attn_mask(positions, k_pos, start + s, True)
+            s_ = jnp.where(mask[None, None], s_, DEFAULT_MASK_VALUE)
+            w_ = jax.nn.softmax(s_, axis=-1)
+            o = jnp.einsum("bhqk,bhkd->bhqd", w_,
+                           new["v"].astype(jnp.float32)).astype(x.dtype)
+            return self.project_out(params, o), new
+        index = None
+        if self.sparse:
+            qi, ki, w = self.project_index(params, x, rope)
+            new["ki"] = lax.dynamic_update_slice(
+                cache["ki"], ki.astype(cache["ki"].dtype), (0, start, 0))
+            index = (qi, new["ki"], w)
+        o = sparse_attend(q, new["k"], new["v"],
+                          jnp.broadcast_to(positions, (b, s)),
+                          jnp.broadcast_to(start + s, (b,)), index,
+                          cfg.index_top_k)
+        return self.project_out(params, o), new
 
-    # -- continuous-batching decode (per-row positions) ----------------- #
-    def project_qkv_rows(self, params, x, positions):
-        """Projections for ONE new token per batch row at per-row global
-        offsets: x (B, 1, d_model), positions (B,).  Returns q, k, v
-        each (B, H, 1, Dh) with RoPE applied to q/k at ``positions[b]``
-        — the slot-batched half of :meth:`apply_cached`, split out so a
-        paged KV cache can own the write and the attention in between
-        (:func:`bigdl_tpu.ops.paged_attention.attend_window` is the
-        other half's math; :meth:`project_out` closes it)."""
-        cfg = self.cfg
-        p = self.own(params)
-        b = x.shape[0]
-        dt = x.dtype
 
-        def proj(w):
-            y = jnp.dot(x, w.astype(dt))
-            y = y.reshape(b, 1, cfg.n_heads, cfg.head_dim)
-            return jnp.transpose(y, (0, 2, 1, 3))        # (B, H, 1, Dh)
-
-        q = apply_rope_rows(proj(p["wq"]), positions, cfg.rope_theta)
-        k = apply_rope_rows(proj(p["wk"]), positions, cfg.rope_theta)
-        v = proj(p["wv"])
-        return q, k, v
-
-    def project_out(self, params, o):
-        """Output projection of a single-token attention result o
-        (B, H, 1, Dh) -> (B, 1, d_model): what follows the paged
-        cache's attention in :meth:`TransformerBlock.apply_decode`."""
-        b = o.shape[0]
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, 1, self.cfg.d_model)
-        return jnp.dot(o, self.own(params)["wo"].astype(o.dtype))
+def _rms(y, gain, eps=1e-6):
+    yf = y.astype(jnp.float32)
+    return (yf * lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + eps)
+            * gain).astype(y.dtype)
 
 
 class SwiGLU(Module):
@@ -292,7 +366,11 @@ class TransformerBlock(Module):
         self.norm1 = RMSNorm(cfg.d_model, name=f"{self.name}.norm1")
         self.attn = MultiHeadAttention(cfg, name=f"{self.name}.attn")
         self.norm2 = RMSNorm(cfg.d_model, name=f"{self.name}.norm2")
-        if cfg.moe_experts > 0:
+        if cfg.moe_experts > 0 and cfg.moe_capacity_factor is None:
+            from ..nn.moe import RoutedExperts
+            self.mlp = RoutedExperts(cfg.d_model, cfg.d_ff, cfg.moe_experts,
+                                     cfg.moe_top_k, name=f"{self.name}.moe")
+        elif cfg.moe_experts > 0:
             from ..nn.moe import SwitchFFN
             self.mlp = SwitchFFN(cfg.d_model, cfg.d_ff, cfg.moe_experts,
                                  top_k=cfg.moe_top_k,
@@ -325,16 +403,36 @@ class TransformerBlock(Module):
 
     def apply_decode(self, params, x, ctx, positions, kv_io):
         """Slot-batched single-token decode: x (B, 1, d_model),
-        positions (B,).  ``kv_io(attn_name, q, k_new, v_new) -> o`` is
-        the paged-KV seam — it writes this token's k/v rows into the
-        cache and returns the attention of q (B, H, 1, Dh) over the
+        positions (B,).  ``kv_io(attn_name, q, k_new, v_new[, index]) ->
+        o`` is the paged-KV seam — it writes this token's k/v rows into
+        the cache and returns the attention of q (B, H, 1, Dh) over the
         slot's keys, the rows just written included (the same
         update-then-attend order :meth:`apply_cached` uses), as
         (B, H, 1, Dh).  The cache owns the attention because how it is
-        computed depends on where the pages lie, not on the model."""
+        computed depends on where the pages lie, not on the model.  A
+        sparse attention hands it ``index`` = (qi (B, 1, Hi, Di), ki
+        (B, 1, Di), w (B, 1, Hi)) as well: the index key is cached with
+        k and v, and the selection is the cache's to apply."""
+        return self._through_cache(
+            params, x, ctx, kv_io,
+            lambda t: apply_rope_rows(t, positions, self.cfg.rope_theta))
+
+    def apply_chunk(self, params, x, ctx, start, kv_io):
+        """A chunk of ONE sequence's prompt through the same seam: x
+        (1, C, d_model) at positions ``start + arange(C)``; ``kv_io``
+        writes the chunk's rows and attends each query over the slot's
+        cache up to its own position."""
+        positions = start + jnp.arange(x.shape[1])
+        return self._through_cache(
+            params, x, ctx, kv_io,
+            lambda t: apply_rope(t, positions, self.cfg.rope_theta))
+
+    def _through_cache(self, params, x, ctx, kv_io, rope):
         h = self.norm1.apply(params, x, ctx)
-        q, k, v = self.attn.project_qkv_rows(params, h, positions)
-        h = x + self.attn.project_out(params, kv_io(self.attn.name, q, k, v))
+        qkv = self.attn.project_qkv(params, h, rope)
+        if self.attn.sparse:
+            qkv += (self.attn.project_index(params, h, rope),)
+        h = x + self.attn.project_out(params, kv_io(self.attn.name, *qkv))
         return h + self.mlp.apply(params, self.norm2.apply(params, h, ctx),
                                   ctx)
 
@@ -467,11 +565,16 @@ class TransformerLM(Module):
         that can exist, not the full context window."""
         cfg = self.cfg
         dt = jnp.dtype(dtype or cfg.dtype)
-        shape = (batch, cfg.n_heads, int(cache_len or cfg.max_len),
-                 cfg.head_dim)
-        return {blk.attn.name: {"k": jnp.zeros(shape, dt),
-                                "v": jnp.zeros(shape, dt)}
-                for blk in self.blocks}
+        n = int(cache_len or cfg.max_len)
+        shape = (batch, cfg.n_kv_heads, n, cfg.head_dim)
+
+        def one():
+            out = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+            if cfg.index_heads:
+                out["ki"] = jnp.zeros((batch, n, cfg.index_dim), dt)
+            return out
+
+        return {blk.attn.name: one() for blk in self.blocks}
 
     def apply_with_cache(self, params, tokens, cache, start):
         """logits for ``tokens`` (B, s) written at global offset ``start``
@@ -491,7 +594,7 @@ class TransformerLM(Module):
             logits = jnp.dot(h, w.T.astype(h.dtype))
         return logits.astype(jnp.float32), new_cache
 
-    def decode_tokens(self, params, tokens, positions, kv_io):
+    def decode_tokens(self, params, tokens, positions, kv_io, ctx=None):
         """Continuous-batching decode core: one new token per slot.
 
         ``tokens`` (B,) int32 are each slot's freshly emitted token,
@@ -502,15 +605,36 @@ class TransformerLM(Module):
         (B, V) for each slot's NEXT position.  Unlike
         :meth:`apply_with_cache` every batch row advances at its own
         offset, which is what lets a serving engine admit/retire
-        requests per decode step instead of per batch."""
+        requests per decode step instead of per batch.  ``ctx`` (the
+        engine's, optional) carries which slots are live
+        (``token_mask``) and takes what the layers count."""
         cfg = self.cfg
-        ctx = Ctx(state={}, training=False, rng_key=None)
+        if ctx is None:
+            ctx = Ctx(state={}, training=False, rng_key=None)
         h = self.embed.apply(params, tokens[:, None], ctx)
         h = h.astype(jnp.dtype(cfg.dtype))
         for blk in self.blocks:
             h = blk.apply_decode(params, h, ctx, positions, kv_io)
         h = self.final_norm.apply(params, h, ctx)
         return self.head_logits(params, h, ctx)[:, 0].astype(jnp.float32)
+
+    def prefill_chunk(self, params, tokens, start, n_valid, kv_io, ctx=None):
+        """One chunk of one sequence's prompt against its paged cache:
+        ``tokens`` (1, C) at positions ``start + arange(C)`` of which the
+        first ``n_valid`` are the prompt's (the rest pad the last chunk),
+        through the blocks' :meth:`TransformerBlock.apply_chunk` seam.
+        Returns fp32 logits (V,) after the chunk's last valid token: the
+        request's first token when this is its last chunk."""
+        cfg = self.cfg
+        if ctx is None:
+            ctx = Ctx(state={}, training=False, rng_key=None)
+        ctx.token_mask = (jnp.arange(tokens.shape[1]) < n_valid)[None]
+        h = self.embed.apply(params, tokens, ctx).astype(jnp.dtype(cfg.dtype))
+        for blk in self.blocks:
+            h = blk.apply_chunk(params, h, ctx, start, kv_io)
+        h = lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
+        h = self.final_norm.apply(params, h, ctx)
+        return self.head_logits(params, h, ctx)[0, 0].astype(jnp.float32)
 
     def generate(self, params, prompt, max_new_tokens: int,
                  temperature: float = 0.0, rng=None,

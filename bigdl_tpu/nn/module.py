@@ -143,7 +143,7 @@ class Ctx:
     """
 
     __slots__ = ("training", "rng_key", "state", "new_state",
-                 "side_losses", "step_rng")
+                 "side_losses", "step_rng", "token_mask", "counters")
 
     def __init__(self, state=None, training=False, rng_key=None):
         self.training = training
@@ -154,6 +154,12 @@ class Ctx:
         # per-timestep key a Recurrent scan threads through its carry so
         # stochastic cells (LSTM/GRU p>0) draw fresh masks each step
         self.step_rng = None
+        # which tokens of the call are real (bool, one a token; None:
+        # all): a serving step's padding and dead slots are not
+        self.token_mask = None
+        # sums a layer counts while it computes, by counter name: traced
+        # values, returned by the program that made the Ctx
+        self.counters: Dict[str, Any] = {}
 
     def rng(self, module) -> jax.Array:
         if self.rng_key is None:
@@ -170,6 +176,9 @@ class Ctx:
 
     def add_loss(self, value):
         self.side_losses.append(value)
+
+    def count(self, name, value):
+        self.counters[name] = self.counters.get(name, 0) + value
 
 
 class Module:

@@ -16,8 +16,11 @@ Load-balancing auxiliary loss (Switch Transformer eq. 4) rides on
 """
 from __future__ import annotations
 
+import importlib
+
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .module import Module
@@ -117,3 +120,149 @@ class SwitchFFN(Module):
             ctx.add_loss(self.aux_loss_weight * aux.astype(jnp.float32))
 
         return out.reshape(B, S, D)
+
+
+# --------------------------------------------------------------------- #
+# fine-grained routed experts, no capacity                              #
+# --------------------------------------------------------------------- #
+# Test hook: the Pallas grouped matmul in interpret mode on the CPU.
+_INTERPRET = False
+
+# (tm, tk, tn) of the Pallas grouped matmul: large weight tiles (an
+# expert's matrix is streamed in one or two), and row tiles no taller than
+# the rows an expert has: every (expert, row tile) visit multiplies a
+# whole tile, so with 32 rows an expert a 512-row tile is sixteen times
+# the work (a chunk's three matmuls took 1.5 ms each against 0.5 of
+# bytes; my chip run, PR 28).
+_TILING = (128, 2048, 1024)
+
+
+def grouped_matmul_path(backend=None):
+    """``(route, why)`` of :func:`grouped_matmul`: ``"pallas"`` on a TPU
+    (also under the interpret test hook), else ``"ragged_dot"``."""
+    if backend is None:
+        backend = jax.default_backend()
+    if backend == "tpu":
+        return "pallas", "tpu backend"
+    if _INTERPRET:
+        return "pallas", "interpret mode"
+    return "ragged_dot", f"backend {backend!r} is not tpu"
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group: lhs
+    (m, k), rhs (groups, k, n), group_sizes (groups,) int32 whose sum may
+    be under m.  Rows past the sum belong to no group: their result is
+    UNDEFINED (the caller masks them).  On a TPU the megablox Pallas
+    kernel that ships with JAX, whose grid is the row tiles that hold a
+    group's rows, so the weights it reads are those of the groups that
+    have rows; elsewhere ``lax.ragged_dot``, which XLA expands to a dense
+    product over every group."""
+    if grouped_matmul_path()[0] == "ragged_dot":
+        return lax.ragged_dot(lhs, rhs, group_sizes)
+    # the package's `gmm` attribute is its differentiable wrapper, which
+    # takes no output dtype; the kernel itself is the submodule's
+    gmm = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tm, tk, tn = _TILING
+    if m <= tm:
+        tm = -(-m // 16) * 16
+    pad = -m % tm
+    if pad:
+        lhs = jnp.concatenate([lhs, jnp.zeros((pad, k), lhs.dtype)])
+    out = gmm.gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                  tiling=(tm, min(tk, k), min(tn, n)),
+                  interpret=jax.default_backend() != "tpu")
+    return out[:m] if pad else out
+
+
+# jitted under a name of its own, so that the device trace and the
+# compiled program's metadata show the experts' matmuls apart from the
+# step's other work
+@jax.jit
+def _moe_experts(xs, w1, w3, w2, group_sizes):
+    h = jax.nn.silu(grouped_matmul(xs, w1, group_sizes)) \
+        * grouped_matmul(xs, w3, group_sizes)
+    return grouped_matmul(h, w2, group_sizes)
+
+
+class RoutedExperts(Module):
+    """Top-k routed SwiGLU experts with no capacity: no token is dropped.
+
+    The router scores every expert (``softmax(x W_r)`` in float32), takes
+    the ``top_k`` largest and weighs a chosen expert by its probability
+    over the sum of the chosen ones.  The (token, expert) pairs are sorted
+    by expert and go through :func:`grouped_matmul`: rows follow the
+    pairs, so a decode step reads the experts its tokens touch and no
+    others, and an expert that takes most of the tokens just has more
+    rows.  ``ctx.token_mask`` (one bool a token; None: all) takes padding
+    and dead slots out of the routing.  Counts into ``ctx``:
+    ``moe/pairs``, ``moe/experts_touched`` (experts with at least one
+    token), ``moe/expert_load_max`` (the fullest expert's tokens).
+
+    Input (B, S, d_model) -> output (B, S, d_model).  The expert dim is
+    sharded over 'ep' as :class:`SwitchFFN`'s is.
+    """
+
+    def __init__(self, d_model, d_ff, n_experts, top_k, name=None):
+        super().__init__(name=name)
+        if not 0 < top_k <= n_experts:
+            raise ValueError(f"top_k {top_k} of {n_experts} experts")
+        self.d_model, self.d_ff = d_model, d_ff
+        self.n_experts, self.top_k = int(n_experts), int(top_k)
+        self.pspec = {"router": P(None, None),
+                      "w1": P("ep", None, "tp"), "w3": P("ep", None, "tp"),
+                      "w2": P("ep", "tp", None)}
+
+    def init(self, rng):
+        k0, k1, k2, k3 = jax.random.split(rng, 4)
+        E, D, F = self.n_experts, self.d_model, self.d_ff
+        s_in, s_out = D ** -0.5, F ** -0.5
+        return {self.name: {
+            "router": jax.random.normal(k0, (D, E), jnp.float32) * s_in,
+            "w1": jax.random.normal(k1, (E, D, F), jnp.float32) * s_in,
+            "w3": jax.random.normal(k3, (E, D, F), jnp.float32) * s_in,
+            "w2": jax.random.normal(k2, (E, F, D), jnp.float32) * s_out,
+        }}
+
+    def route(self, params, xt):
+        """xt (N, D) -> (expert ids (N, top_k), gates (N, top_k) f32)."""
+        probs = jax.nn.softmax(jnp.dot(
+            xt.astype(jnp.float32),
+            self.own(params)["router"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST), axis=-1)
+        picked, idx = lax.top_k(probs, self.top_k)
+        return idx, picked / picked.sum(-1, keepdims=True)
+
+    def apply(self, params, x, ctx):
+        p = self.own(params)
+        dt = x.dtype
+        B, S, D = x.shape
+        N, K, E = B * S, self.top_k, self.n_experts
+        xt = x.reshape(N, D)
+        idx, gate = self.route(params, xt)
+        valid = jnp.ones((N,), bool) if ctx.token_mask is None \
+            else ctx.token_mask.reshape(N)
+        # pairs sorted by expert; those of masked tokens sort behind
+        # every group and are never touched
+        key = jnp.where(valid[:, None], idx, E).reshape(N * K)
+        order = jnp.argsort(key, stable=True)
+        # (a compare-and-sum, not a scatter-add: thousands of updates
+        # into a few dozen bins serialise on a TPU)
+        load = (key[:, None] == jnp.arange(E)[None, :]).sum(
+            0, dtype=jnp.int32)
+        xs = jnp.take(xt, order // K, axis=0)
+        ys = _moe_experts(xs, p["w1"].astype(dt), p["w3"].astype(dt),
+                          p["w2"].astype(dt), load)
+        rows = jnp.arange(N * K) < load.sum()
+        ys = jnp.where(rows[:, None],
+                       ys.astype(jnp.float32)
+                       * gate.reshape(N * K)[order][:, None], 0.0)
+        # back into pair order, then the pairs of a token add up
+        out = jnp.take(ys, jnp.argsort(order), axis=0).reshape(N, K, D).sum(1)
+        ctx.count("moe/pairs", load.sum())
+        ctx.count("moe/experts_touched", (load > 0).sum())
+        ctx.count("moe/expert_load_max", load.max())
+        return out.astype(dt).reshape(B, S, D)

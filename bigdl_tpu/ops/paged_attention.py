@@ -36,12 +36,13 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import DEFAULT_MASK_VALUE
+from .sparse_attention import (index_scores, masked_attention,
+                               positions_of, select_mask)
 
 # Test hook: when True the kernel runs in interpret mode, so the TPU
 # code path itself (not the window route) is exercised on CPU.
@@ -54,25 +55,65 @@ _PAGES_PER_BLOCK = 8
 
 def attend_window(q, k_win, v_win, positions):
     """Single-token attention of q (B, H, 1, Dh) against a gathered
-    window k_win/v_win (B, H, W, Dh): the einsum / scale / mask-value /
-    softmax sequence of ``MultiHeadAttention.apply_cached``, in
-    float32.  ``positions`` (B,) is each row's token index; keys at
-    ``k_pos > positions[b]`` (unwritten, or a recycled page's stale
-    rows) are masked out.  Returns (B, H, 1, Dh) in q's dtype."""
+    window k_win/v_win (B, Hkv, W, Dh), H a multiple of Hkv: the einsum /
+    scale / mask-value / softmax sequence of
+    ``MultiHeadAttention.apply_cached``, in float32.  ``positions`` (B,)
+    is each row's token index; keys at ``k_pos > positions[b]``
+    (unwritten, or a recycled page's stale rows) are masked out and their
+    V rows scrubbed: masked weights are exactly 0, but 0 * NaN = NaN, and
+    a recycled page can hold non-finite rows from a poisoned publication.
+    Returns (B, H, 1, Dh) in q's dtype."""
     k_pos = jnp.arange(k_win.shape[2])
-    s_ = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                    k_win.astype(jnp.float32)) / np.sqrt(q.shape[-1])
     # same semantics as _attn_mask(positions, k_pos, pos+1, True) per
     # row: causal (k <= q) subsumes the kv_len bound at s=1
     mask = k_pos[None, :] <= positions[:, None]          # (B, W)
-    s_ = jnp.where(mask[:, None, None, :], s_, DEFAULT_MASK_VALUE)
-    w_ = jax.nn.softmax(s_, axis=-1)
-    # masked weights are exactly 0, but 0 * NaN = NaN: a recycled KV
-    # page can hold non-finite rows from a poisoned/rejected
-    # publication, and they must not leak through the value sum —
-    # scrub masked V rows (a no-op for finite stale data)
-    v_ = jnp.where(mask[:, None, :, None], v_win.astype(jnp.float32), 0.0)
-    return jnp.einsum("bhqk,bhkd->bhqd", w_, v_).astype(q.dtype)
+    return masked_attention(q, k_win, v_win, mask[:, None, :])
+
+
+# The two halves of the sparse decode route, each jitted under a name of
+# its own: the compiled step's metadata then says which of its ops score
+# and select and which gather and attend (a roofline share each).
+@functools.partial(jax.jit, static_argnames=("top_k",))
+def _index_topk(qi, w, ki_win, lengths, *, top_k):
+    """qi (S, Hi, Di) and w (S, Hi) of each slot's new token against its
+    index keys ki_win (S, W, Di): the positions (S, min(top_k, W)) of the
+    ``top_k`` highest index scores among rows ``0..lengths[s]``, ``-1``
+    where the slot has fewer."""
+    n_rows = ki_win.shape[1]
+    scores = index_scores(qi[:, None], ki_win, w[:, None])
+    visible = jnp.arange(n_rows)[None, :] <= lengths[:, None]
+    # a mask at the top_k-th score (exact, no sort), then the mask's set
+    # positions, in order
+    chosen = select_mask(scores, visible[:, None], top_k)[:, 0]
+    return positions_of(chosen, min(top_k, n_rows))
+
+
+@jax.jit
+def _sparse_attend(q, k_pool, v_pool, tables, positions):
+    """q (S, H, Dh) over the rows at ``positions`` (S, K; -1: none) of
+    each slot, gathered from the pools through the page tables."""
+    _, page_size, hkv, dh = k_pool.shape
+    page = jnp.take_along_axis(tables, jnp.maximum(positions, 0) // page_size,
+                               axis=1)
+    ok = (positions >= 0) & (page >= 0)
+    row = jnp.where(ok, page * page_size + positions % page_size, 0)
+
+    def rows(pool):
+        sel = jnp.take(pool.reshape(-1, hkv, dh), row, axis=0)
+        return jnp.swapaxes(sel, 1, 2)                    # (S, Hkv, K, Dh)
+
+    return masked_attention(q[:, :, None], rows(k_pool), rows(v_pool),
+                            ok[:, None, :])[:, :, 0]
+
+
+def sparse_paged_attention(q, qi, w, k_pool, v_pool, ki_win, tables,
+                           lengths, top_k: int):
+    """Decode attention with the selection inside: score each slot's live
+    index keys, take the ``top_k`` best, gather those K and V rows
+    wherever in the pages they lie, attend.  The row just written at
+    ``lengths[s]`` is a candidate like any other.  -> (S, H, Dh)."""
+    positions = _index_topk(qi, w, ki_win, lengths, top_k=int(top_k))
+    return _sparse_attend(q, k_pool, v_pool, tables, positions)
 
 
 def paged_attention_path(pool_dtype, n_heads: int, head_dim: int, *,
@@ -255,4 +296,5 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, *, pages_per_block,
       q, k_pool, v_pool)
 
 
-__all__ = ["paged_attention", "paged_attention_path", "attend_window"]
+__all__ = ["paged_attention", "paged_attention_path", "attend_window",
+           "sparse_paged_attention"]

@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import faults as faultplane
+from ..nn.module import Ctx
 from ..observability import Recorder
 from .buckets import BucketLadder
 from .kvcache import PagedKVCache
@@ -122,7 +123,7 @@ class _DecodeRequest:
     __slots__ = ("prompt", "max_new", "temperature", "eos_id", "deadline",
                  "arrival", "stream", "generated", "trace", "slot",
                  "first_token_at", "last_token_at", "evictions",
-                 "replay_i")
+                 "replay_i", "prefilled")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  temperature: float, eos_id: Optional[int],
@@ -145,6 +146,10 @@ class _DecodeRequest:
         # its recorded tokens through the decode program to rebuild the
         # evicted KV bitwise (see DecodeEngine._prefill)
         self.replay_i = 0
+        # chunked prefill: how many of the prompt's tokens are cached
+        # while its chunks still run (the slot is held, and takes no
+        # part in decode steps); None before and after
+        self.prefilled: Optional[int] = None
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now > self.deadline
@@ -167,6 +172,15 @@ class DecodeEngine:
     ``max_context`` longest prompt+generation a slot may hold
     ``max_prompt``  admission cap on client prompt length
                     (readmissions may re-prefill up to max_context)
+    ``prefill_chunk``  prompt tokens one prefill program takes of a long
+                    prompt.  Where ``max_prompt`` is at most this, every
+                    prompt is prefilled whole, in the tick that admits
+                    it, through the bucket ladder.  Where it is more,
+                    prompts go through ONE chunk program, a chunk a tick
+                    between decode steps, each chunk attending the
+                    slot's own pages (so the gap a prefill puts between
+                    two tokens of the live slots is one chunk's, however
+                    long the prompt); a multiple of ``page_size``
     ``max_new_tokens``  default generation budget per request
     ``max_waiting`` waiting-queue bound, in requests — beyond it
                     submit sheds with :class:`LoadShedError`
@@ -187,6 +201,7 @@ class DecodeEngine:
                  pool_pages: Optional[int] = None,
                  max_context: Optional[int] = None,
                  max_prompt: Optional[int] = None,
+                 prefill_chunk: int = 512,
                  max_new_tokens: int = 32, max_waiting: int = 64,
                  int8_kv: bool = False, kv_dtype=None,
                  eos_id: Optional[int] = None, seed: int = 0,
@@ -236,16 +251,30 @@ class DecodeEngine:
         # the decode program, so the ladder tops out at max_prompt —
         # compiling buckets up to max_context would burn minutes of
         # warmup on programs nothing can reach
-        self.ladder = BucketLadder(self.max_prompt)
+        # a prompt past the chunk goes through the one chunk program, and
+        # then so does every prompt: the ladder is empty
+        self.prefill_chunk = int(prefill_chunk)
+        self.chunked = self.max_prompt > self.prefill_chunk
+        if self.chunked and self.prefill_chunk % page_size:
+            raise ValueError(f"prefill_chunk {prefill_chunk} must be a "
+                             f"multiple of page_size {page_size}")
+        self.ladder = () if self.chunked else BucketLadder(self.max_prompt)
+        # the table a chunk program takes: the pages of the longest prompt
+        self._chunk_pages = -(-self.max_prompt // self.prefill_chunk) \
+            * self.prefill_chunk // page_size
         self.kv = PagedKVCache(
             [blk.attn.name for blk in model.blocks],
-            n_heads=cfg.n_heads, head_dim=cfg.head_dim,
+            n_heads=cfg.n_kv_heads, q_heads=cfg.n_heads,
+            head_dim=cfg.head_dim,
+            index_dim=cfg.index_dim if cfg.index_heads else 0,
+            index_top_k=cfg.index_top_k if cfg.index_heads else 0,
             n_pages=pool_pages if pool_pages is not None
             else self.slots * -(-self.max_context // page_size),
             page_size=page_size, n_slots=self.slots,
             max_context=self.max_context,
             dtype=kv_dtype or jnp.dtype(cfg.dtype), int8=int8_kv,
             recorder=self.recorder)
+        self.recorder.gauge("kv/index_bytes", self.kv.index_bytes())
         self._base_key = jax.random.PRNGKey(int(seed))
         self._pool = self.kv.init_pool()
         self._pool_avals = jax.tree_util.tree_map(
@@ -262,6 +291,9 @@ class DecodeEngine:
         self._lock = threading.Condition()
         self._waiting: List[_DecodeRequest] = []
         self._programs: Dict[Any, Any] = {}
+        # what the layers of each program count (Ctx.count), in the order
+        # of the vector the program returns beside its tokens
+        self._count_names: Dict[str, List[str]] = {}
         self._warmed = False
         self._closed = False
         self._drain = True
@@ -282,6 +314,8 @@ class DecodeEngine:
                 ledger_phase(self.recorder, "compile_warmup"):
             for bucket in self.ladder:
                 self._program("prefill", bucket)
+            if self.chunked:
+                self._program("chunk")
             self._program("decode")
         with self._lock:
             self._warmed = True
@@ -460,7 +494,8 @@ class DecodeEngine:
     def stats(self) -> Dict[str, Any]:
         rec = self.recorder
         out = {k: rec.counter_value(f"decode/{k}")
-               for k in ("requests", "prefills", "readmissions", "steps",
+               for k in ("requests", "prefills", "prefill_chunks",
+                         "readmissions", "steps",
                          "tokens", "finished", "shed_queue_full",
                          "shed_deadline", "recompiles", "warmup_compiles",
                          "errors")}
@@ -474,6 +509,11 @@ class DecodeEngine:
         out["kv_pages_read_share"] = rec.counter_value("kv/pages_read") \
             / max(rec.counter_value("kv/pages_window"), 1.0)
         out["attn_route"] = self.kv.attention_path()[0]
+        # the sparse route: rows the steps' attention read of the rows
+        # that were live (0 over 0 for a model with no indexer)
+        out["kv_rows_attended_share"] = \
+            rec.counter_value("sparse/rows_attended") \
+            / max(rec.counter_value("sparse/rows_live"), 1.0)
         for h, label in (("decode/ttft_ms", "ttft"),
                          ("decode/intertoken_ms", "intertoken")):
             q = rec.hist_quantiles(h, (50.0, 99.0))
@@ -518,20 +558,35 @@ class DecodeEngine:
         base_key = self._base_key
         if kind == "decode":
             # which way the step's attention goes (kvcache.attend picks
-            # it while tracing): 1 = pallas, 0 = gather
+            # it while tracing): 0 = gather, 1 = pallas, 2 = sparse
             self.recorder.gauge("decode/attn_route", float(
-                kv.attention_path()[0] == "pallas"))
+                _ATTN_ROUTES.index(kv.attention_path()[0])))
 
             def fn(params, pool, tokens, lengths, tables, temps, step):
                 new_pool = dict(pool)
+                ctx = Ctx(state={}, training=False, rng_key=None)
+                live = tables[:, 0] >= 0
+                ctx.token_mask = live
 
-                def kv_io(name, q, k_new, v_new):
+                def kv_io(name, q, k_new, v_new, index=None):
+                    if index is None:
+                        new_pool[name] = kv.write_token(
+                            new_pool[name], tables, lengths, k_new, v_new)
+                        return kv.attend(new_pool[name], tables, lengths, q)
+                    qi, ki, w = index
                     new_pool[name] = kv.write_token(
-                        new_pool[name], tables, lengths, k_new, v_new)
-                    return kv.attend(new_pool[name], tables, lengths, q)
+                        new_pool[name], tables, lengths, k_new, v_new,
+                        ki[:, 0])
+                    rows = jnp.where(live, lengths + 1, 0)
+                    ctx.count("sparse/rows_live", rows.sum())
+                    ctx.count("sparse/rows_scored", rows.sum())
+                    ctx.count("sparse/rows_attended",
+                              jnp.minimum(rows, kv.index_top_k).sum())
+                    return kv.attend(new_pool[name], tables, lengths, q,
+                                     (qi[:, 0], w[:, 0]))
 
                 logits = model.decode_tokens(params, tokens, lengths,
-                                             kv_io)
+                                             kv_io, ctx)
                 tok = _select_tokens(logits, temps, step, base_key)
                 # poisoned-weights sentinel: argmax of NaN logits is a
                 # VALID token id, so without this a poisoned publish
@@ -539,7 +594,7 @@ class DecodeEngine:
                 # the engine fail exactly the affected requests (and a
                 # canary golden-decode reject the publication)
                 bad = ~jnp.isfinite(logits).all(axis=-1)
-                return tok, bad, new_pool
+                return (tok, bad, new_pool) + self._counts(kind, ctx)
 
             args = (self._aval_params(), self._pool_avals,
                     jax.ShapeDtypeStruct((self.slots,), jnp.int32),
@@ -548,6 +603,42 @@ class DecodeEngine:
                         (self.slots, self.kv.max_pages_per_slot),
                         jnp.int32),
                     jax.ShapeDtypeStruct((self.slots,), jnp.float32),
+                    jax.ShapeDtypeStruct((), jnp.int32))
+        elif kind == "chunk":
+            chunk, n_pages = self.prefill_chunk, self._chunk_pages
+
+            def prefill_chunk(params, pool, tokens, start, n_valid,
+                              table, temp, step):
+                new_pool = dict(pool)
+                ctx = Ctx(state={}, training=False, rng_key=None)
+                pages = jax.lax.dynamic_slice_in_dim(
+                    table, start // kv.page_size, chunk // kv.page_size)
+
+                def kv_io(name, q, k, v, index=None):
+                    qi, ki, w = index or (None, None, None)
+                    new_pool[name] = kv.write_chunk(
+                        new_pool[name], pages, k, v, ki)
+                    return kv.attend_chunk(
+                        new_pool[name], table, start, q,
+                        None if index is None else (qi, w))
+
+                last = model.prefill_chunk(params, tokens, start, n_valid,
+                                           kv_io, ctx)
+                tok = _select_tokens(last[None, :], temp[None], step,
+                                     base_key)[0]
+                bad = ~jnp.isfinite(last).all()
+                return (tok, bad, new_pool) + self._counts(kind, ctx)
+
+            # (a name without `fn`: a reader of the device trace tells the
+            # decode step as the most frequent module with `fn` in it, and
+            # a window that opens on a long prompt runs more chunks)
+            fn = prefill_chunk
+            args = (self._aval_params(), self._pool_avals,
+                    jax.ShapeDtypeStruct((1, chunk), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32),
+                    jax.ShapeDtypeStruct((n_pages,), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.float32),
                     jax.ShapeDtypeStruct((), jnp.int32))
         else:
             n_pages = -(-bucket // kv.page_size)
@@ -563,7 +654,7 @@ class DecodeEngine:
                 for name in kv.layer_names:
                     new_pool[name] = kv.write_prefill(
                         new_pool[name], table, cache[name]["k"],
-                        cache[name]["v"])
+                        cache[name]["v"], cache[name].get("ki"))
                 last = jnp.take(logits[0], true_len - 1, axis=0)
                 tok = _select_tokens(last[None, :], temp[None], step,
                                      base_key)[0]
@@ -581,6 +672,23 @@ class DecodeEngine:
         if not self._warmed:
             self.recorder.inc("decode/warmup_compiles")
         return prog
+
+    def _counts(self, kind, ctx):
+        """What a program's layers counted (``Ctx.count``), as the tail of
+        the program's outputs: ONE float32 vector beside its tokens, so it
+        comes to the host in the step's own sync, or nothing for a model
+        that counts nothing (its programs are what they were).  The names
+        go by program kind, noted while tracing."""
+        self._count_names[kind] = sorted(ctx.counters)
+        return (jnp.stack([jnp.asarray(ctx.counters[k], jnp.float32)
+                           for k in self._count_names[kind]]),) \
+            if ctx.counters else ()
+
+    def _count_in(self, kind, counts, prefix=""):
+        for name, value in zip(self._count_names[kind],
+                               np.asarray(counts[0]) if counts else ()):
+            head, _, leaf = name.partition("/")
+            self.recorder.inc(f"{head}/{prefix}{leaf}", float(value))
 
     def _params_for_step(self, entry):
         """Device-placed params of the CURRENT snapshot, cached per
@@ -667,7 +775,16 @@ class DecodeEngine:
 
     def _admit(self):
         """Move waiting requests into free slots (expired ones shed);
-        each admission is one bucketed prefill."""
+        each admission is one bucketed prefill.  On the chunked route a
+        tick runs at most ONE chunk of ONE request, first come first
+        served: the next chunk of the prompt that is part-way, else the
+        first chunk of the queue's head."""
+        if self.chunked:
+            part = next((s for s, r in self._live.items()
+                         if r.prefilled is not None), None)
+            if part is not None:
+                self._try_prefill(part, self._live[part])
+                return
         while True:
             with self._lock:
                 if not self._waiting:
@@ -708,14 +825,22 @@ class DecodeEngine:
                     depth = len(self._waiting)
                 self.recorder.gauge("decode/queue_depth", depth)
                 return
-            try:
-                self._prefill(slot, req, prompt)
-            except Exception as e:
-                self.recorder.inc("decode/errors")
-                self._live.pop(slot, None)
-                self.kv.free_slot(slot)
-                self._finish(req, exc=e)
-                self._recover_pool(e)
+            self._try_prefill(slot, req)
+            if self.chunked:
+                return
+
+    def _try_prefill(self, slot: int, req: _DecodeRequest):
+        try:
+            if self.chunked:
+                self._prefill_chunk(slot, req)
+            else:
+                self._prefill(slot, req, req.prompt)
+        except Exception as e:
+            self.recorder.inc("decode/errors")
+            self._live.pop(slot, None)
+            self.kv.free_slot(slot)
+            self._finish(req, exc=e)
+            self._recover_pool(e)
 
     def _evict_for(self, needy_slot: int, n_tokens: int) -> bool:
         """Evict slots YOUNGER than ``needy_slot`` (most recent
@@ -747,6 +872,7 @@ class DecodeEngine:
         req = self._live.pop(slot)
         self.kv.free_slot(slot, evict=True)
         req.slot = None
+        req.prefilled = None     # part-way through its chunks: again
         req.evictions += 1
         if req.trace is not None:
             req.trace.meta["evictions"] = req.evictions
@@ -757,19 +883,88 @@ class DecodeEngine:
         # the runbook reads it
         self.recorder.gauge("decode/queue_depth", depth)
 
-    def _prefill(self, slot: int, req: _DecodeRequest, prompt: np.ndarray):
-        rec = self.recorder
-        t0 = time.monotonic()
+    def _prefill_begins(self, req: _DecodeRequest, t0: float):
+        """The request leaves the queue: its trace's spans, and the
+        `decode.queue` span under its own trace id, like its prefill's,
+        so a reader can join the two.  Returns the trace id."""
         trace_id = None
         if req.trace is not None:
             req.trace.close("queue", t0)
             req.trace.open("prefill", t0)
             trace_id = req.trace.trace_id
         if not req.evictions:
-            # the request's wait for a slot, under its own trace id like
-            # its prefill below, so a reader can join the two
-            rec.add_span("decode.queue", t0 - req.arrival,
-                         trace_id=trace_id)
+            self.recorder.add_span("decode.queue", t0 - req.arrival,
+                                   trace_id=trace_id)
+        return trace_id
+
+    def _prefill_chunk(self, slot: int, req: _DecodeRequest):
+        """One chunk of ``req``'s prompt in ``slot``.  From its first
+        chunk the request holds the slot (in ``_live``, ``prefilled``
+        set) and sits out the decode steps; its first token follows its
+        last chunk."""
+        rec = self.recorder
+        t0 = time.monotonic()
+        chunk, prompt = self.prefill_chunk, req.prompt
+        if req.prefilled is None:
+            self._prefill_begins(req, t0)
+            req.prefilled = 0
+            req.slot = slot
+            # _live and the slot arrays are decode-thread-only (single
+            # mutator: see the note in _admitted)
+            self._live[slot] = req           # graftlint: disable=GL003
+            self._lengths[slot] = 0          # graftlint: disable=GL003
+            self._admitted_at[slot] = t0
+        trace_id = req.trace.trace_id if req.trace is not None else None
+        start = req.prefilled
+        n = min(chunk, prompt.size - start)
+        toks = np.zeros((1, chunk), np.int32)
+        toks[0, :n] = prompt[start:start + n]
+        table = np.full(self._chunk_pages, -1, np.int32)
+        m = min(self._chunk_pages, self.kv.max_pages_per_slot)
+        table[:m] = self.kv.tables[slot, :m]
+        entry = self.registry.get(self.model_name)
+        prog = self._program("chunk")
+        with rec.span("decode.prefill", trace_id=trace_id, slot=slot,
+                      offset=start):
+            tok, bad, self._pool, *counts = prog(
+                self._params_for_step(entry), self._pool,
+                jnp.asarray(toks), jnp.int32(start), jnp.int32(n),
+                jnp.asarray(table), jnp.float32(req.temperature),
+                jnp.int32(self._steps))
+            token = int(tok)
+            # what the layers counted, under a prefill's own names: a
+            # chunk's pairs are not a decode step's
+            self._count_in("chunk", counts, prefix="prefill_")
+        rec.inc("decode/prefill_chunks")
+        if bool(bad):
+            self._live.pop(slot, None)
+            self._prefill_poisoned(slot, req, entry, chunk)
+            return
+        if start + n < prompt.size:
+            req.prefilled = start + n
+            return
+        req.prefilled = None
+        self._admitted(slot, req, token, chunk)
+
+    def _prefill_poisoned(self, slot, req, entry, bucket):
+        # poisoned-weights sentinel: the program call SUCCEEDED
+        # (self._pool was reassigned), so this is one request's
+        # failure, not a donation hazard — fail it alone; the other
+        # live slots' KV is intact and must survive (_recover_pool
+        # would collaterally error every in-flight request)
+        self.recorder.inc("decode/nonfinite")
+        req.prefilled = None
+        if req.trace is not None:
+            req.trace.close("prefill", time.monotonic(), bucket=bucket)
+        self.kv.free_slot(slot)
+        self._finish(req, exc=RuntimeError(
+            f"non-finite prefill logits serving "
+            f"{entry.snapshot.version} — poisoned weights?"),
+            cause="nonfinite")
+
+    def _prefill(self, slot: int, req: _DecodeRequest, prompt: np.ndarray):
+        rec = self.recorder
+        trace_id = self._prefill_begins(req, time.monotonic())
         bucket = self.ladder.bucket_for(prompt.size)
         prog = self._program("prefill", bucket)
         toks = np.zeros((1, bucket), np.int32)
@@ -790,21 +985,15 @@ class DecodeEngine:
                 jnp.int32(self._steps))
             token = int(tok)
         if bool(bad):
-            # poisoned-weights sentinel: the program call SUCCEEDED
-            # (self._pool was reassigned), so this is one request's
-            # failure, not a donation hazard — fail it alone; the other
-            # live slots' KV is intact and must survive (_recover_pool
-            # would collaterally error every in-flight request)
-            rec.inc("decode/nonfinite")
-            if req.trace is not None:
-                req.trace.close("prefill", time.monotonic(),
-                                bucket=bucket)
-            self.kv.free_slot(slot)
-            self._finish(req, exc=RuntimeError(
-                f"non-finite prefill logits serving "
-                f"{entry.snapshot.version} — poisoned weights?"),
-                cause="nonfinite")
+            self._prefill_poisoned(slot, req, entry, bucket)
             return
+        self._admitted(slot, req, token, bucket)
+
+    def _admitted(self, slot: int, req: _DecodeRequest, token: int,
+                  bucket: int):
+        """The prompt is cached: the slot joins the decode steps."""
+        rec = self.recorder
+        prompt = req.prompt
         now = time.monotonic()
         rec.inc("decode/prefills")
         led = rec.get_ledger()
@@ -844,8 +1033,8 @@ class DecodeEngine:
     def _step_live(self) -> Optional[int]:
         """One fixed-shape decode step over every live slot; returns the
         step's index, or None when no step ran."""
-        if not self._live:
-            return None
+        if all(r.prefilled is not None for r in self._live.values()):
+            return None            # nobody, or prompts still in chunks
         rec = self.recorder
         with rec.span("decode.schedule"):
             now = time.monotonic()
@@ -853,8 +1042,9 @@ class DecodeEngine:
             # step's inputs are consistent
             for slot in list(self._live):
                 req = self._live.get(slot)
-                if req is None:
-                    continue        # evicted by an earlier slot's growth
+                if req is None or req.prefilled is not None:
+                    continue        # evicted by an earlier slot's growth,
+                    # or part-way through its chunks: not in this step
                 if req.expired(now):
                     self._live.pop(slot)
                     self.kv.free_slot(slot)
@@ -865,18 +1055,27 @@ class DecodeEngine:
                                            int(self._lengths[slot]) + 1):
                         # nothing else to evict: this slot itself yields
                         self._evict(slot)
-            if not self._live:
+            live_slots = sorted(s for s, r in self._live.items()
+                                if r.prefilled is None)
+            if not live_slots:
                 return None
-            live_slots = sorted(self._live)
             tokens = self._last_tokens.copy()
             lengths = self._lengths.copy()
+            tables = self.kv.tables
             temps = np.zeros(self.slots, np.float32)
             for s in live_slots:
                 temps[s] = self._live[s].temperature
-            dead = [s for s in range(self.slots) if s not in self._live]
-            for s in dead:
+            for s in range(self.slots):
+                if s in live_slots:
+                    continue
                 tokens[s] = 0
                 lengths[s] = 0
+                if s in self._live:
+                    # held for a prompt still in chunks: the step must
+                    # neither write its pages nor count it live
+                    if tables is self.kv.tables:
+                        tables = tables.copy()
+                    tables[s] = -1
             # the pages this step's attention has to read (each live
             # slot's, the new token's included) of those a gathered
             # window holds
@@ -893,15 +1092,18 @@ class DecodeEngine:
         with rec.span("decode.stage"):
             params = self._params_for_step(entry)
             inputs = (jnp.asarray(tokens), jnp.asarray(lengths),
-                      jnp.asarray(self.kv.tables), jnp.asarray(temps),
+                      jnp.asarray(tables), jnp.asarray(temps),
                       jnp.int32(self._steps))
         with rec.span("decode.dispatch"):
-            tok, bad, self._pool = prog(params, self._pool, *inputs)
+            tok, bad, self._pool, *counts = prog(params, self._pool,
+                                                 *inputs)
             del inputs
         with rec.span("decode.sync"):
             toks = np.asarray(tok)     # the per-step host sync — the
             # serving contract: every emitted token crosses to the host
             bads = np.asarray(bad)
+            # what the layers counted in the step, ready with the tokens
+            self._count_in("decode", counts)
         with rec.span("decode.emit"):
             step = self._emit_step(entry, live_slots, toks, bads)
             # the step's device handles go once the tokens are out, as
@@ -920,7 +1122,7 @@ class DecodeEngine:
         token.  Returns the step's index (None when no slot survived)."""
         rec = self.recorder
         now = time.monotonic()
-        for slot in list(self._live):
+        for slot in live_slots:
             if slot in self._live and bads[slot]:
                 rec.inc("decode/nonfinite")
                 req = self._live.pop(slot)
@@ -1070,6 +1272,9 @@ class DecodeEngine:
             intertoken=rec.hist_quantiles("decode/intertoken_ms",
                                           (50.0, 99.0)),
             counters=counters)
+
+
+_ATTN_ROUTES = ("gather", "pallas", "sparse")
 
 
 def _select_tokens(logits, temps, step, base_key):

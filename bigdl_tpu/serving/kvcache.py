@@ -23,8 +23,13 @@ step never sees the allocator — it takes the page tables as a plain
     :func:`bigdl_tpu.ops.paged_attention_path` picks from what the code
     can observe.  On a TPU, for a float pool whose rows tile, a Pallas
     kernel reads the live pages where they lie, up to each slot's
-    length, in the dtype they are stored in.  Elsewhere (CPU, an int8
-    pool) the pages are **gathered** back into a contiguous window
+    length, in the dtype they are stored in.  A pool that also holds
+    **index keys** (``index_dim``: one narrow row a token beside K and
+    V, in the same pages under the same tables) takes the **sparse**
+    route: score the slot's live index keys, take the ``index_top_k``
+    best, gather only those K and V rows.  Elsewhere (CPU, an int8
+    pool, query heads grouped over fewer KV heads) the pages are
+    **gathered** back into a contiguous window
     ``(slots, heads, max_pages * page_size, head_dim)`` — a fixed-shape
     gather; ``-1`` entries **fill** with zeros (``mode="fill"``),
     exactly the zero rows an unwritten contiguous cache would hold, so
@@ -62,7 +67,9 @@ import numpy as np
 
 from ..observability import Recorder
 from ..ops.paged_attention import (attend_window, paged_attention,
-                                   paged_attention_path)
+                                   paged_attention_path,
+                                   sparse_paged_attention)
+from ..ops.sparse_attention import attend as attend_rows
 from ..quantized import dequantize_rows, quantize_rows
 
 
@@ -74,7 +81,11 @@ class PagedKVCache:
     """Device page pool + host allocator + the jitted write/attend fns.
 
     ``layer_names``   attention-module names (one k/v pool each)
-    ``n_heads`` / ``head_dim``  per-layer KV row geometry
+    ``n_heads`` / ``head_dim``  per-layer KV row geometry (the KV heads;
+                      ``q_heads`` query heads share them, default as many)
+    ``index_dim`` / ``index_top_k``  > 0: every token also caches one
+                      index key of that width, and attention reads only
+                      the ``index_top_k`` rows it scores highest
     ``n_pages``       pool size, in pages, shared by all slots
     ``page_size``     token rows per page
     ``n_slots``       concurrent sequences (page-table rows)
@@ -96,12 +107,20 @@ class PagedKVCache:
                  head_dim: int, n_pages: int, page_size: int = 16,
                  n_slots: int = 8, max_context: int = 256,
                  dtype=jnp.float32, int8: bool = False,
+                 q_heads: Optional[int] = None, index_dim: int = 0,
+                 index_top_k: int = 0,
                  recorder: Optional[Recorder] = None):
         if page_size < 1 or n_pages < 1 or n_slots < 1:
             raise ValueError("page_size, n_pages and n_slots must be >= 1")
+        if index_dim and (int8 or index_top_k < 1):
+            raise ValueError("an index-key pool is a float pool with "
+                             "index_top_k >= 1")
         self.layer_names = list(layer_names)
         self.n_heads = int(n_heads)
+        self.q_heads = int(q_heads or n_heads)
         self.head_dim = int(head_dim)
+        self.index_dim = int(index_dim)
+        self.index_top_k = int(index_top_k)
         self.n_pages = int(n_pages)
         self.page_size = int(page_size)
         self.n_slots = int(n_slots)
@@ -123,10 +142,10 @@ class PagedKVCache:
     # -- device pool ------------------------------------------------------ #
     def init_pool(self):
         """Zeroed device pool pytree: ``{layer: {"k", "v"[, "k_scale",
-        "v_scale"]}}`` with pages laid out ``(n_pages, page_size,
+        "v_scale"][, "ki"]}}`` with pages laid out ``(n_pages, page_size,
         n_heads, head_dim)`` (scales ``(n_pages, page_size, n_heads,
-        1)``).  Zero pages read back as the zero rows of a fresh
-        contiguous cache."""
+        1)``, index keys ``(n_pages, page_size, index_dim)``).  Zero
+        pages read back as the zero rows of a fresh contiguous cache."""
         shape = (self.n_pages, self.page_size, self.n_heads, self.head_dim)
         sshape = shape[:-1] + (1,)
 
@@ -136,10 +155,19 @@ class PagedKVCache:
                         "v": jnp.zeros(shape, jnp.int8),
                         "k_scale": jnp.zeros(sshape, jnp.float32),
                         "v_scale": jnp.zeros(sshape, jnp.float32)}
-            return {"k": jnp.zeros(shape, self.dtype),
-                    "v": jnp.zeros(shape, self.dtype)}
+            out = {"k": jnp.zeros(shape, self.dtype),
+                   "v": jnp.zeros(shape, self.dtype)}
+            if self.index_dim:
+                out["ki"] = jnp.zeros(shape[:2] + (self.index_dim,),
+                                      self.dtype)
+            return out
 
         return {name: one() for name in self.layer_names}
+
+    def index_bytes(self) -> int:
+        """Bytes the index keys take of the pool (``kv/index_bytes``)."""
+        return len(self.layer_names) * self.n_pages * self.page_size \
+            * self.index_dim * self.dtype.itemsize
 
     # -- host allocator --------------------------------------------------- #
     def pages_for(self, n_tokens: int) -> int:
@@ -264,30 +292,53 @@ class PagedKVCache:
         return (one(layer_pool["k"], layer_pool.get("k_scale")),
                 one(layer_pool["v"], layer_pool.get("v_scale")))
 
+    def gather_index(self, layer_pool, tables):
+        """The index keys of ``tables`` (slots, pages) as ``(slots, pages *
+        page_size, index_dim)``; ``-1`` entries fill with zeros."""
+        pages = jnp.take(layer_pool["ki"], self._oob(tables), axis=0,
+                         mode="fill", fill_value=0)
+        return pages.reshape(tables.shape[0], -1, self.index_dim)
+
     def attention_path(self, backend: Optional[str] = None
                        ) -> Tuple[str, str]:
-        """``(route, why)`` of :meth:`attend` for this pool: ``"pallas"``
-        (pages read in place) or ``"gather"`` (window, then float32
-        math) — :func:`~bigdl_tpu.ops.paged_attention_path` over the
-        pool's dtype and row geometry."""
+        """``(route, why)`` of :meth:`attend` for this pool: ``"sparse"``
+        (index keys scored, the best rows gathered), ``"pallas"`` (pages
+        read in place) or ``"gather"`` (window, then float32 math) —
+        for the last two :func:`~bigdl_tpu.ops.paged_attention_path` over
+        the pool's dtype and row geometry."""
+        if self.index_dim:
+            return "sparse", (f"the pool holds index keys: top "
+                              f"{self.index_top_k} rows a slot")
+        if self.q_heads != self.n_heads:
+            return "gather", (f"{self.q_heads} query heads over "
+                              f"{self.n_heads} KV heads: the kernel reads "
+                              "one KV head a query head")
         return paged_attention_path(
             jnp.int8 if self.int8 else self.dtype, self.n_heads,
             self.head_dim, backend=backend)
 
-    def attend(self, layer_pool, tables, lengths, q):
+    def attend(self, layer_pool, tables, lengths, q, index=None):
         """Single-token attention of q ``(slots, heads, 1, head_dim)``
         over each slot's pages, the row :meth:`write_token` just wrote
         at ``lengths[s]`` included (write, then attend).  Keys past it
         are masked and their V rows zeroed, so a recycled page's stale
         or non-finite rows cannot leak; a dead slot reads zeros (its
-        token is never emitted).  Returns ``(slots, heads, 1,
-        head_dim)`` in q's dtype."""
+        token is never emitted).  ``index`` = (qi ``(slots, index heads,
+        index_dim)``, w ``(slots, index heads)``) of an index-key pool.
+        Returns ``(slots, heads, 1, head_dim)`` in q's dtype."""
         route, why = self.attention_path()
+        if route == "sparse":
+            qi, w = index
+            return sparse_paged_attention(
+                q[:, :, 0], qi, w, layer_pool["k"], layer_pool["v"],
+                self.gather_index(layer_pool, tables), tables, lengths,
+                self.index_top_k)[:, :, None]
         if route == "pallas":
             return paged_attention(q[:, :, 0], layer_pool["k"],
                                    layer_pool["v"], tables,
                                    lengths)[:, :, None]
-        if jax.default_backend() == "tpu" and not self.int8:
+        if jax.default_backend() == "tpu" and not self.int8 \
+                and self.q_heads == self.n_heads:
             # on the chip the window is never the intended route for a
             # float pool: say so (once per call site)
             warnings.warn("PagedKVCache.attend gathers every slot's "
@@ -296,12 +347,14 @@ class PagedKVCache:
         k_win, v_win = self.gather_window(layer_pool, tables)
         return attend_window(q, k_win, v_win, lengths)
 
-    def write_token(self, layer_pool, tables, lengths, k_new, v_new):
+    def write_token(self, layer_pool, tables, lengths, k_new, v_new,
+                    ki_new=None):
         """Scatter one new k/v row per slot into the pool at
         ``(table[len // page], len % page)``.  k_new/v_new are
         ``(slots, heads, 1, head_dim)`` (the
-        :meth:`~bigdl_tpu.models.transformer.MultiHeadAttention.project_qkv_rows`
-        output); dead slots' ``-1`` page indices drop."""
+        :meth:`~bigdl_tpu.models.transformer.MultiHeadAttention.project_qkv`
+        output), ki_new ``(slots, index_dim)`` the index key of an
+        index-key pool; dead slots' ``-1`` page indices drop."""
         pidx = self._oob(jnp.take_along_axis(
             tables, (lengths // self.page_size)[:, None], axis=1)[:, 0])
         off = lengths % self.page_size
@@ -317,26 +370,34 @@ class PagedKVCache:
             else:
                 out[key] = layer_pool[key].at[pidx, off].set(
                     row.astype(layer_pool[key].dtype), mode="drop")
+        if ki_new is not None:
+            out["ki"] = layer_pool["ki"].at[pidx, off].set(
+                ki_new.astype(layer_pool["ki"].dtype), mode="drop")
         return out
 
-    def write_prefill(self, layer_pool, table, k, v):
+    def write_prefill(self, layer_pool, table, k, v, ki=None):
         """Scatter a contiguous prefill's k/v ``(1, heads, Lb, head_dim)``
-        into the pages of ``table`` (``ceil(Lb / page_size)`` entries,
-        ``-1``-padded past the slot's allocation — those pages hold
-        only prompt-padding rows, which the per-slot attention mask
-        never exposes, so dropping them is exact)."""
+        (and index keys ki ``(1, Lb, index_dim)``) into the pages of
+        ``table`` (``ceil(Lb / page_size)`` entries, ``-1``-padded past
+        the slot's allocation — those pages hold only prompt-padding
+        rows, which the per-slot attention mask never exposes, so
+        dropping them is exact)."""
         pg = self.page_size
         table = self._oob(table)
         out = dict(layer_pool)
-        for key, arr in (("k", k), ("v", v)):
-            rows = jnp.transpose(arr[0], (1, 0, 2))   # (Lb, H, Dh)
+        for key, arr in (("k", k), ("v", v), ("ki", ki)):
+            if arr is None:
+                continue
+            # (Lb, H, Dh); an index key is one row of no heads
+            rows = arr[0] if key == "ki" \
+                else jnp.transpose(arr[0], (1, 0, 2))
             lb = rows.shape[0]
             n_pages = math.ceil(lb / pg)
             if lb % pg:
                 rows = jnp.concatenate(
                     [rows, jnp.zeros((n_pages * pg - lb,) + rows.shape[1:],
                                      rows.dtype)], axis=0)
-            pages = rows.reshape(n_pages, pg, self.n_heads, self.head_dim)
+            pages = rows.reshape((n_pages, pg) + rows.shape[1:])
             if self.int8:
                 q, sc = quantize_rows(pages, axis=-1)
                 out[key] = layer_pool[key].at[table].set(q, mode="drop")
@@ -346,6 +407,52 @@ class PagedKVCache:
                 out[key] = layer_pool[key].at[table].set(
                     pages.astype(layer_pool[key].dtype), mode="drop")
         return out
+
+    def write_chunk(self, layer_pool, table, k, v, ki=None):
+        """A prefill chunk's rows into the pages of ``table`` (the chunk's
+        own, ``C / page_size`` entries): k/v ``(1, heads, C, head_dim)``,
+        ki ``(1, C, index_dim)``.  Row by row, as :meth:`write_token`
+        scatters: a scatter of whole pages has the compiler re-lay every
+        layer's whole pool for it and back, which a pool of gigabytes
+        cannot pay a chunk (a float pool only)."""
+        n = k.shape[2]
+        at = jnp.arange(n)
+        pidx = jnp.take(self._oob(table), at // self.page_size)
+        off = at % self.page_size
+        out = dict(layer_pool)
+        for key, rows in (("k", jnp.swapaxes(k[0], 0, 1)),
+                          ("v", jnp.swapaxes(v[0], 0, 1)),
+                          ("ki", None if ki is None else ki[0])):
+            if rows is not None:
+                out[key] = layer_pool[key].at[pidx, off].set(
+                    rows.astype(layer_pool[key].dtype), mode="drop")
+        return out
+
+    def attend_chunk(self, layer_pool, table, start, q, index=None):
+        """A prefill chunk's attention against the slot's own pages, the
+        chunk's rows (written before, :meth:`write_chunk`) included: q
+        ``(1, heads, C, head_dim)`` at positions ``start + arange(C)``,
+        each over the keys up to its own position, with the
+        top-``index_top_k`` selection of an index-key pool applied per
+        query (``index`` = (qi ``(1, C, index heads, index_dim)``, w
+        ``(1, C, index heads)``)).  ``table`` holds the pages of the
+        longest prompt, and the whole of that window is gathered and
+        scored whatever ``start`` is: a chunk costs the same wherever in
+        its prompt it falls, so the gap it puts between two tokens of the
+        live slots is one length.  (A ladder of shorter windows for early
+        chunks served a fifth more requests a second, and put the gaps'
+        95th percentile on one rung or another, 53 or 99 ms, by the order
+        the prompts came in; my chip runs, PR 28.)  Returns ``(1, heads,
+        C, head_dim)``."""
+        chunk = q.shape[2]
+        tab = table[None]
+        k_win, v_win = self.gather_window(layer_pool, tab)
+        if index is not None:
+            index = (index[0], self.gather_index(layer_pool, tab), index[1])
+        return attend_rows(q, k_win, v_win,
+                           (start + jnp.arange(chunk))[None],
+                           jnp.asarray(start + chunk)[None], index,
+                           self.index_top_k)
 
 
 __all__ = ["PagedKVCache", "PagePoolError"]

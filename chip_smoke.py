@@ -59,7 +59,19 @@ SIZES = dict(
     # it fits the smoke's time (the engine's own default would be 2047)
     serve=dict(slots=8, max_context=2048, max_prompt=256,
                prompts=(5, 17, 33, 64, 100, 129, 200, 256),
-               new_tokens=(8, 24, 16, 32, 8, 24, 16, 32)),
+               new_tokens=(8, 24, 16, 32, 8, 24, 16, 32),
+               # a second small model: grouped KV heads, an indexer and
+               # routed experts, prompts past its top-k and past one chunk
+               sparse=dict(
+                   lm=dict(vocab_size=1024, d_model=512, n_heads=8,
+                           n_kv_heads=2, head_dim=128, n_layers=2, d_ff=256,
+                           moe_experts=8, moe_top_k=2,
+                           moe_capacity_factor=None, qk_norm=True,
+                           index_heads=4, index_dim=64, index_top_k=128,
+                           max_len=1024, dtype="bfloat16"),
+                   slots=4, max_context=1024, max_prompt=768,
+                   prefill_chunk=256, prompts=(300, 700),
+                   new_tokens=(16, 16))),
     flash_shape=(8, 8, 2048, 128),
     optim_leaf=(32000, 1024),
     four_conv_batch=1024, four_conv_iters=3,
@@ -304,12 +316,71 @@ def stage_serve(ctx):
         _check(st["attn_route"] == "pallas",
                "decode attention gathers the window on the chip: "
                + eng.kv.attention_path()[1])
-    return dict(compile_s=warm_s, run_s=run_s, attn_route=st["attn_route"],
+    sparse = _serve_sparse(ctx, s["sparse"])
+    return dict(compile_s=warm_s + sparse.pop("compile_s"),
+                run_s=run_s + sparse.pop("run_s"),
+                attn_route=st["attn_route"],
                 kv_pages_read_share=st["kv_pages_read_share"],
                 warmup_compiles=int(st["warmup_compiles"]),
                 max_prompt=s["max_prompt"], requests=len(streams),
                 tokens=sum(len(t) for t in streamed),
-                decode_steps=int(st["steps"]))
+                decode_steps=int(st["steps"]), sparse=sparse)
+
+
+def _serve_sparse(ctx, s):
+    """A small model with grouped KV heads, an indexer and routed experts
+    through the same engine: prompts past one chunk and past its top-k,
+    so the chunk program, the selection and the experts all run."""
+    from bigdl_tpu.models.transformer import TransformerConfig, TransformerLM
+    from bigdl_tpu.serving import DecodeEngine, ModelRegistry
+    model = TransformerLM(TransformerConfig(dropout=0.0, **s["lm"]))
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(s["lm"]["dtype"]),
+        jax.jit(model.init)(jax.random.PRNGKey(5)))
+    model.set_params(params, {})
+    reg = ModelRegistry()
+    reg.register("lm", model)
+    eng = DecodeEngine(reg, "lm", slots=s["slots"],
+                       max_context=s["max_context"],
+                       max_prompt=s["max_prompt"],
+                       prefill_chunk=s["prefill_chunk"],
+                       max_new_tokens=max(s["new_tokens"]))
+    try:
+        t0 = time.perf_counter()
+        eng.warmup()
+        warm_s = time.perf_counter() - t0
+        rng = np.random.RandomState(3)
+        t0 = time.perf_counter()
+        streams = [eng.stream("lm", rng.randint(0, model.cfg.vocab_size, n),
+                              max_new_tokens=m)
+                   for n, m in zip(s["prompts"], s["new_tokens"])]
+        results = [st.result(timeout=600) for st in streams]
+        run_s = time.perf_counter() - t0
+    finally:
+        eng.shutdown(drain=False, timeout=60)
+    st, rec = eng.stats(), eng.recorder
+    _check(all(len(r) == n + m for r, n, m in
+               zip(results, s["prompts"], s["new_tokens"])),
+           "a sparse request came back short")
+    _check(st["errors"] == 0 and st["recompiles"] == 0
+           and rec.counter_value("decode/nonfinite") == 0,
+           f"sparse serve: errors {st['errors']}, recompiles "
+           f"{st['recompiles']}")
+    chunks = sum(-(-n // s["prefill_chunk"]) for n in s["prompts"])
+    _check(st["prefill_chunks"] == chunks,
+           f"{st['prefill_chunks']} prefill chunks, expected {chunks}")
+    _check(st["attn_route"] == "sparse"
+           and rec.gauge_value("decode/attn_route") == 2.0,
+           "decode attention did not take the sparse route: "
+           + eng.kv.attention_path()[1])
+    _check(0 < st["kv_rows_attended_share"] < 1,
+           f"rows attended share {st['kv_rows_attended_share']}: the "
+           "prompts lie past top-k, so less than every row is attended")
+    _check(rec.counter_value("moe/experts_touched") > 0,
+           "the routed experts counted nothing")
+    return dict(compile_s=warm_s, run_s=run_s, attn_route=st["attn_route"],
+                prefill_chunks=int(st["prefill_chunks"]),
+                kv_rows_attended_share=st["kv_rows_attended_share"])
 
 
 def stage_kernels(ctx):

@@ -113,7 +113,17 @@ def test_chip_smoke_stages_tiny_on_cpu():
                 d_ff=256, max_len=256, dtype="bfloat16"),
         lm_batch=4, lm_seq=128, lm_steps=3,
         serve=dict(slots=4, max_context=128, max_prompt=16,
-                   prompts=(3, 9, 16), new_tokens=(4, 8, 6)),
+                   prompts=(3, 9, 16), new_tokens=(4, 8, 6),
+                   sparse=dict(
+                       lm=dict(vocab_size=128, d_model=64, n_heads=4,
+                               n_kv_heads=2, head_dim=16, n_layers=2,
+                               d_ff=32, moe_experts=8, moe_top_k=2,
+                               moe_capacity_factor=None, qk_norm=True,
+                               index_heads=2, index_dim=8, index_top_k=8,
+                               max_len=128, dtype="bfloat16"),
+                       slots=2, max_context=64, max_prompt=40,
+                       prefill_chunk=16, prompts=(20, 40),
+                       new_tokens=(4, 4))),
         flash_shape=(1, 2, 256, 128), optim_leaf=(300, 130),
         four_conv_batch=8, four_conv_iters=3)
     old = fa._INTERPRET
@@ -127,3 +137,4 @@ def test_chip_smoke_stages_tiny_on_cpu():
         sys.path.remove(_REPO)
     assert "skipped" not in results["four_chips"]
     assert results["serve"]["warmup_compiles"] == 6
+    assert results["serve"]["sparse"]["attn_route"] == "sparse"
