@@ -6,11 +6,19 @@ this kernel is the core primitive of our long-context flagship
 
 Design:
   * forward — Pallas kernel on TPU: grid over (batch*heads, q blocks),
-    online-softmax ``fori_loop`` over key blocks held in VMEM; scores and
-    accumulators in fp32 on the MXU, inputs may be bf16.
+    online-softmax ``fori_loop`` over key blocks held in VMEM.  Every
+    matmul feeds the MXU its operands in the dtype they come in (bf16
+    from a bf16 model, fp32 from fp32 callers) and accumulates in fp32;
+    scores, the running max and normaliser, lse and the accumulators are
+    fp32, and p is cast to the operands' dtype only as it enters p @ v.
   * backward — two Pallas kernels (dk/dv over q blocks, dq over kv
     blocks) recomputing p from the saved (q, k, v, out, lse) residuals:
-    flash-style O(seq * block) memory, no materialised (seq, seq) matrix.
+    flash-style O(seq * block) memory, no materialised (seq, seq) matrix;
+    the same rule for operands, with p and ds cast as they enter a matmul.
+  * no operand is transposed in a loop (``dot_general`` contracts on the
+    dimension that is there), the causal mask is built only for the
+    blocks the diagonal crosses, and :func:`flash_blocks` picks the
+    blocks from the shape.
   * blockwise route — the same math as a ``lax.scan`` over key blocks,
     forward and backward; what runs on CPU and for shapes the kernel
     does not tile.  :func:`attention_path` is the ONE place that decides
@@ -52,6 +60,13 @@ _INTERPRET = False
 # at seq 15360 in bf16 and 7680 in f32, and the forward — the tightest
 # of the three — stops compiling at 15872 / 8064.
 _VMEM_RESIDENT_BYTES = 15 * 2 ** 20
+# The scoped limit itself, and the blocks :func:`flash_blocks` chooses
+# from under it.  On a v5e at seq 2048, head_dim 128 (PERF.md, PR 29):
+# 128 x 128 blocks spend the time on the loop, not the MXU (forward
+# 3.5 ms a call, 1.04 at 512 x 512); 1024 gains nothing more and wastes
+# more of the blocks the causal diagonal crosses.
+_VMEM_LIMIT_BYTES = 16 * 2 ** 20
+_BLOCKS = (128, 256, 512)
 
 
 class _Config(NamedTuple):
@@ -201,19 +216,50 @@ def _fwd_blockwise(q, k, v, cfg: _Config):
 # --------------------------------------------------------------------- #
 # Pallas kernels                                                        #
 # --------------------------------------------------------------------- #
-def _block_causal_mask(qi, j, block_q, block_k):
-    """(block_q, block_k) bool mask for q block `qi` vs kv block `j`."""
+# dot_general dimension numbers for a @ b.T: the contraction runs over the
+# dimension that is there, so no operand is transposed in a loop
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """MXU matmul of operands in their own dtype, accumulated in fp32."""
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _block_causal_mask(qi, j, block_q, block_k, transposed=False):
+    """(block_q, block_k) bool mask for q block `qi` vs kv block `j`;
+    (block_k, block_q), keys down the rows, when ``transposed``."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
     qp = qi * block_q + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, shape, 1 if transposed else 0)
     kp = j * block_k + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, shape, 0 if transposed else 1)
     return qp >= kp
 
 
-def _causal_hi(qi, block_q, block_k, n_kb):
-    """First kv-block index past the causal horizon of q block `qi`."""
-    hi = lax.div(qi * block_q + block_q - 1, block_k) + 1
-    return jnp.minimum(hi, n_kb)
+def _kv_loop(body, qi, init, *, causal, block_q, block_k, n_kb):
+    """``body(masked, j, carry)`` over the kv blocks q block `qi` sees:
+    first those wholly at or under its first row, which need no mask,
+    then those the diagonal crosses; blocks past it are skipped."""
+    if not causal:
+        return lax.fori_loop(0, n_kb, functools.partial(body, False), init)
+    full = jnp.minimum(lax.div(qi * block_q + 1, block_k), n_kb)
+    hi = jnp.minimum(lax.div(qi * block_q + block_q - 1, block_k) + 1, n_kb)
+    carry = lax.fori_loop(0, full, functools.partial(body, False), init)
+    return lax.fori_loop(full, hi, functools.partial(body, True), carry)
+
+
+def _q_loop(body, ki, init, *, causal, block_q, block_k, n_qb):
+    """``body(masked, i, carry)`` over the q blocks that see kv block
+    `ki`: those the diagonal crosses, then those wholly at or past its
+    last key, which need no mask; blocks before it are skipped."""
+    if not causal:
+        return lax.fori_loop(0, n_qb, functools.partial(body, False), init)
+    lo = lax.div(ki * block_k, block_q)
+    full = jnp.minimum(
+        lax.div(ki * block_k + block_k - 1 + block_q - 1, block_q), n_qb)
+    carry = lax.fori_loop(lo, full, functools.partial(body, True), init)
+    return lax.fori_loop(full, n_qb, functools.partial(body, False), carry)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
@@ -223,31 +269,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     # every ref block shape legal on hardware (interpret mode never checks
     # this — the r2 kernel only failed when first run on a real TPU).
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * sm_scale          # (bq, d)
+    q = q_ref[0]                                          # (bq, d)
     d = q.shape[-1]
-    n_kb = seq_k // block_k
-    hi = _causal_hi(qi, block_q, block_k, n_kb) if causal else n_kb
 
-    def body(j, carry):
+    def body(masked, j, carry):
         acc, m, l = carry
-        kb = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)  # (bq, bk)
-        if causal:
+        start = pl.multiple_of(j * block_k, block_k)
+        kb = k_ref[0, pl.ds(start, block_k), :]
+        vb = v_ref[0, pl.ds(start, block_k), :]
+        # the fp32 scores are scaled, not q: 1/sqrt(d) is no power of two
+        s = _dot(q, kb, _NT) * sm_scale                   # (bq, bk) fp32
+        if masked:
             s = jnp.where(_block_causal_mask(qi, j, block_q, block_k),
                           s, DEFAULT_MASK_VALUE)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))     # (bq, 1)
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = corr * l + p.sum(axis=-1, keepdims=True)
-        acc_new = corr * acc + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32)
+        acc_new = corr * acc + _dot(p.astype(vb.dtype), vb)
         return acc_new, m_new, l_new
 
     init = (jnp.zeros((block_q, d), jnp.float32),
             jnp.full((block_q, 1), DEFAULT_MASK_VALUE, jnp.float32),
             jnp.zeros((block_q, 1), jnp.float32))
-    acc, m, l = lax.fori_loop(0, hi, body, init)
+    acc, m, l = _kv_loop(body, qi, init, causal=causal, block_q=block_q,
+                         block_k=block_k, n_kb=seq_k // block_k)
     safe_l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
     lse_ref[0] = (m + jnp.log(safe_l)).astype(lse_ref.dtype)
@@ -295,37 +341,37 @@ def _bwd_kernel_dkv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     """One (batch*head, kv-block) program: accumulate dk/dv over q blocks.
 
     Flash-attention backward recomputes p = exp(s - lse) per block from the
-    saved lse — no (seq, seq) matrix is ever materialised.
+    saved lse — no (seq, seq) matrix is ever materialised.  The scores are
+    computed transposed, keys down the rows, so that dv = p^T dO and
+    dk = ds^T q are plain matmuls; lse and delta come as (1, seq) rows.
     """
     ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                      # (bk, d)
-    v = v_ref[0].astype(jnp.float32)
-    n_qb = seq_q // block_q
-    # under causality, q blocks strictly before this kv block see none of it
-    lo = lax.div(ki * block_k, block_q) if causal else 0
+    k = k_ref[0]                                          # (bk, d)
+    v = v_ref[0]
 
-    def body(i, carry):
+    def body(masked, i, carry):
         dk, dv = carry
-        qb = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        dob = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lseb = lse_ref[0, pl.ds(i * block_q, block_q), :]      # (bq, 1)
-        deltab = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        s = jnp.dot(qb, k.T, preferred_element_type=jnp.float32) * sm_scale
-        p = jnp.exp(s - lseb)                             # (bq, bk)
-        if causal:
-            p = jnp.where(_block_causal_mask(i, ki, block_q, block_k),
-                          p, 0.0)
-        dv = dv + jnp.dot(p.T, dob, preferred_element_type=jnp.float32)
-        dp = jnp.dot(dob, v.T, preferred_element_type=jnp.float32)
-        ds_ = p * (dp - deltab) * sm_scale
-        dk = dk + jnp.dot(ds_.T, qb, preferred_element_type=jnp.float32)
+        start = pl.multiple_of(i * block_q, block_q)
+        qb = q_ref[0, pl.ds(start, block_q), :]
+        dob = do_ref[0, pl.ds(start, block_q), :]
+        lseb = lse_ref[0, :, pl.ds(start, block_q)]           # (1, bq)
+        deltab = delta_ref[0, :, pl.ds(start, block_q)]
+        p = jnp.exp(_dot(k, qb, _NT) * sm_scale - lseb)       # (bk, bq)
+        if masked:
+            p = jnp.where(_block_causal_mask(i, ki, block_q, block_k,
+                                             transposed=True), p, 0.0)
+        dv = dv + _dot(p.astype(dob.dtype), dob)
+        ds_ = p * (_dot(v, dob, _NT) - deltab)
+        dk = dk + _dot(ds_.astype(qb.dtype), qb)
         return dk, dv
 
     d = k.shape[-1]
     init = (jnp.zeros((block_k, d), jnp.float32),
             jnp.zeros((block_k, d), jnp.float32))
-    dk, dv = lax.fori_loop(lo, n_qb, body, init)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dk, dv = _q_loop(body, ki, init, causal=causal, block_q=block_q,
+                     block_k=block_k, n_qb=seq_q // block_q)
+    # ds = p * (dp - delta) * sm_scale: the scale goes on once, here
+    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -333,28 +379,27 @@ def _bwd_kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, *, sm_scale, causal, block_q, block_k, seq_k):
     """One (batch*head, q-block) program: accumulate dq over kv blocks."""
     qi = pl.program_id(1)
-    qb = q_ref[0].astype(jnp.float32)                     # (bq, d)
-    dob = do_ref[0].astype(jnp.float32)
+    qb = q_ref[0]                                         # (bq, d)
+    dob = do_ref[0]
     lseb = lse_ref[0]                                     # (bq, 1)
     deltab = delta_ref[0]
-    n_kb = seq_k // block_k
-    hi = _causal_hi(qi, block_q, block_k, n_kb) if causal else n_kb
 
-    def body(j, dq):
-        kb = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * sm_scale
-        p = jnp.exp(s - lseb)
-        if causal:
+    def body(masked, j, dq):
+        start = pl.multiple_of(j * block_k, block_k)
+        kb = k_ref[0, pl.ds(start, block_k), :]
+        vb = v_ref[0, pl.ds(start, block_k), :]
+        p = jnp.exp(_dot(qb, kb, _NT) * sm_scale - lseb)
+        if masked:
             p = jnp.where(_block_causal_mask(qi, j, block_q, block_k),
                           p, 0.0)
-        dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-        ds_ = p * (dp - deltab) * sm_scale
-        return dq + jnp.dot(ds_, kb, preferred_element_type=jnp.float32)
+        ds_ = p * (_dot(dob, vb, _NT) - deltab)
+        return dq + _dot(ds_.astype(kb.dtype), kb)
 
     d = qb.shape[-1]
-    dq = lax.fori_loop(0, hi, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq = _kv_loop(body, qi, jnp.zeros((block_q, d), jnp.float32),
+                  causal=causal, block_q=block_q, block_k=block_k,
+                  n_kb=seq_k // block_k)
+    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _bwd_pallas(q, k, v, out, lse, do, cfg: _Config):
@@ -365,10 +410,11 @@ def _bwd_pallas(q, k, v, out, lse, do, cfg: _Config):
     kf = k.reshape(b * h, sk, d)
     vf = v.reshape(b * h, sk, d)
     dof = do.reshape(b * h, sq, d)
-    lsef = lse.reshape(b * h, sq, 1)
     # delta_i = sum_d do_i * out_i; tiny elementwise reduce, leave it to XLA
-    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)
-             ).sum(-1).reshape(b * h, sq, 1)
+    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    # per-query columns for the dq kernel, lane-major rows for dk/dv
+    lse_col, delta_col = (x.reshape(b * h, sq, 1) for x in (lse, delta))
+    lse_row, delta_row = (x.reshape(b * h, 1, sq) for x in (lse, delta))
 
     kv_kernel = functools.partial(
         _bwd_kernel_dkv, sm_scale=cfg.sm_scale, causal=cfg.causal,
@@ -381,8 +427,8 @@ def _bwd_pallas(q, k, v, out, lse, do, cfg: _Config):
             pl.BlockSpec((1, bk, d), lambda bh, j: (bh, j, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, j: (bh, j, 0)),
             pl.BlockSpec((1, sq, d), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, sq, 1), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, sq, 1), lambda bh, j: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, sq), lambda bh, j: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, sq), lambda bh, j: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda bh, j: (bh, j, 0)),
@@ -393,7 +439,7 @@ def _bwd_pallas(q, k, v, out, lse, do, cfg: _Config):
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
         ],
         interpret=_INTERPRET,
-    )(qf, kf, vf, dof, lsef, delta)
+    )(qf, kf, vf, dof, lse_row, delta_row)
 
     q_kernel = functools.partial(
         _bwd_kernel_dq, sm_scale=cfg.sm_scale, causal=cfg.causal,
@@ -412,19 +458,53 @@ def _bwd_pallas(q, k, v, out, lse, do, cfg: _Config):
         out_specs=[pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((b * h, sq, d), q.dtype)],
         interpret=_INTERPRET,
-    )(qf, kf, vf, dof, lsef, delta)[0]
+    )(qf, kf, vf, dof, lse_col, delta_col)[0]
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
 
-def attention_path(q_shape, k_shape, dtype, *, block_q: int = 128,
-                   block_k: int = 128, use_pallas: bool = True,
+def _resident_bytes(seq_q, seq_k, head_dim, dtype) -> int:
+    """The resident pair (K, V in fwd/dq; Q, dO in dkv), double-buffered."""
+    return 4 * head_dim * jnp.dtype(dtype).itemsize * max(seq_q, seq_k)
+
+
+def _vmem_bytes(block_q, block_k, seq_q, seq_k, head_dim, dtype) -> int:
+    """What the forward, the tightest of the three kernels, is reckoned
+    to hold in VMEM: the resident pair, three fp32 score tiles (scores,
+    probabilities, one temporary), the q and o blocks double-buffered,
+    and the fp32 accumulator.  Under _VMEM_LIMIT_BYTES this agrees with
+    what Mosaic compiles for a v5e at every sequence tried (a multiple
+    of 512 up to 15360 in bf16 and 7680 in f32;
+    tests/test_paged_attention.py keeps four of them)."""
+    return (_resident_bytes(seq_q, seq_k, head_dim, dtype)
+            + 3 * 4 * block_q * block_k
+            + 4 * block_q * head_dim * jnp.dtype(dtype).itemsize
+            + 4 * block_q * head_dim)
+
+
+def flash_blocks(seq_q, seq_k, head_dim, dtype) -> Tuple[int, int]:
+    """``(block_q, block_k)`` the kernels take where the caller names
+    none: the largest of _BLOCKS that divide the sequences and whose
+    :func:`_vmem_bytes` fit the scoped limit; one lane tile, (128, 128),
+    where nothing larger does."""
+    fits = [(bq, bk) for bq in _BLOCKS for bk in _BLOCKS
+            if seq_q % bq == 0 and seq_k % bk == 0
+            and _vmem_bytes(bq, bk, seq_q, seq_k, head_dim, dtype)
+            <= _VMEM_LIMIT_BYTES]
+    return max(fits, key=lambda b: (b[0] * b[1], b[1]), default=(128, 128))
+
+
+def attention_path(q_shape, k_shape, dtype, *,
+                   block_q: Optional[int] = None,
+                   block_k: Optional[int] = None, use_pallas: bool = True,
                    backend: Optional[str] = None) -> Tuple[str, str]:
     """Which route ``flash_attention`` takes for these (B, H, S, D)
     shapes, and why: ``("pallas", reason)`` or ``("blockwise", reason)``.
     Forward and backward always take the same route.  ``backend``
-    defaults to ``jax.default_backend()``."""
+    defaults to ``jax.default_backend()``; blocks left ``None`` are
+    :func:`flash_blocks`'s, and the reason names them and the dtype the
+    MXU is fed."""
     if not use_pallas:
         return "blockwise", "use_pallas=False"
     if backend is None:
@@ -436,18 +516,22 @@ def attention_path(q_shape, k_shape, dtype, *, block_q: int = 128,
     if d % 128:
         return "blockwise", (f"head_dim {d} is not a multiple of 128 "
                              "(one lane tile)")
+    auto = flash_blocks(sq, sk, d, dtype)
+    block_q = auto[0] if block_q is None else block_q
+    block_k = auto[1] if block_k is None else block_k
     if sq % block_q or sk % block_k:
         return "blockwise", (f"seq_q {sq} / seq_k {sk} not multiples of "
                              f"block_q {block_q} / block_k {block_k}")
-    # the resident pair (K, V in fwd/dq; Q, dO in dkv), double-buffered
-    need = 4 * d * jnp.dtype(dtype).itemsize * max(sq, sk)
+    need = _resident_bytes(sq, sk, d, dtype)
     if need > _VMEM_RESIDENT_BYTES:
         return "blockwise", (
             f"seq {max(sq, sk)} keeps {need / 2 ** 20:.1f} MiB resident "
             f"in VMEM, over the {_VMEM_RESIDENT_BYTES >> 20} MiB the "
             "kernel compiles under")
-    return "pallas", ("interpret mode" if backend != "tpu"
-                      else "tpu backend, shape tiles")
+    return "pallas", (
+        ("interpret mode" if backend != "tpu" else "tpu backend")
+        + f", blocks {block_q} x {block_k}, {jnp.dtype(dtype).name} "
+        "operands, float32 accumulation")
 
 
 def _pallas_ok(q, k, cfg: _Config) -> bool:
@@ -525,16 +609,27 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal: bool = False,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     use_pallas: bool = True):
     """Fused attention. q, k, v: (batch, heads, seq, head_dim).
 
     Pallas kernels on TPU, forward and backward; the blockwise lax.scan
     elsewhere and for shapes the kernel does not take —
-    :func:`attention_path` says which and why.
+    :func:`attention_path` says which and why.  The kernels feed the MXU
+    q, k, v and dO in the dtype they come in and accumulate in fp32.
+    Blocks left ``None`` are :func:`flash_blocks`'s for the kernels and
+    128 for the scan.
     """
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
+    if block_q is None or block_k is None:
+        kernels = attention_path(q.shape, k.shape, q.dtype, block_q=block_q,
+                                 block_k=block_k, use_pallas=use_pallas)[0]
+        auto = (flash_blocks(q.shape[2], k.shape[2], q.shape[3], q.dtype)
+                if kernels == "pallas" else (128, 128))
+        block_q = auto[0] if block_q is None else block_q
+        block_k = auto[1] if block_k is None else block_k
     cfg = _Config(bool(causal), float(sm_scale), int(block_q), int(block_k),
                   bool(use_pallas))
     return _flash(cfg, q, k, v)

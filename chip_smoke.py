@@ -77,9 +77,10 @@ SIZES = dict(
     four_conv_batch=1024, four_conv_iters=3,
 )
 
-# Tolerances, as measured on a TPU v5e (NOTES.md "Bring-up on the chip"):
+# Tolerances, as measured on a TPU v5e (NOTES.md "Bring-up on the chip";
+# the flash kernels' again in PR 29, bf16 operands and 512 x 512 blocks):
 FLASH_FWD_TOL = 1e-2     # bf16 vs f32 reference: measured 3.7e-3
-FLASH_BWD_TOL = 2e-2     # measured 6.0e-3 (dv), 5.2e-3 (dq), 4.5e-3 (dk)
+FLASH_BWD_TOL = 2e-2     # measured 5.2e-3 (dq), 4.5e-3 (dk), 3.0e-3 (dv)
 FUSED_ULPS = 4           # measured 0: bitwise on Adam, AdamW, SGD-momentum
 FOUR_CHIP_LOSS_TOL = 5e-2   # first-step loss (~10.9), sharded vs one chip:
                             # bf16 sums in another order; measured <= 2e-3
@@ -247,7 +248,7 @@ def stage_transformer(ctx):
     # a second jit signature — NOTES.md lists it as a gap, not fixed here)
     out = dict(compile_s=hlo_s + secs[0], run_s=sum(secs[1:]),
                step_s=[round(s, 2) for s in secs],
-               losses=[round(l, 4) for l in losses])
+               losses=[round(l, 4) for l in losses], attention_route=why)
     if ctx.native:
         calls = _mosaic_calls(hlo)
         # forward, dk/dv and dq kernels, once per layer
@@ -408,6 +409,7 @@ def stage_kernels(ctx):
                for _ in range(3))
     path, why = fa.attention_path(q.shape, k.shape, q.dtype)
     _check(path == "pallas", f"attention takes {path}: {why}")
+    out["flash_route"] = why      # the blocks and operand dtype chosen
 
     def total(attn):
         return lambda q, k, v: jnp.sum(

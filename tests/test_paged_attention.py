@@ -222,3 +222,29 @@ def test_kernel_compiles_for_v5e_without_a_pool_copy(one_chip, dtype,
         sds((32, 32), "int32"), sds((32,), "int32")).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# The flash kernels under the chip's compiler.  Here and not in
+# test_flash_pallas.py: one process holds the TPU's library, so every
+# compile for a described chip lives in the one file that has the fixture.
+@pytest.mark.parametrize("seq,dtype", [(2048, "bfloat16"),
+                                       (8192, "bfloat16"),
+                                       (15360, "bfloat16"),
+                                       (7680, "float32")])
+def test_flash_kernels_compile_for_v5e_at_the_blocks_chosen(one_chip, seq,
+                                                            dtype):
+    """``flash_blocks`` reckons VMEM from shapes; Mosaic has the last
+    word.  The LM cells' sequence, a middle one, and the longest the
+    kernels take in bf16 and in f32: forward, dk/dv and dq all compile."""
+    from bigdl_tpu.ops import flash_attention_mod as fa
+    x = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.dtype(dtype),
+                             sharding=one_chip)
+    bq, bk = fa.flash_blocks(seq, seq, 128, dtype)
+    cfg = fa._Config(True, 128 ** -0.5, bq, bk, True)
+    lse = jax.ShapeDtypeStruct((1, 2, seq), jnp.float32, sharding=one_chip)
+    fwd = jax.jit(lambda q, k, v: fa._fwd_pallas(q, k, v, cfg)
+                  ).lower(x, x, x).compile()
+    bwd = jax.jit(lambda q, k, v, o, l, do: fa._bwd_pallas(
+        q, k, v, o, l, do, cfg)).lower(x, x, x, x, lse, x).compile()
+    assert fwd.as_text().count("tpu_custom_call") == 1
+    assert bwd.as_text().count("tpu_custom_call") == 2
