@@ -118,21 +118,24 @@ def capture_compiled(compiled) -> Dict[str, Any]:
     return out
 
 
-def aot_capture(jitted, *args) -> Dict[str, Any]:
+def aot_capture(jitted, *args, avals: bool = True) -> Dict[str, Any]:
     """Lower ``jitted`` at ``args``' avals and capture its compiled
     cost.  Lowering uses ``ShapeDtypeStruct``s so no real buffer is
     read or donated; XLA's compile cache serves the executable when the
-    same signature was (or will be) dispatched.  Raises on backends
-    without the AOT API — callers that must not fail go through
-    :func:`capture_and_attach`."""
+    same signature was (or will be) dispatched.  ``avals=False`` lowers
+    with the arrays themselves, whose shardings abstract avals would
+    drop (lowering never reads or donates them either).  Raises on
+    backends without the AOT API — callers that must not fail go
+    through :func:`capture_and_attach`."""
     import jax
 
     def aval(leaf):
         if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
             return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
         return leaf
-    sds = jax.tree_util.tree_map(aval, args)
-    return capture_compiled(jitted.lower(*sds).compile())
+    if avals:
+        args = jax.tree_util.tree_map(aval, args)
+    return capture_compiled(jitted.lower(*args).compile())
 
 
 class StepCostModel:
@@ -207,13 +210,13 @@ def attach_cost(recorder, cost: Dict[str, Any],
 
 
 def capture_and_attach(recorder, jitted, args, kind: str = "train_step",
-                       **fields) -> StepCostModel:
-    """Capture ``jitted``'s compiled cost at ``args``' avals and attach
-    it (:func:`attach_cost`).  NEVER raises — a backend without the
-    analysis APIs yields a record whose cost says so."""
+                       avals: bool = True, **fields) -> StepCostModel:
+    """Capture ``jitted``'s compiled cost at ``args`` (:func:`aot_capture`)
+    and attach it (:func:`attach_cost`).  NEVER raises — a backend
+    without the analysis APIs yields a record whose cost says so."""
     try:
         with recorder.span("profile.capture"):
-            cost = aot_capture(jitted, *args)
+            cost = aot_capture(jitted, *args, avals=avals)
     except Exception as e:      # AOT API missing / lowering failed
         cost = {"unavailable": ["capture_failed"], "error": repr(e)}
     return attach_cost(recorder, cost, kind=kind, **fields)

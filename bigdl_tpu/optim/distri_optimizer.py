@@ -204,8 +204,9 @@ class DistriOptimizer(Optimizer):
             specs_in = (P(), P(), P(), P("dp"), P("dp"), P())
             specs_out = (P(), P(), P(), P()) + ((P(),) if telemetry else ())
             return jax.jit(
-                jax.shard_map(step, mesh=self.mesh, in_specs=specs_in,
-                              out_specs=specs_out, check_vma=False),
+                jax.shard_map(self._accounted(step), mesh=self.mesh,
+                              in_specs=specs_in, out_specs=specs_out,
+                              check_vma=False),
                 donate_argnums=(0, 1, 2)), None
 
         # ---- FSDP: params sharded on dim 0 where divisible -------------- #
@@ -237,8 +238,9 @@ class DistriOptimizer(Optimizer):
         specs_out = (p_specs, o_specs, P(), P()) \
             + ((P(),) if telemetry else ())
         return jax.jit(
-            jax.shard_map(step, mesh=self.mesh, in_specs=specs_in,
-                          out_specs=specs_out, check_vma=False),
+            jax.shard_map(self._accounted(step), mesh=self.mesh,
+                          in_specs=specs_in, out_specs=specs_out,
+                          check_vma=False),
             donate_argnums=(0, 1, 2)), shardable
 
     # ---- ZeRO-1: replicated params, sharded update + optimizer state -- #
@@ -286,8 +288,9 @@ class DistriOptimizer(Optimizer):
         specs_out = (P(), o_specs, P(), P()) \
             + ((P(),) if telemetry else ())
         return jax.jit(
-            jax.shard_map(step, mesh=self.mesh, in_specs=specs_in,
-                          out_specs=specs_out, check_vma=False),
+            jax.shard_map(self._accounted(step), mesh=self.mesh,
+                          in_specs=specs_in, out_specs=specs_out,
+                          check_vma=False),
             donate_argnums=(0, 1, 2)), None
 
     def _shard_params_host(self, params, shardable):
@@ -342,16 +345,9 @@ class DistriOptimizer(Optimizer):
 
     def _make_step_builder(self, params_template, optim):
         def build_step():
-            telemetry = self._telemetry_active()
-            self._with_health = telemetry
-            self._seen_sigs.clear()
-            self._rec().reset_gauges("collective/")
-            self._rec().reset_gauges("comm/group.")
-            step_fn, shardable = self._build_step(params_template, optim,
-                                                  telemetry=telemetry)
-            self._shardable = shardable
-            self._cost_pending = True   # new program: re-capture cost
-            return step_fn
+            return self._build_step(
+                params_template, optim,
+                telemetry=self._begin_step_build())[0]
         return build_step
 
     def _layout_params(self, params):

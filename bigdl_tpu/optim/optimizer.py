@@ -31,8 +31,8 @@ from jax import lax
 from ..nn.module import Ctx, Module, migrate_legacy_names
 from ..data.dataset import DataSet
 from ..data.minibatch import MiniBatch
-from ..observability import (DivergenceError, Recorder, null_recorder,
-                             set_recorder)
+from ..observability import DivergenceError, Recorder
+from ..observability.host import TelemetryHost
 from .optim_method import OptimMethod, SGD
 from .trigger import Trigger
 from .validation import ValidationMethod
@@ -345,9 +345,11 @@ def make_eval_step(model: Module, device_augment=None):
     return step
 
 
-class Optimizer:
+class Optimizer(TelemetryHost):
     """Base training driver; factory returns Local or Distri optimizer
-    (≙ optim/Optimizer.scala apply)."""
+    (≙ optim/Optimizer.scala apply); ``set_telemetry`` / ``set_health``
+    / ``serve_metrics`` come from :class:`TelemetryHost` (≙
+    optim/Metrics.scala, grown into a first-class subsystem)."""
 
     def __init__(self, model: Module, training_set, criterion,
                  batch_size: Optional[int] = None, seed: int = 0):
@@ -394,22 +396,7 @@ class Optimizer:
         # (x, rng, training) -> x callable)
         self._device_augment = None
         self._retry_cache = None
-        # telemetry (observability.Recorder); None = zero-cost no-op path
-        self._recorder: Optional[Recorder] = None
-        self._trace_ctx = None          # causal TraceContext, if adopted
-        self._telemetry_health = True
-        self._with_health = False     # does the built step return health?
-        self._seen_sigs = set()       # (shape, dtype) sigs → recompile detect
-        # static cost capture (observability.profile): harvest XLA
-        # cost/memory analysis once per step build, at first dispatch
-        self._capture_cost = True
-        self._cost_pending = False
-        # training-health layer (observability.health)
-        self._health_monitor = None
-        self._flight = None
-        self._watchdog = None
-        self._http_server = None
-        self._max_rollbacks = 2
+        TelemetryHost.__init__(self)
 
     # -- fluent config, reference API ----------------------------------- #
     def set_optim_method(self, method):
@@ -516,55 +503,6 @@ class Optimizer:
         self._eval_step = None
         return self
 
-    def set_telemetry(self, recorder: Recorder, health: bool = True,
-                      capture_cost: bool = True):
-        """Attach an observability Recorder: every iteration emits one
-        step record (spans: data_fetch / h2d / train_step, compile
-        detection; scalars: loss, learning rate, records/sec — plus
-        grad/param/update norms when ``health``, computed on device
-        inside the step).  Also installs ``recorder`` as the
-        process-active recorder so DeviceLoader and collective
-        accounting report to it (≙ optim/Metrics.scala, grown into a
-        first-class subsystem).
-
-        ``capture_cost`` harvests XLA's compile-time cost/memory
-        analysis from the jitted step (once per step build, via an AOT
-        lowering at the first batch's avals) so every step record
-        additionally carries ``perf/mfu``, ``perf/hbm_bw_util`` and
-        ``mem/peak_hbm_bytes`` — or explicit ``*_unavailable`` markers
-        on backends without the analysis APIs.  Live ``mem/device.*``
-        gauges are refreshed from ``jax.local_devices()``
-        ``memory_stats()`` on every record/scrape.  Both opt-outs —
-        ``capture_cost=False`` and the ``BIGDL_PROFILE_CAPTURE=0`` env
-        kill switch — disable the capture AND the per-step memory
-        polling, keeping attribution entirely off the hot path."""
-        from ..observability.profile import (capture_enabled,
-                                             install_device_memory_poller)
-        self._recorder = recorder
-        self._telemetry_health = bool(health)
-        self._capture_cost = bool(capture_cost)
-        if self._capture_cost and capture_enabled():
-            install_device_memory_poller(recorder)
-        if recorder.enabled and recorder.get_ledger() is None:
-            # goodput ledger: end_step folds data_fetch/h2d/compile/
-            # checkpoint.blocking spans into badput device-seconds, the
-            # residual step time is goodput (docs/observability.md,
-            # "Goodput & badput taxonomy")
-            from ..observability.goodput import GoodputLedger
-            import jax
-            recorder.set_ledger(GoodputLedger(
-                name="train", devices=jax.local_device_count()))
-        set_recorder(recorder)
-        return self
-
-    def set_trace_context(self, ctx, tracer=None):
-        """Adopt a causal :class:`~bigdl_tpu.observability.context.
-        TraceContext`: checkpoint saves carry a child of it to the
-        async writer thread (queue-wait + write spans under the
-        training run's trace id).  ``ctx=None`` detaches."""
-        self._trace_ctx = ctx
-        return self
-
     def set_trace_every(self, n_steps: int, log_dir: str):
         """Capture a jax.profiler trace of every n-th step into
         ``log_dir`` (TensorBoard profile plugin / Perfetto).  Creates a
@@ -575,87 +513,6 @@ class Optimizer:
         self._recorder.trace_every(n_steps, log_dir)
         return self
 
-    def set_health(self, policy: str = "warn", flight_dir=None,
-                   max_rollbacks: int = 2, stall_factor=None,
-                   install_crash_hooks: bool = True, **monitor_kw):
-        """Enable numeric-health sentinels over every step record:
-        NaN/Inf in loss or gradients, loss-spike (EWMA z-score), and
-        gradient-norm explosion — the device checks ride the step's
-        existing ``health_scalars`` output, so nothing extra syncs the
-        host.  ``policy`` is ``"warn"`` / ``"record"`` / ``"raise"``
-        (:class:`~bigdl_tpu.observability.DivergenceError`) /
-        ``"rollback"`` (restore the last committed checkpoint — needs
-        ``set_checkpoint`` — at most ``max_rollbacks`` times).
-
-        ``flight_dir`` arms the crash flight recorder: the Recorder's
-        recent-record ring is dumped atomically to ``flight_<ts>.json``
-        there on divergence, unhandled exception, or SIGTERM
-        (``install_crash_hooks`` chains excepthook/SIGTERM without
-        displacing the PR-3 preemption handler).  ``stall_factor``
-        additionally starts a :class:`StallWatchdog` with that p99
-        multiplier.  Extra kwargs reach
-        :class:`~bigdl_tpu.observability.HealthMonitor`."""
-        from ..observability.health import (FlightRecorder, HealthMonitor,
-                                           StallWatchdog)
-        if self._recorder is None:
-            self.set_telemetry(Recorder())
-        rec = self._recorder
-        if flight_dir is not None:
-            if self._flight is not None:     # reconfigure: one hook chain
-                self._flight.uninstall()
-            self._flight = FlightRecorder(rec, flight_dir)
-            if install_crash_hooks:
-                self._flight.install()
-        self._health_monitor = HealthMonitor(
-            policy=policy, recorder=rec, flight=self._flight, **monitor_kw)
-        self._max_rollbacks = int(max_rollbacks)
-        if stall_factor:
-            if self._watchdog is not None:   # re-budget: one thread only
-                self._watchdog.stop()
-            self._watchdog = StallWatchdog(rec,
-                                           factor=float(stall_factor)).start()
-        if self._http_server is not None:   # set_health after serve_metrics
-            self._http_server.monitor = self._health_monitor
-            self._http_server.watchdog = self._watchdog \
-                or self._http_server.watchdog
-        return self
-
-    def telemetry_sources(self):
-        """``[("trainer", recorder)]`` — the fleet aggregator's
-        attachment hook (``aggregator.add(opt, name="train")``); a
-        recorder is created on demand like ``serve_metrics`` does."""
-        if self._recorder is None:
-            self.set_telemetry(Recorder())
-        return [("trainer", self._recorder)]
-
-    def serve_metrics(self, port: int = 0, host: str = "127.0.0.1",
-                      watchdog: bool = True):
-        """Start the live introspection HTTP server for this trainer's
-        recorder — ``/metrics`` (Prometheus), ``/healthz``, ``/records``
-        — on a daemon thread.  ``port=0`` binds an ephemeral port (read
-        it back from the returned server's ``.port``).  ``watchdog``
-        starts a stall watchdog so ``/healthz`` flips unhealthy when
-        the step loop wedges.  Returns the
-        :class:`~bigdl_tpu.observability.IntrospectionServer` (call
-        ``.stop()`` to shut it down)."""
-        from ..observability.health import StallWatchdog
-        from ..observability.http import IntrospectionServer
-        if self._recorder is None:
-            self.set_telemetry(Recorder())
-        if watchdog and self._watchdog is None:
-            self._watchdog = StallWatchdog(self._recorder).start()
-        if self._http_server is not None:   # reconfigure: no leaked
-            self._http_server.stop()        # thread/socket on the old port
-        self._http_server = IntrospectionServer(
-            self._recorder, port=port, host=host,
-            watchdog=self._watchdog,
-            monitor=self._health_monitor).start()
-        return self._http_server
-
-    def _rec(self) -> Recorder:
-        return self._recorder if self._recorder is not None \
-            else null_recorder()
-
     def _wd_suspended(self):
         """Suspend the stall watchdog around legitimate between-step
         work (validation, checkpoint commit) — a long pass there is not
@@ -664,27 +521,6 @@ class Optimizer:
             from contextlib import nullcontext
             return nullcontext()
         return self._watchdog.suspended()
-
-    def _telemetry_active(self) -> bool:
-        """Should the step being built compute health scalars?  A
-        disabled recorder must compile the plain step — the no-op
-        guarantee covers device work too."""
-        return (self._recorder is not None and self._recorder.enabled
-                and self._telemetry_health)
-
-    def _capture_step_cost(self, step_fn, args):
-        """Harvest XLA cost/memory analysis for the jitted step at these
-        args' avals (AOT lowering — real buffers untouched) and attach
-        the StepCostModel deriving per-step ``perf/mfu`` /
-        ``perf/hbm_bw_util`` / ``mem/peak_hbm_bytes``.  Best-effort by
-        contract: never raises, never blocks the loop beyond one
-        analysis pass (the ``profile.capture`` span measures it)."""
-        from ..observability import profile as _profile
-        rec = self._rec()
-        if (not self._capture_cost or not rec.enabled
-                or not _profile.capture_enabled()):
-            return
-        _profile.capture_and_attach(rec, step_fn, args, kind="train_step")
 
     def set_auto_retry(self, max_retries):
         """Retry a failed epoch from the last end-of-epoch state snapshot
@@ -875,13 +711,7 @@ class Optimizer:
     def _make_step_builder(self, params_template, optim):
         def build_step():
             n_accum = self._grad_accum
-            telemetry = self._telemetry_active()
-            self._with_health = telemetry
-            self._seen_sigs.clear()   # rebuilt fn: first calls re-compile
-            # rebuilds re-trace: clear the trace-time collective gauges
-            # so per-step volume is not double-counted
-            self._rec().reset_gauges("collective/")
-            self._rec().reset_gauges("comm/group.")
+            telemetry = self._begin_step_build()
             if n_accum > 1:
                 fn = make_accum_train_step(self.model, self.criterion,
                                            optim, n_accum,
@@ -893,10 +723,7 @@ class Optimizer:
                                      self.mixed_precision,
                                      telemetry=telemetry,
                                      device_augment=self._device_augment)
-            # a rebuilt step is a new program: re-capture its cost at
-            # the next first dispatch
-            self._cost_pending = True
-            return jax.jit(fn, donate_argnums=(0, 1, 2))
+            return jax.jit(self._accounted(fn), donate_argnums=(0, 1, 2))
         return build_step
 
     def _layout_params(self, params):
@@ -1127,40 +954,8 @@ class Optimizer:
             rng, sub = jax.random.split(rng)
             t0 = time.time()
             self._loop_rng = rng
-            span_name = "train_step"
-            if rec.enabled:
-                # a signature never dispatched before means XLA compiles
-                # inside this call: label it so trace_summary can split
-                # compile from execute (and count recompiles)
-                sig = tuple(
-                    (tuple(jnp.shape(l)), str(getattr(l, "dtype", "?")))
-                    for l in jax.tree_util.tree_leaves((x, y)))
-                if sig not in self._seen_sigs:
-                    self._seen_sigs.add(sig)
-                    span_name = "train_step_compile"
-                    rec.scalar("recompile", 1.0)
-                    # this call re-traces (e.g. a ragged last batch) and
-                    # the trace-time collective accounting re-runs: reset
-                    # the per-step gauges or volume double-counts forever
-                    # (comm/group.* has accumulate semantics — it would
-                    # inflate, not just go stale)
-                    rec.reset_gauges("collective/")
-                    rec.reset_gauges("comm/group.")
-                    if self._cost_pending:
-                        # once per step build, at the first (full-batch)
-                        # signature — a ragged last batch would
-                        # under-report every following full step
-                        self._cost_pending = False
-                        self._capture_step_cost(
-                            step_fn, (params, opt_state, model_state,
-                                      x, y, sub))
-            with rec.span(span_name):
-                out = step_fn(params, opt_state, model_state, x, y, sub)
-            if self._with_health:
-                params, opt_state, model_state, loss, health = out
-            else:
-                params, opt_state, model_state, loss = out
-                health = None
+            (params, opt_state, model_state, loss), health = self._dispatch(
+                step_fn, (params, opt_state, model_state, x, y, sub), (x, y))
             # keep `loss` on device: float()ing here would sync the host
             # with the accelerator every step and stall the input pipeline
             # (telemetry syncs it in end_step — the price of a loss curve)
@@ -1250,28 +1045,13 @@ class Optimizer:
             # need the floats)
             rec.end_step(self.state.iteration)
             return
-        raw = rec.gauge_value("collective/bytes_per_step")
-        if raw:
-            rec.inc("collective/bytes_total", raw)
-        wire = rec.gauge_value("collective/wire_bytes_per_step")
-        if wire:
-            rec.inc("collective/wire_bytes_total", wire)
-        rec.inc("records_total", size)
-        rec.scalar("records", size)
-        rec.scalar("loss", loss)
+        extra = {}
         try:
-            rec.scalar("learning_rate", float(
-                self.optim_method.get_learning_rate(opt_state)))
+            extra["learning_rate"] = float(
+                self.optim_method.get_learning_rate(opt_state))
         except Exception:
             pass    # custom OptimMethods without a readable lr
-        if health:
-            for k, v in health.items():
-                rec.scalar(k, v)
-        record = rec.end_step(self.state.iteration)
-        if self._health_monitor is not None and record is not None:
-            # sentinel checks over the floats end_step already produced;
-            # raise/rollback policies surface DivergenceError from here
-            self._health_monitor.check_record(record)
+        self._record_step(self.state.iteration, size, loss, health, extra)
 
     def _fire_mid_epoch(self, params, opt_state, model_state) -> bool:
         """iteration-level triggers; returns True if training should end."""

@@ -27,6 +27,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..observability.host import TelemetryHost
 
 
 def pipeline_run(stage_fn: Callable, stage_params, microbatches,
@@ -109,7 +110,7 @@ def pipelined(stage_fn: Callable, mesh: Mesh, n_microbatches: int,
 # --------------------------------------------------------------------- #
 # transformer pipeline trainer                                          #
 # --------------------------------------------------------------------- #
-class PipelineLMTrainer:
+class PipelineLMTrainer(TelemetryHost):
     """GPipe training for TransformerLM over a 'pp' mesh axis (x optional
     'dp', 'tp', 'sp'): each pp rank owns n_layers/n_stages blocks (params
     stacked on a leading layer axis, sharded over pp); microbatches flow
@@ -161,6 +162,9 @@ class PipelineLMTrainer:
                      dp for the replicated rest, dp×pp for the stage
                      shards).
     """
+
+    _items_counter = "tokens_total"
+    _resize_adopted_ledger = True
 
     def __init__(self, model, optim, mesh, n_microbatches=4, seed=0,
                  loss_chunk=None, zero1=False, bucket_bytes=None,
@@ -231,10 +235,7 @@ class PipelineLMTrainer:
         self.opt_state = None
         self._step_fn = None
         self._step_count = 0
-        self._recorder = None
-        self._telemetry_health = True
-        self._with_health = False
-        self._seen_sigs = set()
+        TelemetryHost.__init__(self)
         self._z1_rest = None
         self._z1_blocks = None
 
@@ -348,10 +349,6 @@ class PipelineLMTrainer:
             lambda l, sp: jax.device_put(l, NamedSharding(self.mesh, sp)),
             state, self._o_specs)
 
-    def _telemetry_active(self):
-        return (self._recorder is not None and self._recorder.enabled
-                and self._telemetry_health)
-
     def _build(self):
         from ..models.transformer import lm_token_nll, chunked_token_nll
         from ..nn.module import Ctx
@@ -369,15 +366,7 @@ class PipelineLMTrainer:
         clip_norm = self.clip_norm
         n_chunks = self.overlap_chunks
         z1r, z1b = self._z1_rest, self._z1_blocks
-        telemetry = self._telemetry_active()
-        self._with_health = telemetry
-        self._seen_sigs.clear()
-        rec = self._recorder
-        if rec is not None and rec.enabled:
-            # re-traces re-report the trace-time accounting: reset the
-            # per-step gauge families so a rebuild never double-counts
-            rec.reset_gauges("collective/")
-            rec.reset_gauges("comm/group.")
+        telemetry = self._begin_step_build()
         bucketer_rest = bucketer_blocks = None
         if self.bucket_bytes and not zero1:
             # two dp bucket streams — one per param family — so a flat
@@ -663,34 +652,24 @@ class PipelineLMTrainer:
                     res += (health,)
                 return res
 
-        self._step_fn = jax.jit(step, donate_argnums=(0, 1))
+        self._step_fn = jax.jit(self._accounted(step),
+                                donate_argnums=(0, 1))
 
     # -- telemetry ------------------------------------------------------ #
     def set_telemetry(self, recorder, health: bool = True):
-        """Attach an observability Recorder (same contract as
-        ``SpmdTrainer.set_telemetry``): each step() emits a step record
-        (h2d / train_step spans with recompile detection; loss and
-        tokens/sec scalars, plus the axis-group-scoped grad/param/update
-        norms when ``health`` — the health variant changes the compiled
-        program).  Re-jits without losing training progress when called
-        after ``init()``.  Also installs ``recorder`` as the
-        process-active one, so the trace-time ``comm/group.<axis>.*``
-        accounting of the dp/pp exchanges lands in the same ring."""
-        from ..observability import set_recorder
-        self._recorder = recorder
-        self._telemetry_health = bool(health)
-        set_recorder(recorder)
-        if (self._step_fn is not None
-                and self._with_health != self._telemetry_active()):
+        """:meth:`TelemetryHost.set_telemetry` without the cost capture.
+        The trace-time ``comm/group.<axis>.*`` accounting of the dp/pp
+        exchanges lands in ``recorder``'s ring, and ``health`` adds the
+        axis-group-scoped grad/param/update norms."""
+        return super().set_telemetry(recorder, health, capture_cost=False)
+
+    def _ledger_devices(self):
+        return int(self.mesh.devices.size)
+
+    def _rebuild_step(self):
+        if self._step_fn is not None:
             self._step_fn = None
             self._build()
-        return self
-
-    def _rec(self):
-        if self._recorder is not None:
-            return self._recorder
-        from ..observability import null_recorder
-        return null_recorder()
 
     # -- API ----------------------------------------------------------- #
     def step(self, tokens, targets):
@@ -726,38 +705,10 @@ class PipelineLMTrainer:
         with rec.span("h2d"):
             tokens = jax.device_put(jnp.asarray(tokens), sh)
             targets = jax.device_put(jnp.asarray(targets), sh)
-        span_name = "train_step"
-        if rec.enabled:
-            sig = (tuple(tokens.shape), str(tokens.dtype),
-                   tuple(targets.shape), str(targets.dtype))
-            if sig not in self._seen_sigs:
-                self._seen_sigs.add(sig)
-                span_name = "train_step_compile"
-                rec.scalar("recompile", 1.0)
-                # a new signature re-TRACES: the trace-time accounting
-                # re-reports, and the accumulate-semantics group gauges
-                # would double-count without a reset here
-                rec.reset_gauges("collective/")
-                rec.reset_gauges("comm/group.")
-        with rec.span(span_name):
-            out = self._step_fn(self.params, self.opt_state, tokens,
-                                targets)
-        if self._with_health:
-            self.params, self.opt_state, loss, health = out
-        else:
-            self.params, self.opt_state, loss = out
-            health = None
+        (self.params, self.opt_state, loss), health = self._dispatch(
+            self._step_fn, (self.params, self.opt_state, tokens, targets),
+            (tokens, targets))
         self._step_count += 1
-        if rec.enabled:
-            wire = rec.gauge_value("collective/wire_bytes_per_step")
-            if wire:
-                rec.inc("collective/wire_bytes_total", wire)
-            n_tok = int(np.prod(np.shape(tokens)))
-            rec.inc("tokens_total", n_tok)
-            rec.scalar("records", n_tok)
-            rec.scalar("loss", loss)
-            if health:
-                for k, v in health.items():
-                    rec.scalar(k, v)
-            rec.end_step(self._step_count - 1)
+        self._record_step(self._step_count - 1,
+                          int(np.prod(np.shape(tokens))), loss, health)
         return loss

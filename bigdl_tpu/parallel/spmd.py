@@ -42,8 +42,8 @@ from .ring_attention import ring_attention_shmap
 from ..models.transformer import TransformerLM
 from ..ops.flash_attention import flash_attention
 from ..observability import collectives as _acct
-from ..observability import (DivergenceError, Recorder, null_recorder,
-                             set_recorder)
+from ..observability import DivergenceError
+from ..observability.host import TelemetryHost
 from ..optim.optimizer import make_accum_grads
 
 
@@ -104,8 +104,14 @@ def flash_attention_shmap(q, k, v, mesh: Mesh, causal: bool = True):
 _MESH_ATTENTION = (ring_attention_shmap, flash_attention_shmap)
 
 
-class SpmdTrainer:
-    """Compiles one fused (fwd + bwd + update) XLA program over the mesh."""
+class SpmdTrainer(TelemetryHost):
+    """Compiles one fused (fwd + bwd + update) XLA program over the mesh.
+    ``set_telemetry`` / ``set_health`` / ``serve_metrics`` come from
+    :class:`TelemetryHost` (attach before ``init()`` to compile once)."""
+
+    _items_counter = "tokens_total"
+    _capture_at_avals = False
+    _resize_adopted_ledger = True
 
     def __init__(self, model: TransformerLM, optim, mesh: Optional[Mesh] = None,
                  fsdp: bool = True, seed: int = 0,
@@ -151,16 +157,7 @@ class SpmdTrainer:
         self.opt_state = None
         self._step_fn = None
         self._step_count = 0
-        self._recorder = None
-        self._trace_ctx = None          # TraceContext from the supervisor
-        self._tracer = None             # None -> process default
-        self._telemetry_health = True
-        self._with_health = False
-        self._hlo_accounted = False
-        self._seen_sigs = set()
-        # static cost capture (observability.profile), once per init()
-        self._capture_cost = True
-        self._cost_pending = False
+        TelemetryHost.__init__(self)
         self._ckpt_layout = "orbax"
         self._ckpt_mgr = None
         self._shard_arrays = False      # elastic sliced saves (v2)
@@ -170,12 +167,6 @@ class SpmdTrainer:
         self._input_transform = None
         # attached streaming dataset whose cursor rides in checkpoints
         self._data_pipeline = None
-        # training-health layer (observability.health)
-        self._health_monitor = None
-        self._flight = None
-        self._watchdog = None
-        self._http_server = None
-        self._max_rollbacks = 2
 
     # ------------------------------------------------------------------ #
     def _param_shardings(self, params):
@@ -314,9 +305,7 @@ class SpmdTrainer:
 
         from ..optim.optimizer import health_scalars, mask_frozen_grads
 
-        telemetry = self._telemetry_active()
-        self._with_health = telemetry
-        self._seen_sigs.clear()
+        telemetry = self._begin_step_build()
         transform = self._input_transform
 
         def step(params, opt_state, tokens, targets, rng):
@@ -345,74 +334,22 @@ class SpmdTrainer:
             return new_params, new_opt, loss
 
         self._step_fn = jax.jit(step, donate_argnums=(0, 1))
-        self._cost_pending = True   # new program: re-capture its cost
         return self
 
     # -- telemetry ------------------------------------------------------- #
-    def set_telemetry(self, recorder, health: bool = True,
-                      capture_cost: bool = True):
-        """Attach an observability Recorder: each step() emits a step
-        record (spans: h2d / train_step with compile detection; scalars:
-        loss, tokens/sec, plus grad/param/update norms when ``health`` —
-        the health variant changes the compiled program, so set this
-        BEFORE init()/the first step).  Also installs ``recorder`` as
-        the process-active one.  ``capture_cost`` harvests XLA
-        cost/memory analysis from the compiled step (once per init(),
-        cache-served lowering at the first batch's shapes) so step
-        records carry ``perf/mfu`` / ``perf/hbm_bw_util`` /
-        ``mem/peak_hbm_bytes``, plus live ``mem/device.*`` gauges
-        (``capture_cost=False`` / ``BIGDL_PROFILE_CAPTURE=0`` disable
-        both the capture and the polling)."""
-        from ..observability.profile import (capture_enabled,
-                                             install_device_memory_poller)
-        self._recorder = recorder
-        self._telemetry_health = bool(health)
-        self._capture_cost = bool(capture_cost)
-        if self._capture_cost and capture_enabled():
-            install_device_memory_poller(recorder)
-        if recorder.enabled:
-            # goodput ledger over this trainer's whole mesh: end_step
-            # folds h2d/compile/checkpoint.blocking/elastic.reshard
-            # spans into badput, residual step time is goodput.  A
-            # rebuilt trainer (elastic replan) reuses the recorder's
-            # existing ledger — continuity across replans is the point
-            # — but must adopt the NEW mesh size
-            led = recorder.get_ledger()
-            if led is None:
-                from ..observability.goodput import GoodputLedger
-                recorder.set_ledger(GoodputLedger(
-                    name="train", devices=int(self.mesh.devices.size)))
-            else:
-                led.set_devices(int(self.mesh.devices.size))
-        set_recorder(recorder)
-        if (self._step_fn is not None
-                and self._with_health != self._telemetry_active()):
-            # re-jit with the new step signature WITHOUT losing training
-            # progress: init() re-randomizes params, so stash and restore
-            params, opt_state = self.params, self.opt_state
-            self._step_fn = None
-            self.init()
-            if params is not None:
-                self.params, self.opt_state = params, opt_state
-        return self
+    def _ledger_devices(self):
+        return int(self.mesh.devices.size)
 
-    def set_trace_context(self, ctx, tracer=None):
-        """Adopt a causal :class:`~bigdl_tpu.observability.context.
-        TraceContext` (e.g. the elastic supervisor's run trace): each
-        ``step()`` records a ``train.step`` span under it and every
-        checkpoint save carries a child context to the async writer
-        thread, so step → queue-wait → write shows up as ONE trace.
-        ``ctx=None`` detaches.  ``tracer`` overrides the process
-        default span store."""
-        self._trace_ctx = ctx
-        if tracer is not None:
-            self._tracer = tracer
-        return self
-
-    def _trace_spine(self):
-        from ..observability import tracing as trace_spine
-        return self._tracer if self._tracer is not None \
-            else trace_spine.get_tracer()
+    def _rebuild_step(self):
+        """Re-jit for the new step signature WITHOUT losing training
+        progress: init() re-randomizes params, so stash and restore."""
+        if self._step_fn is None:
+            return
+        params, opt_state = self.params, self.opt_state
+        self._step_fn = None
+        self.init()
+        if params is not None:
+            self.params, self.opt_state = params, opt_state
 
     def set_input_transform(self, fn):
         """Compile ``fn(tokens, rng) -> tokens`` into the jitted step —
@@ -423,12 +360,7 @@ class SpmdTrainer:
         ``set_telemetry(health=...)``, changing it after ``init()``
         re-jits without losing training progress."""
         self._input_transform = fn
-        if self._step_fn is not None:
-            params, opt_state = self.params, self.opt_state
-            self._step_fn = None
-            self.init()
-            if params is not None:
-                self.params, self.opt_state = params, opt_state
+        self._rebuild_step()
         return self
 
     def set_data_pipeline(self, dataset):
@@ -440,59 +372,6 @@ class SpmdTrainer:
         Feed ``fit(...)`` from ``dataset.stream()``."""
         self._data_pipeline = dataset
         return self
-
-    def set_health(self, policy: str = "warn", flight_dir=None,
-                   max_rollbacks: int = 2, stall_factor=None,
-                   install_crash_hooks: bool = True, **monitor_kw):
-        """Numeric-health sentinels over each step record (same layer as
-        ``Optimizer.set_health``): NaN/Inf, loss-spike, grad-explosion
-        detection riding the step's existing device→host results;
-        ``policy="rollback"`` needs ``set_checkpoint`` and restores the
-        newest intact checkpoint at most ``max_rollbacks`` times during
-        ``fit()``.  ``flight_dir`` arms the crash flight recorder."""
-        from ..observability.health import (FlightRecorder, HealthMonitor,
-                                           StallWatchdog)
-        if self._recorder is None:
-            self.set_telemetry(Recorder())
-        rec = self._recorder
-        if flight_dir is not None:
-            if self._flight is not None:     # reconfigure: one hook chain
-                self._flight.uninstall()
-            self._flight = FlightRecorder(rec, flight_dir)
-            if install_crash_hooks:
-                self._flight.install()
-        self._health_monitor = HealthMonitor(
-            policy=policy, recorder=rec, flight=self._flight, **monitor_kw)
-        self._max_rollbacks = int(max_rollbacks)
-        if stall_factor:
-            if self._watchdog is not None:
-                self._watchdog.stop()
-            self._watchdog = StallWatchdog(rec,
-                                           factor=float(stall_factor)).start()
-        if self._http_server is not None:
-            self._http_server.monitor = self._health_monitor
-            self._http_server.watchdog = self._watchdog \
-                or self._http_server.watchdog
-        return self
-
-    def serve_metrics(self, port: int = 0, host: str = "127.0.0.1",
-                      watchdog: bool = True):
-        """Live introspection server (``/metrics`` ``/healthz``
-        ``/records``) for this trainer's recorder; see
-        ``Optimizer.serve_metrics``.  Returns the server."""
-        from ..observability.health import StallWatchdog
-        from ..observability.http import IntrospectionServer
-        if self._recorder is None:
-            self.set_telemetry(Recorder())
-        if watchdog and self._watchdog is None:
-            self._watchdog = StallWatchdog(self._recorder).start()
-        if self._http_server is not None:   # reconfigure: no leaked
-            self._http_server.stop()        # thread/socket on the old port
-        self._http_server = IntrospectionServer(
-            self._recorder, port=port, host=host,
-            watchdog=self._watchdog,
-            monitor=self._health_monitor).start()
-        return self._http_server
 
     def straggler_report(self):
         """Per-host step-time attribution — the "which worker drags the
@@ -517,38 +396,6 @@ class SpmdTrainer:
                   "scalars": {"host": h}}
                  for h, m in enumerate(gathered)])
         return attribute_stragglers(self._rec().recent_records())
-
-    def _rec(self):
-        return self._recorder if self._recorder is not None \
-            else null_recorder()
-
-    def _telemetry_active(self):
-        """Compile health scalars into the step?  Only for an attached,
-        ENABLED recorder — a disabled one must get the plain program."""
-        return (self._recorder is not None and self._recorder.enabled
-                and self._telemetry_health)
-
-    def _capture_step_cost(self, tokens, targets, rng):
-        """Harvest XLA cost/memory analysis for the compiled GSPMD step
-        and attach the StepCostModel (per-step ``perf/mfu`` etc.).
-        Lowers with the CONCRETE placed arrays — abstract avals would
-        drop the shardings and analyze a different program; lowering
-        never reads or donates the buffers, and the compile is
-        cache-served against the dispatch about to happen.  Never
-        raises."""
-        from ..observability import profile as _profile
-        rec = self._rec()
-        if (not self._capture_cost or not rec.enabled
-                or not _profile.capture_enabled()):
-            return
-        try:
-            with rec.span("profile.capture"):
-                cost = _profile.capture_compiled(
-                    self._step_fn.lower(self.params, self.opt_state,
-                                        tokens, targets, rng).compile())
-        except Exception as e:
-            cost = {"unavailable": ["capture_failed"], "error": repr(e)}
-        _profile.attach_cost(rec, cost, kind="train_step")
 
     def account_collectives(self, tokens, targets):
         """Compile the current step for these shapes and parse the
@@ -595,7 +442,6 @@ class SpmdTrainer:
                           f"{op.replace('-', '_')}_wire_bytes", wire)
             rec.gauge(f"comm/group.{label}.wire_bytes_per_step",
                       d["wire_bytes"])
-        self._hlo_accounted = True
         return {"ops": by_op, "groups": groups,
                 "wire_bytes_per_step": total}
 
@@ -618,44 +464,17 @@ class SpmdTrainer:
             targets = jax.device_put(jnp.asarray(targets), sh)
         rng = jax.random.fold_in(jax.random.PRNGKey(self.seed + 1),
                                  self._step_count)
-        span_name = "train_step"
-        if rec.enabled:
-            sig = (tuple(tokens.shape), str(tokens.dtype),
-                   tuple(targets.shape), str(targets.dtype))
-            if sig not in self._seen_sigs:
-                self._seen_sigs.add(sig)
-                span_name = "train_step_compile"
-                rec.scalar("recompile", 1.0)
-                if self._cost_pending:
-                    self._cost_pending = False
-                    self._capture_step_cost(tokens, targets, rng)
-        with rec.span(span_name):
-            out = self._step_fn(self.params, self.opt_state, tokens,
-                                targets, rng)
-        if self._with_health:
-            self.params, self.opt_state, loss, health = out
-        else:
-            self.params, self.opt_state, loss = out
-            health = None
+        (self.params, self.opt_state, loss), health = self._dispatch(
+            self._step_fn,
+            (self.params, self.opt_state, tokens, targets, rng),
+            (tokens, targets))
         self._step_count += 1
-        if rec.enabled:
-            wire = rec.gauge_value("collective/wire_bytes_per_step")
-            if wire:
-                rec.inc("collective/wire_bytes_total", wire)
-            n_tok = int(np.prod(np.shape(tokens)))
-            rec.inc("tokens_total", n_tok)
-            rec.scalar("records", n_tok)   # records/sec == tokens/sec
-            rec.scalar("loss", loss)
-            if health:
-                for k, v in health.items():
-                    rec.scalar(k, v)
-            if jax.process_count() > 1:
-                # per-host step records: what the stall watchdog's
-                # straggler attribution groups by
-                rec.scalar("host", jax.process_index())
-            record = rec.end_step(self._step_count - 1)
-            if self._health_monitor is not None and record is not None:
-                self._health_monitor.check_record(record)
+        # per-host step records: what the stall watchdog's straggler
+        # attribution groups by
+        self._record_step(
+            self._step_count - 1, int(np.prod(np.shape(tokens))), loss,
+            health, {"host": jax.process_index()}
+            if jax.process_count() > 1 else None)
         if step_span is not None:
             step_span.end(step=self._step_count - 1)
         return loss

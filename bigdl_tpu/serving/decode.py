@@ -84,7 +84,8 @@ from ..nn.module import Ctx
 from ..observability import Recorder
 from .buckets import BucketLadder
 from .kvcache import PagedKVCache
-from .queue import EngineClosedError, LoadShedError
+from .queue import (EngineClosedError, EngineIntrospection,
+                    LoadShedError)
 from .registry import ModelRegistry
 
 _END = object()
@@ -155,7 +156,7 @@ class _DecodeRequest:
         return self.deadline is not None and now > self.deadline
 
 
-class DecodeEngine:
+class DecodeEngine(EngineIntrospection):
     """Slot-based continuous-batching decode over one TransformerLM.
 
     ``registry`` / ``model_name``  the served entry; its module must be
@@ -341,37 +342,6 @@ class DecodeEngine:
         hook (``aggregator.add(engine)`` scrapes the ``decode/*`` +
         ``kv/*`` SLO families)."""
         return [(self.model_name, self.recorder)]
-
-    def serve_metrics(self, port: int = 0, host: str = "127.0.0.1"):
-        """Live introspection for this engine's recorder: ``/metrics``
-        (``decode/*`` + ``kv/*`` per-token SLO families), ``/healthz``,
-        ``/records`` and ``/trace`` — same routes as ServingEngine."""
-        from ..observability.http import IntrospectionServer
-        trace_source = self.dump_chrome_trace \
-            if self.trace_ring is not None else None
-        server = IntrospectionServer(
-            self.recorder, port=port, host=host,
-            trace_source=trace_source).start()
-        while True:
-            with self._lock:
-                if self._closed:
-                    break
-                prev = self._http_server
-                if prev is None:
-                    self._http_server = server
-                    return server
-                self._http_server = None
-            prev.stop()
-        server.stop()
-        raise EngineClosedError(
-            "engine shut down while serve_metrics was binding")
-
-    def dump_chrome_trace(self) -> str:
-        from ..observability.profile import dump_chrome_trace
-        traces = self.trace_ring.traces() if self.trace_ring is not None \
-            else []
-        meta = {"dropped_traces": getattr(self.trace_ring, "dropped", 0)}
-        return dump_chrome_trace(traces, extra_meta=meta)
 
     # -- request path ----------------------------------------------------- #
     def submit(self, name: str, x, deadline_ms: Optional[float] = None,

@@ -42,6 +42,59 @@ class EngineClosedError(RuntimeError):
     """Submit after shutdown began."""
 
 
+class EngineIntrospection:
+    """``serve_metrics`` / ``dump_chrome_trace`` of an engine that has
+    ``recorder``, ``trace_ring`` (or None), ``_lock`` and ``_closed``,
+    and whose ``shutdown()`` takes ``_http_server`` and stops it."""
+
+    def serve_metrics(self, port: int = 0, host: str = "127.0.0.1"):
+        """Start the live introspection server for this engine's
+        recorder: ``/metrics`` (Prometheus — the engine's request / shed
+        / recompile counters, queue-depth gauges and latency summaries;
+        ``decode/*`` + ``kv/*`` per-token SLO families on a decode
+        engine), ``/healthz`` (includes the shed rate), ``/records``,
+        and ``/trace`` (Chrome-trace JSON of recent per-request span
+        timelines).  ``port=0`` binds an ephemeral port (the returned
+        server's ``.port``); ``shutdown()`` stops it."""
+        from ..observability.http import IntrospectionServer
+        trace_source = self.dump_chrome_trace \
+            if self.trace_ring is not None else None
+        server = IntrospectionServer(
+            self.recorder, port=port, host=host,
+            trace_source=trace_source).start()
+        # _http_server is shared with shutdown(): every read/write under
+        # self._lock (GL003), but stop() — which joins the serving
+        # thread — always runs outside it.  Last caller wins (the
+        # documented reconfigure semantics), shutdown wins terminally —
+        # and a raced caller gets an exception, never a dead server
+        # whose .port a scraper would be pointed at
+        while True:
+            with self._lock:
+                if self._closed:
+                    break
+                prev = self._http_server
+                if prev is None:
+                    self._http_server = server
+                    return server
+                self._http_server = None
+            prev.stop()     # reconfigure: no leaked thread/socket
+        server.stop()
+        raise EngineClosedError(
+            "engine shut down while serve_metrics was binding")
+
+    def dump_chrome_trace(self) -> str:
+        """Chrome-trace/Perfetto JSON of the recent completed request
+        traces (one track per request, B/E span pairs, trace IDs and
+        batch/bucket attribution in args).  Save to a file and load in
+        chrome://tracing or https://ui.perfetto.dev; also served live
+        by the ``/trace`` route of :meth:`serve_metrics`."""
+        from ..observability.profile import dump_chrome_trace
+        traces = self.trace_ring.traces() if self.trace_ring is not None \
+            else []
+        meta = {"dropped_traces": getattr(self.trace_ring, "dropped", 0)}
+        return dump_chrome_trace(traces, extra_meta=meta)
+
+
 class Request:
     """One in-flight prediction: ``x`` is ``(n, *feature_shape)``.
 

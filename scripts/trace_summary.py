@@ -6,7 +6,6 @@ Two subcommands:
                      that `jax.profiler.trace(dir)` writes (normally
                      needs TensorBoard's profile plugin):
 
-        python scripts/tpu_tuning.py profile      # writes /tmp/tpu_trace
         python scripts/trace_summary.py /tmp/tpu_trace [top_n]
         python scripts/trace_summary.py xplane /tmp/tpu_trace [top_n]
 
