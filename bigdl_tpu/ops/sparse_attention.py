@@ -135,38 +135,72 @@ def masked_attention(q, k, v, mask):
     return o.reshape(b, h, s, dh).astype(q.dtype)
 
 
+def visible_keys(q_pos, kv_len, n_keys: int, index=None, top_k: int = 0):
+    """bool (B, S, L): the keys at positions ``arange(n_keys)`` that each
+    query at global position ``q_pos`` (B, S) attends: ``k_pos <= q_pos``
+    and ``k_pos < kv_len`` (B,), and of those, with ``index`` = (qi
+    (B, S, Hi, Di), ki (B, L, Di), w (B, S, Hi)), the ``top_k`` the indexer
+    scores highest."""
+    k_pos = jnp.arange(n_keys)
+    visible = (k_pos[None, None, :] <= q_pos[:, :, None]) \
+        & (k_pos[None, None, :] < kv_len[:, None, None])
+    if index is not None:
+        qi, ki, w = index
+        visible = select_mask(index_scores(qi, ki, w), visible, top_k)
+    return visible
+
+
+def _in_query_blocks(fn, n_keys: int, xs, axes):
+    """``fn(*xs)``, against a long window a block of queries at a time
+    under ``lax.map`` (_BLOCK_ELEMENTS): ``xs[i]`` holds its queries on
+    axis ``axes[i]``, as the result does on ``axes[0]``."""
+    s = xs[0].shape[axes[0]]
+    blk = 1 << max(4, (_BLOCK_ELEMENTS // n_keys).bit_length() - 1)
+    if blk >= s or s % blk:
+        return fn(*xs)
+
+    def split(x, axis):
+        shape = x.shape[:axis] + (s // blk, blk) + x.shape[axis + 1:]
+        return jnp.moveaxis(x.reshape(shape), axis, 0)
+
+    out = lax.map(lambda a: fn(*a),
+                  tuple(split(x, a) for x, a in zip(xs, axes)))
+    out = jnp.moveaxis(out, 0, axes[0])
+    return out.reshape(out.shape[:axes[0]] + (s,) + out.shape[axes[0] + 2:])
+
+
+def attention_mask(q_pos, kv_len, n_keys: int, index=None, top_k: int = 0):
+    """:func:`visible_keys` as int8 (B, S, L), for a kernel that takes its
+    mask whole: the selection still runs in :func:`attend`'s blocks of
+    queries (the indexer's per-head scores of one block are what a few
+    arrays of ``_BLOCK_ELEMENTS`` a head hold), and only the blocks' masks
+    are kept."""
+    if index is None:
+        return visible_keys(q_pos, kv_len, n_keys).astype(jnp.int8)
+    qi, ki, w = index
+    return _in_query_blocks(
+        lambda q_pos, qi, w: visible_keys(
+            q_pos, kv_len, n_keys, (qi, ki, w), top_k).astype(jnp.int8),
+        n_keys, (q_pos, qi, w), (1, 1, 1))
+
+
 def attend(q, k, v, q_pos, kv_len, index=None, top_k: int = 0):
     """Causal attention of queries at global positions ``q_pos`` (B, S)
     over keys at positions ``arange(L)`` of which the first ``kv_len``
     (B,) are written.  ``index`` = (qi (B, S, Hi, Di), ki (B, L, Di),
     w (B, S, Hi)) adds the learned selection of ``top_k`` keys a query.
     Long windows are walked a block of queries at a time."""
-    b, h, s, dh = q.shape
     n_keys = k.shape[2]
 
     def block(q, q_pos, qi=None, w=None):
-        k_pos = jnp.arange(n_keys)
-        visible = (k_pos[None, None, :] <= q_pos[:, :, None]) \
-            & (k_pos[None, None, :] < kv_len[:, None, None])
-        if index is not None:
-            visible = select_mask(index_scores(qi, index[1], w), visible,
-                                  top_k)
-        return masked_attention(q, k, v, visible)
+        return masked_attention(q, k, v, visible_keys(
+            q_pos, kv_len, n_keys,
+            None if index is None else (qi, index[1], w), top_k))
 
-    blk = 1 << max(4, (_BLOCK_ELEMENTS // n_keys).bit_length() - 1)
-    if blk >= s or s % blk:
-        return block(q, q_pos, *(() if index is None
-                                 else (index[0], index[2])))
-    n = s // blk
-    xs = [jnp.moveaxis(q.reshape(b, h, n, blk, dh), 2, 0),
-          jnp.moveaxis(q_pos.reshape(b, n, blk), 1, 0)]
-    if index is not None:
-        qi, _, w = index
-        xs += [jnp.moveaxis(qi.reshape(b, n, blk, *qi.shape[2:]), 1, 0),
-               jnp.moveaxis(w.reshape(b, n, blk, w.shape[-1]), 1, 0)]
-    out = lax.map(lambda a: block(*a), tuple(xs))         # (n, B, H, blk, Dh)
-    return jnp.moveaxis(out, 0, 2).reshape(b, h, s, dh)
+    xs = (q, q_pos) if index is None else (q, q_pos, index[0], index[2])
+    return _in_query_blocks(block, n_keys, xs, (2, 1, 1, 1)[:len(xs)])
 
 
-__all__ = ["attend", "index_scores", "kth_largest_key", "masked_attention",
-           "positions_of", "select_mask", "sort_key"]
+__all__ = ["attend", "attention_mask", "index_scores", "kth_largest_key",
+           "masked_attention", "positions_of", "select_mask", "sort_key",
+           "visible_keys"]

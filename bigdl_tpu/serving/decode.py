@@ -73,7 +73,7 @@ import threading
 import time
 import weakref
 from concurrent.futures import Future
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -479,6 +479,8 @@ class DecodeEngine(EngineIntrospection):
         out["kv_pages_read_share"] = rec.counter_value("kv/pages_read") \
             / max(rec.counter_value("kv/pages_window"), 1.0)
         out["attn_route"] = self.kv.attention_path()[0]
+        # a prompt's chunks (None: this engine takes prompts whole)
+        out["chunk_attn_route"] = self.chunk_attention_path()[0]
         # the sparse route: rows the steps' attention read of the rows
         # that were live (0 over 0 for a model with no indexer)
         out["kv_rows_attended_share"] = \
@@ -491,6 +493,17 @@ class DecodeEngine(EngineIntrospection):
                 out[f"{label}_p50_ms"] = q.get("p50")
                 out[f"{label}_p99_ms"] = q.get("p99")
         return out
+
+    def chunk_attention_path(self) -> Tuple[Optional[str], str]:
+        """``(route, why)`` of a prompt chunk's attention
+        (:meth:`PagedKVCache.chunk_attention_path` over this engine's
+        chunk and the longest prompt's table); ``(None, why)`` for an
+        engine that takes its prompts whole."""
+        if not self.chunked:
+            return None, (f"max_prompt {self.max_prompt} fits one prefill: "
+                          "no chunk program")
+        return self.kv.chunk_attention_path(self.prefill_chunk,
+                                            self._chunk_pages)
 
     # -- program cache ----------------------------------------------------- #
     def _program(self, kind: str, bucket: Optional[int] = None):
@@ -531,6 +544,12 @@ class DecodeEngine(EngineIntrospection):
             # it while tracing): 0 = gather, 1 = pallas, 2 = sparse
             self.recorder.gauge("decode/attn_route", float(
                 _ATTN_ROUTES.index(kv.attention_path()[0])))
+            # and a prompt's chunks (kvcache.attend_chunk): 0 = window,
+            # 1 = pallas
+            if self.chunked:
+                self.recorder.gauge("decode/chunk_attn_route", float(
+                    _CHUNK_ATTN_ROUTES.index(
+                        self.chunk_attention_path()[0])))
 
             def fn(params, pool, tokens, lengths, tables, temps, step):
                 new_pool = dict(pool)
@@ -1245,6 +1264,7 @@ class DecodeEngine(EngineIntrospection):
 
 
 _ATTN_ROUTES = ("gather", "pallas", "sparse")
+_CHUNK_ATTN_ROUTES = ("window", "pallas")
 
 
 def _select_tokens(logits, temps, step, base_key):

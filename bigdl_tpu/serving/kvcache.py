@@ -68,8 +68,11 @@ import numpy as np
 from ..observability import Recorder
 from ..ops.paged_attention import (attend_window, paged_attention,
                                    paged_attention_path,
+                                   paged_chunk_attention,
+                                   paged_chunk_attention_path,
                                    sparse_paged_attention)
 from ..ops.sparse_attention import attend as attend_rows
+from ..ops.sparse_attention import attention_mask
 from ..quantized import dequantize_rows, quantize_rows
 
 
@@ -317,6 +320,24 @@ class PagedKVCache:
             jnp.int8 if self.int8 else self.dtype, self.n_heads,
             self.head_dim, backend=backend)
 
+    def chunk_attention_path(self, chunk: int,
+                             n_pages: Optional[int] = None,
+                             backend: Optional[str] = None
+                             ) -> Tuple[str, str]:
+        """``(route, why)`` of :meth:`attend_chunk` for ``chunk`` queries
+        against a table of ``n_pages`` pages (a slot's whole table by
+        default): ``"pallas"`` (the pages read in place, the scores kept
+        on the chip) or ``"window"`` (the window gathered, then float32
+        math in XLA), by :func:`~bigdl_tpu.ops.paged_attention.
+        paged_chunk_attention_path` over the pool's dtype and geometry.
+        An index-key pool takes the same routes: its selection reaches
+        either as a mask."""
+        return paged_chunk_attention_path(
+            jnp.int8 if self.int8 else self.dtype, self.q_heads,
+            self.n_heads, self.head_dim, self.page_size, chunk,
+            self.max_pages_per_slot if n_pages is None else n_pages,
+            backend=backend)
+
     def attend(self, layer_pool, tables, lengths, q, index=None):
         """Single-token attention of q ``(slots, heads, 1, head_dim)``
         over each slot's pages, the row :meth:`write_token` just wrote
@@ -436,22 +457,42 @@ class PagedKVCache:
         top-``index_top_k`` selection of an index-key pool applied per
         query (``index`` = (qi ``(1, C, index heads, index_dim)``, w
         ``(1, C, index heads)``)).  ``table`` holds the pages of the
-        longest prompt, and the whole of that window is gathered and
-        scored whatever ``start`` is: a chunk costs the same wherever in
-        its prompt it falls, so the gap it puts between two tokens of the
-        live slots is one length.  (A ladder of shorter windows for early
-        chunks served a fifth more requests a second, and put the gaps'
-        95th percentile on one rung or another, 53 or 99 ms, by the order
-        the prompts came in; my chip runs, PR 28.)  Returns ``(1, heads,
-        C, head_dim)``."""
+        longest prompt, and the whole of that window is scored whatever
+        ``start`` is: a chunk costs the same wherever in its prompt it
+        falls, so the gap it puts between two tokens of the live slots is
+        one length.  (A ladder of shorter windows for early chunks served
+        a fifth more requests a second, and put the gaps' 95th percentile
+        on one rung or another, 53 or 99 ms, by the order the prompts came
+        in; my chip runs, PR 28.  A cost that follows the offset spreads
+        that percentile by 32% over twelve seeds of the long-document
+        cell's traffic: ISSUE 31's simulation.)  Two routes,
+        :meth:`chunk_attention_path`: on a TPU, for a float pool that
+        tiles, one Pallas call walks every page of ``table`` in place
+        under ONE int8 mask (causal bound, length and selection folded
+        in) and skips none by the causal bound; elsewhere the window is
+        gathered and :func:`~bigdl_tpu.ops.sparse_attention.attend` walks
+        it.  Returns ``(1, heads, C, head_dim)``."""
         chunk = q.shape[2]
         tab = table[None]
-        k_win, v_win = self.gather_window(layer_pool, tab)
+        q_pos = (start + jnp.arange(chunk))[None]
+        kv_len = jnp.asarray(start + chunk)[None]
         if index is not None:
             index = (index[0], self.gather_index(layer_pool, tab), index[1])
-        return attend_rows(q, k_win, v_win,
-                           (start + jnp.arange(chunk))[None],
-                           jnp.asarray(start + chunk)[None], index,
+        n_pages = table.shape[0]
+        route, why = self.chunk_attention_path(chunk, n_pages)
+        if route == "pallas":
+            mask = attention_mask(q_pos, kv_len, n_pages * self.page_size,
+                                  index, self.index_top_k)[0]
+            return paged_chunk_attention(q, layer_pool["k"],
+                                         layer_pool["v"], table, mask)
+        if jax.default_backend() == "tpu" and not self.int8:
+            # as in attend: on the chip the window is never the intended
+            # route for a float pool
+            warnings.warn("PagedKVCache.attend_chunk gathers the whole "
+                          f"window, not the Pallas kernel: {why}",
+                          stacklevel=2)
+        k_win, v_win = self.gather_window(layer_pool, tab)
+        return attend_rows(q, k_win, v_win, q_pos, kv_len, index,
                            self.index_top_k)
 
 

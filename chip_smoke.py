@@ -374,12 +374,18 @@ def _serve_sparse(ctx, s):
            and rec.gauge_value("decode/attn_route") == 2.0,
            "decode attention did not take the sparse route: "
            + eng.kv.attention_path()[1])
+    if ctx.native:
+        _check(st["chunk_attn_route"] == "pallas"
+               and rec.gauge_value("decode/chunk_attn_route") == 1.0,
+               "a prompt's chunks gather the window on the chip: "
+               + eng.chunk_attention_path()[1])
     _check(0 < st["kv_rows_attended_share"] < 1,
            f"rows attended share {st['kv_rows_attended_share']}: the "
            "prompts lie past top-k, so less than every row is attended")
     _check(rec.counter_value("moe/experts_touched") > 0,
            "the routed experts counted nothing")
     return dict(compile_s=warm_s, run_s=run_s, attn_route=st["attn_route"],
+                chunk_attn_route=st["chunk_attn_route"],
                 prefill_chunks=int(st["prefill_chunks"]),
                 kv_rows_attended_share=st["kv_rows_attended_share"])
 

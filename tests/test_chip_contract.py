@@ -138,3 +138,4 @@ def test_chip_smoke_stages_tiny_on_cpu():
     assert "skipped" not in results["four_chips"]
     assert results["serve"]["warmup_compiles"] == 6
     assert results["serve"]["sparse"]["attn_route"] == "sparse"
+    assert results["serve"]["sparse"]["chunk_attn_route"] == "window"

@@ -46,24 +46,26 @@ def build(cfg=TOY, dtype="float32"):
     return cfg, model, prog.program_tree(cfg, KEY, model)
 
 
-def reference_logits(cfg, variant=ref.SOUND):
+def reference_logits(cfg, variant=ref.SOUND, seq=SEQ):
     w = jax.tree_util.tree_map(
         lambda a: a.astype(jnp.float32),
         ref.make_weights(cfg, KEY, jnp.dtype(cfg["param_dtype"])))
-    return np.asarray(ref.logits(w, jnp.asarray(SEQ), cfg, variant))
+    return np.asarray(ref.logits(w, jnp.asarray(seq), cfg, variant))
 
 
-def cache_for(model, pages=16, page_size=4, dtype=jnp.float32):
+def cache_for(model, pages=16, page_size=4, dtype=jnp.float32,
+              max_context=32):
     cfg = model.cfg
     return PagedKVCache(
         [b.attn.name for b in model.blocks], n_heads=cfg.n_kv_heads,
         q_heads=cfg.n_heads, head_dim=cfg.head_dim, n_pages=pages,
-        page_size=page_size, n_slots=2, max_context=32, dtype=dtype,
+        page_size=page_size, n_slots=2, max_context=max_context, dtype=dtype,
         index_dim=cfg.index_dim if cfg.index_heads else 0,
         index_top_k=cfg.index_top_k if cfg.index_heads else 0)
 
 
-def through_cache(model, params, kv, slot, chunk=4):
+def through_cache(model, params, kv, slot, chunk=4, SEQ=SEQ,
+                  N_PROMPT=N_PROMPT):
     """Chunked prefill of SEQ[:N_PROMPT] then one-token decode of the rest
     through `kv`'s pages of `slot`: the logits after every position from
     the prompt's last on, and after each chunk's last token."""
@@ -163,6 +165,42 @@ def test_chunked_prefill_then_decode_in_bfloat16():
         bad = reference_logits(cfg, variant)
         assert float(np.abs(bad[N_PROMPT:] - want[N_PROMPT:]).max()) \
             > 2 * BF16_TOL
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", F32_TOL),
+                                       ("bfloat16", BF16_TOL)])
+def test_chunked_prefill_through_the_chunk_kernel_is_the_reference(
+        monkeypatch, dtype, tol):
+    """`through_cache` once more with the chunk taking the Pallas kernel
+    (interpreted): heads of 128, pages of 16, chunks of 32 over a prompt
+    of 70 (the last chunk short, the table's last pages not held), then
+    one-token decode; the selection reaches the kernel as its mask."""
+    from bigdl_tpu.ops import paged_attention_mod as pa
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    wide = dict(TOY, head_dim=128)
+    seq = np.random.default_rng(1).integers(0, 128, 96).astype(np.int32)
+    with jax.default_matmul_precision("highest"):
+        cfg, model, params = build(wide, dtype=dtype)
+        want = reference_logits(cfg, seq=seq)
+        kv = cache_for(model, pages=20, page_size=16, max_context=128,
+                       dtype=jnp.dtype(dtype))
+        assert kv.chunk_attention_path(32)[0] == "pallas"
+        kv.alloc_for(1, 16)                  # the slot's pages start at 1
+        kv.alloc_for(0, len(seq))
+        got = through_cache(model, params, kv, 0, chunk=32, SEQ=seq,
+                            N_PROMPT=70)
+        assert sorted(got) == [31, 63] + list(range(69, 96))
+        # bfloat16 over 96 tokens: a selection or an expert choice falls
+        # the other way at some positions on EITHER route (0.2-0.3 there,
+        # 0.004 elsewhere; the window route's table is the same to 0.004),
+        # so against the reference it is the median position that is held
+        gaps = [float(np.abs(v - want[t]).max()) for t, v in got.items()]
+        assert (np.median(gaps) if dtype == "bfloat16" else max(gaps)) < tol
+        monkeypatch.setattr(pa, "_INTERPRET", False)
+        assert kv.chunk_attention_path(32)[0] == "window"
+        window = through_cache(model, params, kv, 0, chunk=32, SEQ=seq,
+                               N_PROMPT=70)
+        assert worst(got, window) < tol
 
 
 def test_selection_is_by_position_through_the_page_table(f32):
