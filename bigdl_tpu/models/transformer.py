@@ -25,7 +25,7 @@ TPU-first design decisions:
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +69,17 @@ class TransformerConfig:
     index_heads: int = 0
     index_dim: int = 64
     index_top_k: int = 2048
+    # layers of more than one kind, one entry a layer (None: all alike).
+    # windows[l] > 0: layer l is a sliding-window layer, a query at t
+    # attends t - W < s <= t (0: global, causal); rope_layers[l] False:
+    # layer l rotates neither q nor k (no positional embedding)
+    windows: Optional[Sequence[int]] = None
+    rope_layers: Optional[Sequence[bool]] = None
+    # routed experts (moe_capacity_factor None): the gate's activation,
+    # and whether the router reads the block's attention-normed input
+    # (a router placed before attention) rather than the experts' own
+    moe_activation: str = "silu"
+    moe_router_pre_attention: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -77,6 +88,17 @@ class TransformerConfig:
         if self.n_kv_heads is None:
             self.n_kv_heads = self.n_heads
         assert self.n_heads % self.n_kv_heads == 0
+        for per_layer in (self.windows, self.rope_layers):
+            assert per_layer is None or len(per_layer) == self.n_layers
+        assert not (self.windows and any(self.windows)
+                    and self.index_heads), \
+            "a sliding window and a learned selection do not combine"
+
+    def layer_window(self, i: int) -> int:
+        return int(self.windows[i]) if self.windows else 0
+
+    def layer_rope(self, i: int) -> bool:
+        return bool(self.rope_layers[i]) if self.rope_layers else True
 
 
 def apply_rope(x, positions, theta: float = 10000.0):
@@ -168,11 +190,18 @@ class MultiHeadAttention(Module):
     for the sp ring (see parallel/spmd.py: _RING_HOOK).  The flash
     kernel and the ring take q, k and v of equal heads: grouped K and V
     are repeated for them (the cached paths never repeat).
+
+    ``window`` > 0 makes this layer a sliding-window layer (a query at
+    ``t`` attends ``t - window < s <= t``), ``rope`` False one that
+    rotates neither q nor k: what ``TransformerConfig.windows`` /
+    ``rope_layers`` say of the layer, handed over by the block.
     """
 
-    def __init__(self, cfg: TransformerConfig, name=None):
+    def __init__(self, cfg: TransformerConfig, name=None, window: int = 0,
+                 rope: bool = True):
         super().__init__(name=name)
         self.cfg = cfg
+        self.window, self.rope = int(window), bool(rope)
         self.pspec = {"wq": P(None, "tp"), "wk": P(None, "tp"),
                       "wv": P(None, "tp"), "wo": P("tp", None)}
         # the spmd trainer injects a mesh-aware attention fn here
@@ -259,13 +288,17 @@ class MultiHeadAttention(Module):
         cfg = self.cfg
         b, s, _ = x.shape
         positions = jnp.arange(s)
-        rope = lambda t: apply_rope(t, positions, cfg.rope_theta)
+        rope = self.rotation(lambda t: apply_rope(t, positions,
+                                                  cfg.rope_theta))
         q, k, v = self.project_qkv(params, x, rope)
         if self.sparse:
             qi, ki, w = self.project_index(params, x, rope)
             o = sparse_attend(q, k, v, jnp.broadcast_to(positions, (b, s)),
                               jnp.full((b,), s), (qi, ki, w),
                               cfg.index_top_k)
+        elif self.window:
+            o = sparse_attend(q, k, v, jnp.broadcast_to(positions, (b, s)),
+                              jnp.full((b,), s), window=self.window)
         else:
             if cfg.n_kv_heads != cfg.n_heads:
                 rep = cfg.n_heads // cfg.n_kv_heads
@@ -275,6 +308,11 @@ class MultiHeadAttention(Module):
             else:
                 o = flash_attention(q, k, v, causal=True)
         return self.project_out(params, o)
+
+    def rotation(self, rope):
+        """``rope`` for a layer that rotates, the identity for one that
+        does not."""
+        return rope if self.rope else (lambda t: t)
 
     def apply_cached(self, params, x, cache, start):
         """Incremental attention for generation: project the ``s`` new
@@ -288,13 +326,15 @@ class MultiHeadAttention(Module):
         cfg = self.cfg
         b, s, _ = x.shape
         positions = start + jnp.arange(s)
-        rope = lambda t: apply_rope(t, positions, cfg.rope_theta)
+        rope = self.rotation(lambda t: apply_rope(t, positions,
+                                                  cfg.rope_theta))
         q, k, v = self.project_qkv(params, x, rope)
         new = {"k": lax.dynamic_update_slice(
                    cache["k"], k.astype(cache["k"].dtype), (0, 0, start, 0)),
                "v": lax.dynamic_update_slice(
                    cache["v"], v.astype(cache["v"].dtype), (0, 0, start, 0))}
-        if not self.sparse and cfg.n_kv_heads == cfg.n_heads:
+        if not self.sparse and not self.window \
+                and cfg.n_kv_heads == cfg.n_heads:
             # one K/V head a query head and no selection: the plain
             # sequence, kept as it was so that the programs of the models
             # that have always taken it do not change
@@ -319,7 +359,7 @@ class MultiHeadAttention(Module):
         o = sparse_attend(q, new["k"], new["v"],
                           jnp.broadcast_to(positions, (b, s)),
                           jnp.broadcast_to(start + s, (b,)), index,
-                          cfg.index_top_k)
+                          cfg.index_top_k, window=self.window)
         return self.project_out(params, o), new
 
 
@@ -360,16 +400,28 @@ class SwiGLU(Module):
 
 
 class TransformerBlock(Module):
-    def __init__(self, cfg: TransformerConfig, name=None):
+    """Pre-norm attention, then the MLP (dense, or routed experts), each
+    around a residual.  ``layer`` is the block's index: what kind of
+    attention layer it is comes from ``cfg.windows`` / ``cfg.rope_layers``
+    there.  With ``cfg.moe_router_pre_attention`` the experts' router
+    reads the block's attention-normed input ``norm1(x)``, while the
+    experts themselves read ``norm2(x + attention)`` as ever."""
+
+    def __init__(self, cfg: TransformerConfig, name=None, layer: int = 0):
         super().__init__(name=name)
         self.cfg = cfg
         self.norm1 = RMSNorm(cfg.d_model, name=f"{self.name}.norm1")
-        self.attn = MultiHeadAttention(cfg, name=f"{self.name}.attn")
+        self.attn = MultiHeadAttention(cfg, name=f"{self.name}.attn",
+                                       window=cfg.layer_window(layer),
+                                       rope=cfg.layer_rope(layer))
         self.norm2 = RMSNorm(cfg.d_model, name=f"{self.name}.norm2")
+        self.router_pre_attention = False
         if cfg.moe_experts > 0 and cfg.moe_capacity_factor is None:
             from ..nn.moe import RoutedExperts
             self.mlp = RoutedExperts(cfg.d_model, cfg.d_ff, cfg.moe_experts,
-                                     cfg.moe_top_k, name=f"{self.name}.moe")
+                                     cfg.moe_top_k, name=f"{self.name}.moe",
+                                     activation=cfg.moe_activation)
+            self.router_pre_attention = cfg.moe_router_pre_attention
         elif cfg.moe_experts > 0:
             from ..nn.moe import SwitchFFN
             self.mlp = SwitchFFN(cfg.d_model, cfg.d_ff, cfg.moe_experts,
@@ -389,17 +441,24 @@ class TransformerBlock(Module):
         return out
 
     def apply(self, params, x, ctx):
-        h = x + self._drop(self.attn.apply(
-            params, self.norm1.apply(params, x, ctx), ctx), ctx)
-        return h + self._drop(self.mlp.apply(
-            params, self.norm2.apply(params, h, ctx), ctx), ctx)
+        n1 = self.norm1.apply(params, x, ctx)
+        h = x + self._drop(self.attn.apply(params, n1, ctx), ctx)
+        return h + self._drop(self._mlp(params, h, n1, ctx), ctx)
+
+    def _mlp(self, params, h, n1, ctx):
+        """The MLP of ``h`` (the stream after attention); ``n1`` is the
+        block's attention-normed input, which a router placed before
+        attention reads."""
+        u = self.norm2.apply(params, h, ctx)
+        if self.router_pre_attention:
+            return self.mlp.apply(params, u, ctx, router_input=n1)
+        return self.mlp.apply(params, u, ctx)
 
     def apply_cached(self, params, x, ctx, cache, start):
-        a, cache = self.attn.apply_cached(
-            params, self.norm1.apply(params, x, ctx), cache, start)
+        n1 = self.norm1.apply(params, x, ctx)
+        a, cache = self.attn.apply_cached(params, n1, cache, start)
         h = x + a
-        return h + self.mlp.apply(params, self.norm2.apply(params, h, ctx),
-                                  ctx), cache
+        return h + self._mlp(params, h, n1, ctx), cache
 
     def apply_decode(self, params, x, ctx, positions, kv_io):
         """Slot-batched single-token decode: x (B, 1, d_model),
@@ -428,13 +487,13 @@ class TransformerBlock(Module):
             lambda t: apply_rope(t, positions, self.cfg.rope_theta))
 
     def _through_cache(self, params, x, ctx, kv_io, rope):
-        h = self.norm1.apply(params, x, ctx)
-        qkv = self.attn.project_qkv(params, h, rope)
+        rope = self.attn.rotation(rope)
+        n1 = self.norm1.apply(params, x, ctx)
+        qkv = self.attn.project_qkv(params, n1, rope)
         if self.attn.sparse:
-            qkv += (self.attn.project_index(params, h, rope),)
+            qkv += (self.attn.project_index(params, n1, rope),)
         h = x + self.attn.project_out(params, kv_io(self.attn.name, *qkv))
-        return h + self.mlp.apply(params, self.norm2.apply(params, h, ctx),
-                                  ctx)
+        return h + self._mlp(params, h, n1, ctx)
 
     def _drop(self, x, ctx):
         rate = self.cfg.dropout
@@ -472,7 +531,8 @@ class TransformerLM(Module):
         self.embed = TokenEmbedding(cfg.vocab_size, cfg.d_model,
                                     name=f"{self.name}.embed")
         self._remat_blocks = None
-        self.blocks = [TransformerBlock(cfg, name=f"{self.name}.block{i}")
+        self.blocks = [TransformerBlock(cfg, name=f"{self.name}.block{i}",
+                                        layer=i)
                        for i in range(cfg.n_layers)]
         self.final_norm = RMSNorm(cfg.d_model, name=f"{self.name}.final_norm")
         self.head = None if cfg.tie_embeddings else \

@@ -16,6 +16,7 @@ Load-balancing auxiliary loss (Switch Transformer eq. 4) rides on
 """
 from __future__ import annotations
 
+import functools
 import importlib
 
 import jax
@@ -178,22 +179,32 @@ def grouped_matmul(lhs, rhs, group_sizes):
     return out[:m] if pad else out
 
 
+# what the gate's half of an expert goes through: SwiGLU's SiLU, or the
+# ReLU of a ReGLU expert
+_GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 # jitted under a name of its own, so that the device trace and the
 # compiled program's metadata show the experts' matmuls apart from the
 # step's other work
-@jax.jit
-def _moe_experts(xs, w1, w3, w2, group_sizes):
-    h = jax.nn.silu(grouped_matmul(xs, w1, group_sizes)) \
+@functools.partial(jax.jit, static_argnames=("activation",))
+def _moe_experts(xs, w1, w3, w2, group_sizes, activation="silu"):
+    h = _GATE_ACTIVATIONS[activation](grouped_matmul(xs, w1, group_sizes)) \
         * grouped_matmul(xs, w3, group_sizes)
     return grouped_matmul(h, w2, group_sizes)
 
 
 class RoutedExperts(Module):
-    """Top-k routed SwiGLU experts with no capacity: no token is dropped.
+    """Top-k routed gated experts with no capacity: no token is dropped.
+    An expert is ``W2(act(W1 x) * (W3 x))``, ``activation`` "silu"
+    (SwiGLU) or "relu" (ReGLU).
 
-    The router scores every expert (``softmax(x W_r)`` in float32), takes
+    The router scores every expert (``softmax(r W_r)`` in float32), takes
     the ``top_k`` largest and weighs a chosen expert by its probability
-    over the sum of the chosen ones.  The (token, expert) pairs are sorted
+    over the sum of the chosen ones (which is the softmax over the chosen
+    logits alone).  It reads the experts' own input, or ``router_input``
+    where :meth:`apply` is given one (a router placed before attention
+    reads the block's input).  The (token, expert) pairs are sorted
     by expert and go through :func:`grouped_matmul`: rows follow the
     pairs, so a decode step reads the experts its tokens touch and no
     others, and an expert that takes most of the tokens just has more
@@ -206,10 +217,15 @@ class RoutedExperts(Module):
     sharded over 'ep' as :class:`SwitchFFN`'s is.
     """
 
-    def __init__(self, d_model, d_ff, n_experts, top_k, name=None):
+    def __init__(self, d_model, d_ff, n_experts, top_k, name=None,
+                 activation="silu"):
         super().__init__(name=name)
         if not 0 < top_k <= n_experts:
             raise ValueError(f"top_k {top_k} of {n_experts} experts")
+        if activation not in _GATE_ACTIVATIONS:
+            raise ValueError(f"activation {activation!r} is none of "
+                             f"{sorted(_GATE_ACTIVATIONS)}")
+        self.activation = activation
         self.d_model, self.d_ff = d_model, d_ff
         self.n_experts, self.top_k = int(n_experts), int(top_k)
         self.pspec = {"router": P(None, None),
@@ -236,13 +252,14 @@ class RoutedExperts(Module):
         picked, idx = lax.top_k(probs, self.top_k)
         return idx, picked / picked.sum(-1, keepdims=True)
 
-    def apply(self, params, x, ctx):
+    def apply(self, params, x, ctx, router_input=None):
         p = self.own(params)
         dt = x.dtype
         B, S, D = x.shape
         N, K, E = B * S, self.top_k, self.n_experts
         xt = x.reshape(N, D)
-        idx, gate = self.route(params, xt)
+        idx, gate = self.route(params, xt if router_input is None
+                               else router_input.reshape(N, D))
         valid = jnp.ones((N,), bool) if ctx.token_mask is None \
             else ctx.token_mask.reshape(N)
         # pairs sorted by expert; those of masked tokens sort behind
@@ -255,7 +272,8 @@ class RoutedExperts(Module):
             0, dtype=jnp.int32)
         xs = jnp.take(xt, order // K, axis=0)
         ys = _moe_experts(xs, p["w1"].astype(dt), p["w3"].astype(dt),
-                          p["w2"].astype(dt), load)
+                          p["w2"].astype(dt), load,
+                          activation=self.activation)
         rows = jnp.arange(N * K) < load.sum()
         ys = jnp.where(rows[:, None],
                        ys.astype(jnp.float32)

@@ -44,7 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import DEFAULT_MASK_VALUE
 from .sparse_attention import (index_scores, masked_attention,
-                               positions_of, select_mask)
+                               positions_of, ring_positions, select_mask)
 
 # Test hook: when True the kernel runs in interpret mode, so the TPU
 # code path itself (not the window route) is exercised on CPU.
@@ -116,6 +116,53 @@ def sparse_paged_attention(q, qi, w, k_pool, v_pool, ki_win, tables,
     ``lengths[s]`` is a candidate like any other.  -> (S, H, Dh)."""
     positions = _index_topk(qi, w, ki_win, lengths, top_k=int(top_k))
     return _sparse_attend(q, k_pool, v_pool, tables, positions)
+
+
+# The decode attention of grouped heads over a float pool with no index
+# keys (what `PagedKVCache.attention_path` calls "gather"), jitted under a
+# name of its own for the reason the two above are: the step's device
+# trace shows the gather and the attention of every layer apart from the
+# rest.  A Pallas kernel for grouped heads with a window bound would take
+# this function's place (ROADMAP R1).
+@functools.partial(jax.jit, static_argnames=("window",))
+def _window_attend(q, k_pool, v_pool, tables, lengths, *, window):
+    """q (S, H, Dh) of each slot's new token, at position ``lengths[s]``,
+    over the pages of ``tables`` (S, P; ``-1``: none), gathered: a table
+    as wide as the longest sequence, or (``window`` > 0: a sliding-window
+    layer) a RING of P pages in which logical page ``j`` lies in column
+    ``j % P``.  The mask goes by each row's position
+    (:func:`~bigdl_tpu.ops.sparse_attention.ring_positions`), not by its
+    column: ``lengths[s] - window < position <= lengths[s]``, so rows not
+    yet written, and a recycled page's stale rows (a ring that holds
+    ``window`` rows and a page more never shows one inside the window),
+    are hidden, their K masked by a select and their V zeroed.  K and V
+    enter the matmuls as stored, the probabilities in V's dtype; scores,
+    softmax and both sums are float32.  -> (S, H, Dh) in q's dtype."""
+    n_pages, page_size, hkv, dh = k_pool.shape
+    s, h, _ = q.shape
+    idx = jnp.where(tables < 0, n_pages, tables)
+    k_pos = ring_positions(lengths // page_size, tables.shape[1], page_size)
+    mask = (k_pos >= 0) & (k_pos <= lengths[:, None])
+    if window:
+        mask = mask & (k_pos > lengths[:, None] - window)
+
+    def rows(pool):
+        pages = jnp.take(pool, idx, axis=0, mode="fill", fill_value=0)
+        return pages.reshape(s, -1, hkv, dh)              # (S, L, Hkv, Dh)
+
+    k = rows(k_pool)
+    v = jnp.where(mask[:, :, None, None], rows(v_pool), 0)
+    # q meets K in the wider of their dtypes (an upcast is exact)
+    qk = jnp.promote_types(q.dtype, k.dtype)
+    qg = q.reshape(s, hkv, h // hkv, dh).astype(qk)
+    sc = jnp.einsum("skgd,slkd->skgl", qg, k.astype(qk),
+                    preferred_element_type=jnp.float32) * dh ** -0.5
+    sc = jnp.where(mask[:, None, None, :], sc, DEFAULT_MASK_VALUE)
+    e = jnp.exp(sc - sc.max(axis=-1, keepdims=True))
+    o = jnp.einsum("skgl,slkd->skgd", e.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    o = o / e.sum(axis=-1, keepdims=True)
+    return o.reshape(s, h, dh).astype(q.dtype)
 
 
 def paged_attention_path(pool_dtype, n_heads: int, head_dim: int, *,

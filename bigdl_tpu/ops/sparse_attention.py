@@ -135,15 +135,45 @@ def masked_attention(q, k, v, mask):
     return o.reshape(b, h, s, dh).astype(q.dtype)
 
 
-def visible_keys(q_pos, kv_len, n_keys: int, index=None, top_k: int = 0):
+def ring_positions(top_page, n_cols: int, page_size: int):
+    """The key position of every row of a page table whose ``n_cols``
+    columns are a ring: logical page ``j`` of the sequence lies in column
+    ``j % n_cols``, and a column holds the LATEST such page up to
+    ``top_page`` (B,), the page of the newest row.  -> (B, n_cols *
+    page_size) int32; negative where the column has held no page yet.  A
+    table as wide as its sequences (no page ever recycled) reads
+    ``arange`` up to the top page: the same formula serves both."""
+    col = jnp.arange(n_cols, dtype=jnp.int32)
+    top = top_page.astype(jnp.int32)[:, None]
+    page = top - (top - col[None, :]) % n_cols            # (B, n_cols)
+    pos = page[:, :, None] * page_size \
+        + jnp.arange(page_size, dtype=jnp.int32)
+    return pos.reshape(top.shape[0], n_cols * page_size)
+
+
+def visible_keys(q_pos, kv_len, n_keys: int, index=None, top_k: int = 0,
+                 window: int = 0, k_pos=None):
     """bool (B, S, L): the keys at positions ``arange(n_keys)`` that each
     query at global position ``q_pos`` (B, S) attends: ``k_pos <= q_pos``
     and ``k_pos < kv_len`` (B,), and of those, with ``index`` = (qi
     (B, S, Hi, Di), ki (B, L, Di), w (B, S, Hi)), the ``top_k`` the indexer
-    scores highest."""
-    k_pos = jnp.arange(n_keys)
-    visible = (k_pos[None, None, :] <= q_pos[:, :, None]) \
-        & (k_pos[None, None, :] < kv_len[:, None, None])
+    scores highest.  ``window`` > 0 adds the lower bound of a
+    sliding-window layer, ``k_pos > q_pos - window`` (``window`` keys, the
+    query's own included).  ``k_pos`` (B, L) gives the keys' positions
+    where the columns are not in order (a ring of pages,
+    :func:`ring_positions`; negative: no key): the mask goes by position,
+    never by column."""
+    if k_pos is None:
+        k_pos = jnp.arange(n_keys)
+        visible = (k_pos[None, None, :] <= q_pos[:, :, None]) \
+            & (k_pos[None, None, :] < kv_len[:, None, None])
+        k_pos = k_pos[None] if window else None
+    else:
+        visible = (k_pos[:, None, :] <= q_pos[:, :, None]) \
+            & (k_pos[:, None, :] < kv_len[:, None, None]) \
+            & (k_pos[:, None, :] >= 0)
+    if window:
+        visible = visible & (k_pos[:, None, :] > q_pos[:, :, None] - window)
     if index is not None:
         qi, ki, w = index
         visible = select_mask(index_scores(qi, ki, w), visible, top_k)
@@ -169,38 +199,44 @@ def _in_query_blocks(fn, n_keys: int, xs, axes):
     return out.reshape(out.shape[:axes[0]] + (s,) + out.shape[axes[0] + 2:])
 
 
-def attention_mask(q_pos, kv_len, n_keys: int, index=None, top_k: int = 0):
+def attention_mask(q_pos, kv_len, n_keys: int, index=None, top_k: int = 0,
+                   window: int = 0, k_pos=None):
     """:func:`visible_keys` as int8 (B, S, L), for a kernel that takes its
     mask whole: the selection still runs in :func:`attend`'s blocks of
     queries (the indexer's per-head scores of one block are what a few
     arrays of ``_BLOCK_ELEMENTS`` a head hold), and only the blocks' masks
     are kept."""
     if index is None:
-        return visible_keys(q_pos, kv_len, n_keys).astype(jnp.int8)
+        return visible_keys(q_pos, kv_len, n_keys, window=window,
+                            k_pos=k_pos).astype(jnp.int8)
     qi, ki, w = index
     return _in_query_blocks(
         lambda q_pos, qi, w: visible_keys(
-            q_pos, kv_len, n_keys, (qi, ki, w), top_k).astype(jnp.int8),
+            q_pos, kv_len, n_keys, (qi, ki, w), top_k, window,
+            k_pos).astype(jnp.int8),
         n_keys, (q_pos, qi, w), (1, 1, 1))
 
 
-def attend(q, k, v, q_pos, kv_len, index=None, top_k: int = 0):
+def attend(q, k, v, q_pos, kv_len, index=None, top_k: int = 0,
+           window: int = 0, k_pos=None):
     """Causal attention of queries at global positions ``q_pos`` (B, S)
-    over keys at positions ``arange(L)`` of which the first ``kv_len``
-    (B,) are written.  ``index`` = (qi (B, S, Hi, Di), ki (B, L, Di),
-    w (B, S, Hi)) adds the learned selection of ``top_k`` keys a query.
-    Long windows are walked a block of queries at a time."""
+    over keys at positions ``arange(L)`` (or ``k_pos`` (B, L)) of which
+    the first ``kv_len`` (B,) are written.  ``index`` = (qi (B, S, Hi, Di),
+    ki (B, L, Di), w (B, S, Hi)) adds the learned selection of ``top_k``
+    keys a query, ``window`` > 0 a sliding window's lower bound.  Long
+    windows are walked a block of queries at a time."""
     n_keys = k.shape[2]
 
     def block(q, q_pos, qi=None, w=None):
         return masked_attention(q, k, v, visible_keys(
             q_pos, kv_len, n_keys,
-            None if index is None else (qi, index[1], w), top_k))
+            None if index is None else (qi, index[1], w), top_k, window,
+            k_pos))
 
     xs = (q, q_pos) if index is None else (q, q_pos, index[0], index[2])
     return _in_query_blocks(block, n_keys, xs, (2, 1, 1, 1)[:len(xs)])
 
 
 __all__ = ["attend", "attention_mask", "index_scores", "kth_largest_key",
-           "masked_attention", "positions_of", "select_mask", "sort_key",
-           "visible_keys"]
+           "masked_attention", "positions_of", "ring_positions", "select_mask",
+           "sort_key", "visible_keys"]

@@ -169,7 +169,12 @@ class DecodeEngine(EngineIntrospection):
     ``page_size`` / ``pool_pages``  paged-KV geometry (kvcache.py);
                     ``pool_pages`` defaults to ``slots * max_context /
                     page_size`` (no eviction pressure); smaller pools
-                    evict
+                    evict.  A model with sliding-window layers
+                    (``cfg.windows``) has a pool a KIND of layer:
+                    ``pool_pages`` is then ``{"global": n, "window": n}``
+                    (a kind left out: every slot's whole table, which for
+                    a window layer is a ring of ``window / page_size +
+                    prefill_chunk / page_size + 1`` pages)
     ``max_context`` longest prompt+generation a slot may hold
     ``max_prompt``  admission cap on client prompt length
                     (readmissions may re-prefill up to max_context)
@@ -181,7 +186,10 @@ class DecodeEngine(EngineIntrospection):
                     between decode steps, each chunk attending the
                     slot's own pages (so the gap a prefill puts between
                     two tokens of the live slots is one chunk's, however
-                    long the prompt); a multiple of ``page_size``
+                    long the prompt); a multiple of ``page_size``.  A
+                    model with sliding-window layers takes EVERY prompt
+                    in chunks: a whole-prompt prefill would have to fold
+                    its rows into a ring narrower than the prompt
     ``max_new_tokens``  default generation budget per request
     ``max_waiting`` waiting-queue bound, in requests — beyond it
                     submit sheds with :class:`LoadShedError`
@@ -255,7 +263,9 @@ class DecodeEngine(EngineIntrospection):
         # a prompt past the chunk goes through the one chunk program, and
         # then so does every prompt: the ladder is empty
         self.prefill_chunk = int(prefill_chunk)
-        self.chunked = self.max_prompt > self.prefill_chunk
+        windows = [cfg.layer_window(i) for i in range(cfg.n_layers)]
+        self.chunked = self.max_prompt > self.prefill_chunk \
+            or any(windows)
         if self.chunked and self.prefill_chunk % page_size:
             raise ValueError(f"prefill_chunk {prefill_chunk} must be a "
                              f"multiple of page_size {page_size}")
@@ -269,12 +279,11 @@ class DecodeEngine(EngineIntrospection):
             head_dim=cfg.head_dim,
             index_dim=cfg.index_dim if cfg.index_heads else 0,
             index_top_k=cfg.index_top_k if cfg.index_heads else 0,
-            n_pages=pool_pages if pool_pages is not None
-            else self.slots * -(-self.max_context // page_size),
-            page_size=page_size, n_slots=self.slots,
+            n_pages=pool_pages, page_size=page_size, n_slots=self.slots,
             max_context=self.max_context,
             dtype=kv_dtype or jnp.dtype(cfg.dtype), int8=int8_kv,
-            recorder=self.recorder)
+            windows=windows if any(windows) else None,
+            ring_slack=self.prefill_chunk, recorder=self.recorder)
         self.recorder.gauge("kv/index_bytes", self.kv.index_bytes())
         self._base_key = jax.random.PRNGKey(int(seed))
         self._pool = self.kv.init_pool()
@@ -387,7 +396,7 @@ class DecodeEngine(EngineIntrospection):
             raise ValueError(
                 f"prompt({prompt.size}) + max_new({max_new}) exceeds "
                 f"max_context {self.max_context}")
-        if self.kv.pages_for(prompt.size + max_new) > self.kv.n_pages:
+        if not self.kv.fits_pool(prompt.size + max_new):
             # a request the whole pool cannot hold would self-evict
             # forever once it ran alone — reject loudly at the door
             raise ValueError(
@@ -479,6 +488,8 @@ class DecodeEngine(EngineIntrospection):
         out["kv_pages_read_share"] = rec.counter_value("kv/pages_read") \
             / max(rec.counter_value("kv/pages_window"), 1.0)
         out["attn_route"] = self.kv.attention_path()[0]
+        # which kinds of layer the cache holds, and each kind's table
+        out["kv_kinds"] = {k.name: k.describe() for k in self.kv.kinds}
         # a prompt's chunks (None: this engine takes prompts whole)
         out["chunk_attn_route"] = self.chunk_attention_path()[0]
         # the sparse route: rows the steps' attention read of the rows
@@ -502,6 +513,8 @@ class DecodeEngine(EngineIntrospection):
         if not self.chunked:
             return None, (f"max_prompt {self.max_prompt} fits one prefill: "
                           "no chunk program")
+        # (a window layer's ring takes the route its filled-up width
+        # gives: kv.chunk_attention_path(..., layer=name))
         return self.kv.chunk_attention_path(self.prefill_chunk,
                                             self._chunk_pages)
 
@@ -554,24 +567,39 @@ class DecodeEngine(EngineIntrospection):
             def fn(params, pool, tokens, lengths, tables, temps, step):
                 new_pool = dict(pool)
                 ctx = Ctx(state={}, training=False, rng_key=None)
-                live = tables[:, 0] >= 0
+                live = kv.table_of(tables)[:, 0] >= 0
                 ctx.token_mask = live
 
                 def kv_io(name, q, k_new, v_new, index=None):
+                    # each layer its kind's table (the one table of a
+                    # model of one kind of layer)
+                    tab = kv.table_of(tables, name)
                     if index is None:
                         new_pool[name] = kv.write_token(
-                            new_pool[name], tables, lengths, k_new, v_new)
-                        return kv.attend(new_pool[name], tables, lengths, q)
+                            new_pool[name], tab, lengths, k_new, v_new,
+                            layer=name)
+                        if kv.windowed:
+                            # the rows the step's queries may see, by kind
+                            rows = jnp.where(live, lengths + 1, 0)
+                            window = kv.kind_of(name).window
+                            ctx.count("attn/rows_live", rows.sum())
+                            ctx.count(
+                                "attn/rows_attended_window" if window
+                                else "attn/rows_attended_global",
+                                (jnp.minimum(rows, window) if window
+                                 else rows).sum())
+                        return kv.attend(new_pool[name], tab, lengths, q,
+                                         layer=name)
                     qi, ki, w = index
                     new_pool[name] = kv.write_token(
-                        new_pool[name], tables, lengths, k_new, v_new,
+                        new_pool[name], tab, lengths, k_new, v_new,
                         ki[:, 0])
                     rows = jnp.where(live, lengths + 1, 0)
                     ctx.count("sparse/rows_live", rows.sum())
                     ctx.count("sparse/rows_scored", rows.sum())
                     ctx.count("sparse/rows_attended",
                               jnp.minimum(rows, kv.index_top_k).sum())
-                    return kv.attend(new_pool[name], tables, lengths, q,
+                    return kv.attend(new_pool[name], tab, lengths, q,
                                      (qi[:, 0], w[:, 0]))
 
                 logits = model.decode_tokens(params, tokens, lengths,
@@ -588,9 +616,9 @@ class DecodeEngine(EngineIntrospection):
             args = (self._aval_params(), self._pool_avals,
                     jax.ShapeDtypeStruct((self.slots,), jnp.int32),
                     jax.ShapeDtypeStruct((self.slots,), jnp.int32),
-                    jax.ShapeDtypeStruct(
-                        (self.slots, self.kv.max_pages_per_slot),
-                        jnp.int32),
+                    kv.pack([jax.ShapeDtypeStruct(
+                        (self.slots, k.width), jnp.int32)
+                        for k in kv.kinds]),
                     jax.ShapeDtypeStruct((self.slots,), jnp.float32),
                     jax.ShapeDtypeStruct((), jnp.int32))
         elif kind == "chunk":
@@ -600,16 +628,19 @@ class DecodeEngine(EngineIntrospection):
                               table, temp, step):
                 new_pool = dict(pool)
                 ctx = Ctx(state={}, training=False, rng_key=None)
-                pages = jax.lax.dynamic_slice_in_dim(
-                    table, start // kv.page_size, chunk // kv.page_size)
+                # the chunk's own pages, of each kind of table
+                pages = {k.name: kv.chunk_pages(
+                    kv.table_of(table, k.layers[0]), start, chunk,
+                    k.layers[0]) for k in kv.kinds}
 
                 def kv_io(name, q, k, v, index=None):
                     qi, ki, w = index or (None, None, None)
                     new_pool[name] = kv.write_chunk(
-                        new_pool[name], pages, k, v, ki)
+                        new_pool[name], pages[kv.kind_of(name).name], k, v,
+                        ki)
                     return kv.attend_chunk(
-                        new_pool[name], table, start, q,
-                        None if index is None else (qi, w))
+                        new_pool[name], kv.table_of(table, name), start, q,
+                        None if index is None else (qi, w), layer=name)
 
                 last = model.prefill_chunk(params, tokens, start, n_valid,
                                            kv_io, ctx)
@@ -626,7 +657,9 @@ class DecodeEngine(EngineIntrospection):
                     jax.ShapeDtypeStruct((1, chunk), jnp.int32),
                     jax.ShapeDtypeStruct((), jnp.int32),
                     jax.ShapeDtypeStruct((), jnp.int32),
-                    jax.ShapeDtypeStruct((n_pages,), jnp.int32),
+                    kv.pack([jax.ShapeDtypeStruct(
+                        (k.width if k.ring else n_pages,), jnp.int32)
+                        for k in kv.kinds]),
                     jax.ShapeDtypeStruct((), jnp.float32),
                     jax.ShapeDtypeStruct((), jnp.int32))
         else:
@@ -908,9 +941,7 @@ class DecodeEngine(EngineIntrospection):
         n = min(chunk, prompt.size - start)
         toks = np.zeros((1, chunk), np.int32)
         toks[0, :n] = prompt[start:start + n]
-        table = np.full(self._chunk_pages, -1, np.int32)
-        m = min(self._chunk_pages, self.kv.max_pages_per_slot)
-        table[:m] = self.kv.tables[slot, :m]
+        table = [self._chunk_table(k, slot) for k in self.kv.kinds]
         entry = self.registry.get(self.model_name)
         prog = self._program("chunk")
         with rec.span("decode.prefill", trace_id=trace_id, slot=slot,
@@ -918,8 +949,8 @@ class DecodeEngine(EngineIntrospection):
             tok, bad, self._pool, *counts = prog(
                 self._params_for_step(entry), self._pool,
                 jnp.asarray(toks), jnp.int32(start), jnp.int32(n),
-                jnp.asarray(table), jnp.float32(req.temperature),
-                jnp.int32(self._steps))
+                self.kv.pack([jnp.asarray(t) for t in table]),
+                jnp.float32(req.temperature), jnp.int32(self._steps))
             token = int(tok)
             # what the layers counted, under a prefill's own names: a
             # chunk's pairs are not a decode step's
@@ -934,6 +965,17 @@ class DecodeEngine(EngineIntrospection):
             return
         req.prefilled = None
         self._admitted(slot, req, token, chunk)
+
+    def _chunk_table(self, kind, slot: int) -> np.ndarray:
+        """What a chunk program takes of ``slot``'s table of ``kind``: the
+        pages of the longest prompt (``-1`` past the table's width), or a
+        window layer's whole ring."""
+        if kind.ring:
+            return kind.tables[slot].copy()
+        table = np.full(self._chunk_pages, -1, np.int32)
+        m = min(self._chunk_pages, kind.width)
+        table[:m] = kind.tables[slot, :m]
+        return table
 
     def _prefill_poisoned(self, slot, req, entry, bucket):
         # poisoned-weights sentinel: the program call SUCCEEDED
@@ -1050,7 +1092,8 @@ class DecodeEngine(EngineIntrospection):
                 return None
             tokens = self._last_tokens.copy()
             lengths = self._lengths.copy()
-            tables = self.kv.tables
+            kinds = self.kv.kinds
+            tables = [k.tables for k in kinds]
             temps = np.zeros(self.slots, np.float32)
             for s in live_slots:
                 temps[s] = self._live[s].temperature
@@ -1062,16 +1105,19 @@ class DecodeEngine(EngineIntrospection):
                 if s in self._live:
                     # held for a prompt still in chunks: the step must
                     # neither write its pages nor count it live
-                    if tables is self.kv.tables:
-                        tables = tables.copy()
-                    tables[s] = -1
+                    for i, k in enumerate(kinds):
+                        if tables[i] is k.tables:
+                            tables[i] = k.tables.copy()
+                        tables[i][s] = -1
             # the pages this step's attention has to read (each live
             # slot's, the new token's included) of those a gathered
             # window holds
-            rec.inc("kv/pages_read", int(
-                (lengths[live_slots] // self.kv.page_size + 1).sum()))
-            rec.inc("kv/pages_window",
-                    self.slots * self.kv.max_pages_per_slot)
+            top = lengths[live_slots] // self.kv.page_size + 1
+            rec.inc("kv/pages_read", int(sum(
+                (np.minimum(top, k.width) if k.ring else top).sum()
+                for k in kinds)))
+            rec.inc("kv/pages_window", self.slots * sum(
+                k.width for k in kinds))
             entry = self.registry.get(self.model_name)
             prog = self._program("decode")
             # chaos seam: delay = a wedged decode step (the replica wedge
@@ -1081,8 +1127,8 @@ class DecodeEngine(EngineIntrospection):
         with rec.span("decode.stage"):
             params = self._params_for_step(entry)
             inputs = (jnp.asarray(tokens), jnp.asarray(lengths),
-                      jnp.asarray(tables), jnp.asarray(temps),
-                      jnp.int32(self._steps))
+                      self.kv.pack([jnp.asarray(t) for t in tables]),
+                      jnp.asarray(temps), jnp.int32(self._steps))
         with rec.span("decode.dispatch"):
             tok, bad, self._pool, *counts = prog(params, self._pool,
                                                  *inputs)

@@ -36,6 +36,25 @@ step never sees the allocator — it takes the page tables as a plain
     paged logits match the ``init_cache`` path to rounding
     (tests/test_decode.py pins this) — and attended in float32.
 
+**Layers of two kinds.**  A model whose layers are not all alike (sliding
+windows beside global attention) gives the cache ``windows``, one entry a
+layer (0: global).  The layers of one kind share a page table and a free
+list of their own (:class:`_TableKind`), and their pools have that kind's
+number of pages: a global layer's table is ``max_context / page_size``
+wide, a window layer's is a **ring** of ``window / page_size + ring_slack
+/ page_size + 1`` pages in which logical page ``j`` of the sequence lies
+in column ``j % ring``.  Once a slot holds its whole ring,
+:meth:`PagedKVCache.alloc_for` recycles its pages in place as the slot
+moves on (``kv/pages_recycled``, span ``kv.recycle``): the table does not
+change, the rows of the oldest page are overwritten one by one, and what
+is left of them is stale.  Every mask over a ring goes by each row's
+POSITION (``ops/sparse_attention.py`` ``ring_positions``), which the
+column and the slot's length give, so a stale row reads as the position
+it is about to hold, past the newest, and is hidden like an unwritten one
+(masked K, zeroed V).  One ``alloc_for`` grows every kind or none;
+``free_slot`` and eviction return every kind's pages, and a readmission's
+re-prefill and replay rebuild a ring as they rebuild a table.
+
 Page tables are data, not shapes: admissions, retirements and
 evictions change *values* only, so one compiled decode program serves
 every batch composition — the zero-recompile discipline of the PR-2
@@ -66,18 +85,43 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import Recorder
-from ..ops.paged_attention import (attend_window, paged_attention,
+from ..ops.paged_attention import (_CHUNK_MAX_PAGES, _window_attend,
+                                   attend_window, paged_attention,
                                    paged_attention_path,
                                    paged_chunk_attention,
                                    paged_chunk_attention_path,
                                    sparse_paged_attention)
 from ..ops.sparse_attention import attend as attend_rows
-from ..ops.sparse_attention import attention_mask
+from ..ops.sparse_attention import attention_mask, ring_positions
 from ..quantized import dequantize_rows, quantize_rows
 
 
 class PagePoolError(RuntimeError):
     """Allocator invariant violation (double free, foreign page)."""
+
+
+class _TableKind:
+    """The page table, free list and ledger that the layers of one kind
+    share: ``window`` 0 for global attention (a table as wide as the
+    longest sequence), > 0 for a sliding window (a ring, see the module's
+    docstring).  Pages are numbered within the kind: page ``p`` is row
+    ``p`` of the pool of each of the kind's layers."""
+
+    def __init__(self, name: str, window: int, layers: List[str],
+                 width: int, n_pages: int, n_slots: int):
+        self.name, self.window, self.layers = name, int(window), layers
+        self.ring = self.window > 0       # a ring of pages, not a table
+        self.width, self.n_pages = int(width), int(n_pages)
+        # deterministic allocation order: lowest free page first
+        self.free: List[int] = list(range(self.n_pages))
+        self.owned: Dict[int, List[int]] = {s: [] for s in range(n_slots)}
+        # logical pages a slot has covered; a ring's may pass its width
+        self.covered = np.zeros(n_slots, np.int64)
+        self.tables = np.full((n_slots, self.width), -1, np.int32)
+
+    def describe(self) -> Dict[str, int]:
+        return {"layers": len(self.layers), "window": self.window,
+                "pages_per_slot": self.width, "n_pages": self.n_pages}
 
 
 class PagedKVCache:
@@ -89,7 +133,10 @@ class PagedKVCache:
     ``index_dim`` / ``index_top_k``  > 0: every token also caches one
                       index key of that width, and attention reads only
                       the ``index_top_k`` rows it scores highest
-    ``n_pages``       pool size, in pages, shared by all slots
+    ``n_pages``       pool size, in pages, shared by all slots; with
+                      layers of more than one kind ``{kind: pages}``
+                      (``"global"``, ``"window"``), a kind left out (or
+                      ``None``) sized for every slot's whole table
     ``page_size``     token rows per page
     ``n_slots``       concurrent sequences (page-table rows)
     ``max_context``   longest sequence a slot may hold; rounded up to a
@@ -99,21 +146,34 @@ class PagedKVCache:
                       int8 + fp32 scales)
     ``int8``          quantize KV rows on write, dequantize on gather
                       (an int8 pool always attends by the gather route)
+    ``windows``       one entry a layer: 0 for global attention, W > 0
+                      for a sliding window of W keys, whose layers hold
+                      a ring of ``ceil(W / page_size) + ring_slack /
+                      page_size + 1`` pages a slot (never more than a
+                      global table); ``None``: every layer global
+    ``ring_slack``    rows a ring holds beyond its window: the longest
+                      run of rows written before any of them is attended
+                      (a prefill chunk)
 
     The allocator side (``alloc_for`` / ``free_slot``) is guarded by
-    one lock and keeps the invariant ``free + sum(owned) == n_pages``
-    with every page owned by at most one slot — tests/test_decode.py
-    asserts it across alloc/free/evict churn.
+    one lock and keeps the invariant ``free + sum(owned) == n_pages`` for
+    every kind, with every page owned by at most one slot —
+    tests/test_decode.py and tests/test_window_moe.py assert it across
+    alloc/recycle/free/evict churn.  ``tables``, ``n_pages`` and
+    ``max_pages_per_slot`` are the first kind's (the global one where
+    there is one): all there is for a model of one kind of layer.
     """
 
     def __init__(self, layer_names: Sequence[str], *, n_heads: int,
-                 head_dim: int, n_pages: int, page_size: int = 16,
+                 head_dim: int, n_pages=None, page_size: int = 16,
                  n_slots: int = 8, max_context: int = 256,
                  dtype=jnp.float32, int8: bool = False,
                  q_heads: Optional[int] = None, index_dim: int = 0,
                  index_top_k: int = 0,
+                 windows: Optional[Sequence[int]] = None,
+                 ring_slack: int = 0,
                  recorder: Optional[Recorder] = None):
-        if page_size < 1 or n_pages < 1 or n_slots < 1:
+        if page_size < 1 or n_slots < 1:
             raise ValueError("page_size, n_pages and n_slots must be >= 1")
         if index_dim and (int8 or index_top_k < 1):
             raise ValueError("an index-key pool is a float pool with "
@@ -124,7 +184,6 @@ class PagedKVCache:
         self.head_dim = int(head_dim)
         self.index_dim = int(index_dim)
         self.index_top_k = int(index_top_k)
-        self.n_pages = int(n_pages)
         self.page_size = int(page_size)
         self.n_slots = int(n_slots)
         self.max_pages_per_slot = math.ceil(max_context / page_size)
@@ -135,24 +194,76 @@ class PagedKVCache:
         self.recorder = recorder if recorder is not None else Recorder(
             annotate=False, enabled=False)
         self._lock = threading.Lock()
-        # deterministic allocation order: lowest free page first
-        self._free: List[int] = list(range(self.n_pages))
-        self._owned: Dict[int, List[int]] = {s: [] for s in
-                                             range(self.n_slots)}
-        self.tables = np.full((self.n_slots, self.max_pages_per_slot),
-                              -1, np.int32)
+        windows = [0] * len(self.layer_names) if windows is None \
+            else [int(w) for w in windows]
+        if len(windows) != len(self.layer_names) or min(windows) < 0:
+            raise ValueError(f"windows {windows}: one entry >= 0 a layer")
+        if any(windows) and (int8 or index_dim):
+            raise ValueError("sliding-window layers take a float pool "
+                             "with no index keys")
+        self.kinds: List[_TableKind] = []
+        sizes = sorted(set(windows))
+        if len(sizes) - (0 in sizes) > 1:
+            raise ValueError(f"windows {windows}: window layers of one "
+                             "size")
+        for w in sizes:
+            name = "global" if w == 0 else "window"
+            width = self.max_pages_per_slot if w == 0 else min(
+                self.max_pages_per_slot,
+                math.ceil(w / page_size) + math.ceil(ring_slack / page_size)
+                + 1)
+            if isinstance(n_pages, dict):
+                pages = n_pages.get(name)
+            elif n_pages is None or len(sizes) == 1:
+                pages = n_pages
+            else:
+                raise ValueError("layers of more than one kind take "
+                                 "n_pages as {kind: pages}")
+            pages = self.n_slots * width if pages is None else int(pages)
+            if pages < 1:
+                raise ValueError("page_size, n_pages and n_slots must be "
+                                 ">= 1")
+            self.kinds.append(_TableKind(
+                name, w, [n for n, lw in zip(self.layer_names, windows)
+                          if lw == w], width, pages, self.n_slots))
+        self._kind_of = {n: k for k in self.kinds for n in k.layers}
+        self.windowed = any(k.ring for k in self.kinds)
+
+    # the first kind's: all there is for a model of one kind of layer
+    n_pages = property(lambda self: self.kinds[0].n_pages)
+    tables = property(lambda self: self.kinds[0].tables)
+    _free = property(lambda self: self.kinds[0].free)
+    _owned = property(lambda self: self.kinds[0].owned)
+
+    def kind_of(self, layer: Optional[str] = None) -> _TableKind:
+        """The kind of ``layer``'s table (``None``: the first kind)."""
+        return self.kinds[0] if layer is None else self._kind_of[layer]
+
+    def pack(self, values: Sequence[object]):
+        """One value a kind (a table, a shape), in the order of ``kinds``,
+        as the programs take them: the one value itself for a cache of one
+        kind (a decode tick of such a model builds no dict), else ``{kind:
+        value}``."""
+        if len(self.kinds) == 1:
+            return values[0]
+        return {k.name: v for k, v in zip(self.kinds, values)}
+
+    def table_of(self, tables, layer: Optional[str] = None):
+        """``layer``'s own of what :meth:`pack` made."""
+        return tables[self.kind_of(layer).name] \
+            if isinstance(tables, dict) else tables
 
     # -- device pool ------------------------------------------------------ #
     def init_pool(self):
         """Zeroed device pool pytree: ``{layer: {"k", "v"[, "k_scale",
         "v_scale"][, "ki"]}}`` with pages laid out ``(n_pages, page_size,
         n_heads, head_dim)`` (scales ``(n_pages, page_size, n_heads,
-        1)``, index keys ``(n_pages, page_size, index_dim)``).  Zero
-        pages read back as the zero rows of a fresh contiguous cache."""
-        shape = (self.n_pages, self.page_size, self.n_heads, self.head_dim)
-        sshape = shape[:-1] + (1,)
-
-        def one():
+        1)``, index keys ``(n_pages, page_size, index_dim)``), ``n_pages``
+        the layer's kind's.  Zero pages read back as the zero rows of a
+        fresh contiguous cache."""
+        def one(n_pages):
+            shape = (n_pages, self.page_size, self.n_heads, self.head_dim)
+            sshape = shape[:-1] + (1,)
             if self.int8:
                 return {"k": jnp.zeros(shape, jnp.int8),
                         "v": jnp.zeros(shape, jnp.int8),
@@ -165,7 +276,8 @@ class PagedKVCache:
                                       self.dtype)
             return out
 
-        return {name: one() for name in self.layer_names}
+        return {name: one(self.kind_of(name).n_pages)
+                for name in self.layer_names}
 
     def index_bytes(self) -> int:
         """Bytes the index keys take of the pool (``kv/index_bytes``)."""
@@ -177,14 +289,25 @@ class PagedKVCache:
         return math.ceil(max(int(n_tokens), 0) / self.page_size)
 
     def can_fit(self, n_tokens: int) -> bool:
+        need = self.pages_for(n_tokens)
         with self._lock:
-            return self.pages_for(n_tokens) <= len(self._free)
+            return all(min(need, k.width) <= len(k.free)
+                       for k in self.kinds)
+
+    def fits_pool(self, n_tokens: int) -> bool:
+        """Whether the pools, all free, could hold a slot of ``n_tokens``
+        rows (of every kind: a ring never asks for more than its
+        width)."""
+        need = self.pages_for(n_tokens)
+        return all(min(need, k.width) <= k.n_pages for k in self.kinds)
 
     def alloc_for(self, slot: int, n_tokens: int) -> bool:
-        """Grow ``slot``'s table to cover ``n_tokens`` token rows.
-        All-or-nothing: returns False (allocating nothing) when the
-        free list cannot cover the growth — the caller then evicts or
-        backpressures."""
+        """Grow ``slot``'s tables, of every kind, to cover ``n_tokens``
+        token rows.  All-or-nothing: returns False (allocating nothing)
+        when some kind's free list cannot cover its growth — the caller
+        then evicts or backpressures.  A ring that is whole grows no
+        more: the logical pages past it are RECYCLED in place, each onto
+        the column of the page a ring's width behind it."""
         need_pages = self.pages_for(n_tokens)
         if need_pages > self.max_pages_per_slot:
             raise ValueError(
@@ -192,36 +315,67 @@ class PagedKVCache:
                 f"> max_pages_per_slot {self.max_pages_per_slot} "
                 f"(max_context {self.max_context})")
         with self._lock:
-            owned = self._owned[slot]
-            grow = need_pages - len(owned)
-            if grow <= 0:
+            # (a decode step asks this of every live slot: most of the
+            # time the row fits the pages held, and nothing moves)
+            for k in self.kinds:
+                # (a ring has covered what it holds or has recycled)
+                if need_pages > (k.covered[slot] if k.ring
+                                 else len(k.owned[slot])):
+                    break
+            else:
                 return True
-            if grow > len(self._free):
+            grow = [min(need_pages, k.width) - len(k.owned[slot])
+                    for k in self.kinds]
+            if any(g > len(k.free) for g, k in zip(grow, self.kinds)):
                 return False
-            for _ in range(grow):
-                page = self._free.pop(0)
-                self.tables[slot, len(owned)] = page
-                owned.append(page)
-            self.recorder.inc("kv/page_allocs", grow)
-            self._publish_gauges_locked()
+            for g, k in zip(grow, self.kinds):
+                owned = k.owned[slot]
+                for _ in range(g):
+                    page = k.free.pop(0)
+                    k.tables[slot, len(owned)] = page
+                    owned.append(page)
+                if k.ring and need_pages > k.covered[slot]:
+                    self._recycle_locked(k, slot, need_pages)
+            if max(grow) > 0:
+                self.recorder.inc("kv/page_allocs",
+                                  sum(g for g in grow if g > 0))
+                self._publish_gauges_locked()
             return True
 
+    def _recycle_locked(self, kind: _TableKind, slot: int, need_pages: int):
+        """``slot`` moves on to logical page ``need_pages - 1`` of a ring:
+        every page of it past the ring's width takes the column (and the
+        device page) of the page a width behind it, whose rows fall out
+        of every later query's window before the first of them is
+        overwritten.  The table does not change: what changes is which
+        position a column's rows are read as."""
+        recycled = need_pages - max(int(kind.covered[slot]), kind.width)
+        kind.covered[slot] = need_pages
+        if recycled > 0:
+            with self.recorder.span("kv.recycle", slot=slot,
+                                    pages=int(recycled)):
+                self.recorder.inc("kv/pages_recycled", int(recycled))
+
     def free_slot(self, slot: int, evict: bool = False) -> int:
-        """Return every page ``slot`` owns to the free list (retirement
-        or eviction); the table row resets to ``-1`` so in-flight
-        gathers read zeros and writes drop.  Returns the page count."""
+        """Return every page ``slot`` owns, of every kind, to the free
+        lists (retirement or eviction); the table rows reset to ``-1`` so
+        in-flight gathers read zeros and writes drop.  Returns the page
+        count."""
         with self._lock:
-            owned = self._owned[slot]
-            for page in owned:
-                if page in self._free:
-                    raise PagePoolError(
-                        f"double free: page {page} of slot {slot} is "
-                        "already on the free list")
-                self._free.append(page)
-            n = len(owned)
-            self._free.sort()
-            self._owned[slot] = []
-            self.tables[slot, :] = -1
+            n = 0
+            for k in self.kinds:
+                owned = k.owned[slot]
+                for page in owned:
+                    if page in k.free:
+                        raise PagePoolError(
+                            f"double free: page {page} of slot {slot} is "
+                            "already on the free list")
+                    k.free.append(page)
+                n += len(owned)
+                k.free.sort()
+                k.owned[slot] = []
+                k.covered[slot] = 0
+                k.tables[slot, :] = -1
             if n:
                 self.recorder.inc("kv/page_frees", n)
             if evict:
@@ -229,50 +383,66 @@ class PagedKVCache:
             self._publish_gauges_locked()
             return n
 
+    def _used_locked(self) -> int:
+        return sum(k.n_pages - len(k.free) for k in self.kinds)
+
     def pages_in_use(self) -> int:
         with self._lock:
-            return self.n_pages - len(self._free)
+            return self._used_locked()
 
     def fill(self) -> float:
         """Pool fill fraction in [0, 1] — the ``kv/pool_fill`` gauge."""
         with self._lock:
-            return (self.n_pages - len(self._free)) / self.n_pages
+            return self._used_locked() / sum(k.n_pages for k in self.kinds)
 
     def check_invariants(self):
-        """Every page owned at most once and free+owned == n_pages
-        (test seam; raises :class:`PagePoolError` on violation)."""
+        """For every kind: every page owned at most once, free+owned ==
+        n_pages, the table's columns the ledger's pages in order and no
+        slot past its table's width (test seam; raises
+        :class:`PagePoolError` on violation)."""
         with self._lock:
-            seen = list(self._free)
-            for slot, owned in self._owned.items():
-                seen += owned
-                for i, page in enumerate(owned):
-                    if self.tables[slot, i] != page:
+            for k in self.kinds:
+                seen = list(k.free)
+                for slot, owned in k.owned.items():
+                    seen += owned
+                    if len(owned) > k.width:
                         raise PagePoolError(
-                            f"table/ledger disagree at slot {slot}[{i}]")
-            if sorted(seen) != list(range(self.n_pages)):
-                raise PagePoolError(
-                    f"page ledger broken: {sorted(seen)} != "
-                    f"0..{self.n_pages - 1}")
+                            f"slot {slot} holds {len(owned)} {k.name} pages "
+                            f"of a table {k.width} wide")
+                    for i, page in enumerate(owned):
+                        if k.tables[slot, i] != page:
+                            raise PagePoolError(
+                                f"table/ledger disagree at slot {slot}[{i}]")
+                if sorted(seen) != list(range(k.n_pages)):
+                    raise PagePoolError(
+                        f"page ledger broken: {sorted(seen)} != "
+                        f"0..{k.n_pages - 1}")
 
     def _publish_gauges_locked(self):
-        used = self.n_pages - len(self._free)
+        used = self._used_locked()
         rec = self.recorder
         rec.gauge("kv/pages_in_use", used)
-        fill = used / self.n_pages
+        if len(self.kinds) > 1:
+            for k in self.kinds:
+                rec.gauge(f"kv/pages_in_use_{k.name}",
+                          k.n_pages - len(k.free))
+        fill = used / sum(k.n_pages for k in self.kinds)
         rec.gauge("kv/pool_fill", fill)
         if fill > rec.gauge_value("kv/peak_fill", 0.0):
             rec.gauge("kv/peak_fill", fill)
 
     # -- jitted write/attend (fixed shapes, traced) ------------------------ #
-    def _oob(self, idx):
-        """Map the host tables' ``-1`` free markers to ``n_pages`` —
+    @staticmethod
+    def _oob(idx, layer_pool):
+        """Map the host tables' ``-1`` free markers to ``n_pages`` (the
+        pool's own, which is its kind's) —
         genuinely out of bounds.  jax scatter/gather WRAP negative
         indices (numpy semantics) *before* the drop/fill bounds check,
         so a raw ``-1`` would silently alias the pool's LAST page: a
         dead slot's write clobbered whichever request owned it.  A
         positive out-of-range index is what ``mode="drop"`` /
         ``mode="fill"`` actually drop/fill."""
-        return jnp.where(idx < 0, self.n_pages, idx)
+        return jnp.where(idx < 0, layer_pool["k"].shape[0], idx)
 
     def gather_window(self, layer_pool, tables):
         """(k_win, v_win) each ``(slots, heads, window, head_dim)``
@@ -280,7 +450,7 @@ class PagedKVCache:
         max_pages); ``-1`` entries fill with zeros.  Pages concatenate
         in table order, so a slot's window is exactly the contiguous
         cache a ``init_cache``-path request would hold."""
-        tables = self._oob(tables)
+        tables = self._oob(tables, layer_pool)
 
         def one(q, scale):
             pages = jnp.take(q, tables, axis=0, mode="fill",
@@ -298,7 +468,7 @@ class PagedKVCache:
     def gather_index(self, layer_pool, tables):
         """The index keys of ``tables`` (slots, pages) as ``(slots, pages *
         page_size, index_dim)``; ``-1`` entries fill with zeros."""
-        pages = jnp.take(layer_pool["ki"], self._oob(tables), axis=0,
+        pages = jnp.take(layer_pool["ki"], self._oob(tables, layer_pool), axis=0,
                          mode="fill", fill_value=0)
         return pages.reshape(tables.shape[0], -1, self.index_dim)
 
@@ -312,6 +482,10 @@ class PagedKVCache:
         if self.index_dim:
             return "sparse", (f"the pool holds index keys: top "
                               f"{self.index_top_k} rows a slot")
+        if self.windowed:
+            return "gather", ("layers with a sliding window hold a ring of "
+                              "pages, masked by position: the kernel takes "
+                              "a table in order")
         if self.q_heads != self.n_heads:
             return "gather", (f"{self.q_heads} query heads over "
                               f"{self.n_heads} KV heads: the kernel reads "
@@ -320,9 +494,23 @@ class PagedKVCache:
             jnp.int8 if self.int8 else self.dtype, self.n_heads,
             self.head_dim, backend=backend)
 
+    def chunk_table_width(self, kind: _TableKind,
+                          n_pages: Optional[int] = None) -> int:
+        """Columns of the table a chunk program walks for a layer of
+        ``kind``: the ``n_pages`` of the longest prompt (a slot's whole
+        table by default) for a global layer; a window layer's whole ring,
+        filled up with columns that hold no page to a whole number of the
+        chunk kernel's key blocks (a ring's width is what the window
+        makes it, 37 at 4,096 keys in pages of 128, and a kernel that
+        must divide it would take a page a step)."""
+        if not kind.ring:
+            return self.max_pages_per_slot if n_pages is None else n_pages
+        return -(-kind.width // _CHUNK_MAX_PAGES) * _CHUNK_MAX_PAGES
+
     def chunk_attention_path(self, chunk: int,
                              n_pages: Optional[int] = None,
-                             backend: Optional[str] = None
+                             backend: Optional[str] = None,
+                             layer: Optional[str] = None
                              ) -> Tuple[str, str]:
         """``(route, why)`` of :meth:`attend_chunk` for ``chunk`` queries
         against a table of ``n_pages`` pages (a slot's whole table by
@@ -331,14 +519,16 @@ class PagedKVCache:
         math in XLA), by :func:`~bigdl_tpu.ops.paged_attention.
         paged_chunk_attention_path` over the pool's dtype and geometry.
         An index-key pool takes the same routes: its selection reaches
-        either as a mask."""
+        either as a mask, and so does a window layer's lower bound
+        (``layer``: whose kind's table; the first kind's by default)."""
         return paged_chunk_attention_path(
             jnp.int8 if self.int8 else self.dtype, self.q_heads,
             self.n_heads, self.head_dim, self.page_size, chunk,
-            self.max_pages_per_slot if n_pages is None else n_pages,
+            self.chunk_table_width(self.kind_of(layer), n_pages),
             backend=backend)
 
-    def attend(self, layer_pool, tables, lengths, q, index=None):
+    def attend(self, layer_pool, tables, lengths, q, index=None,
+               layer: Optional[str] = None):
         """Single-token attention of q ``(slots, heads, 1, head_dim)``
         over each slot's pages, the row :meth:`write_token` just wrote
         at ``lengths[s]`` included (write, then attend).  Keys past it
@@ -346,6 +536,10 @@ class PagedKVCache:
         or non-finite rows cannot leak; a dead slot reads zeros (its
         token is never emitted).  ``index`` = (qi ``(slots, index heads,
         index_dim)``, w ``(slots, index heads)``) of an index-key pool.
+        ``layer`` says whose kind ``tables`` is (the first kind's by
+        default): a window layer's is a ring, and the mask is by position
+        (``ops/paged_attention.py`` ``_window_attend``, which grouped
+        heads over a float pool take for a global table too).
         Returns ``(slots, heads, 1, head_dim)`` in q's dtype."""
         route, why = self.attention_path()
         if route == "sparse":
@@ -359,25 +553,34 @@ class PagedKVCache:
                                    layer_pool["v"], tables,
                                    lengths)[:, :, None]
         if jax.default_backend() == "tpu" and not self.int8 \
-                and self.q_heads == self.n_heads:
+                and self.q_heads == self.n_heads and not self.windowed:
             # on the chip the window is never the intended route for a
             # float pool: say so (once per call site)
             warnings.warn("PagedKVCache.attend gathers every slot's "
                           f"whole window, not the Pallas kernel: {why}",
                           stacklevel=2)
+        if self.windowed:
+            return _window_attend(
+                q[:, :, 0], layer_pool["k"], layer_pool["v"], tables,
+                lengths, window=self.kind_of(layer).window)[:, :, None]
         k_win, v_win = self.gather_window(layer_pool, tables)
         return attend_window(q, k_win, v_win, lengths)
 
     def write_token(self, layer_pool, tables, lengths, k_new, v_new,
-                    ki_new=None):
+                    ki_new=None, layer: Optional[str] = None):
         """Scatter one new k/v row per slot into the pool at
         ``(table[len // page], len % page)``.  k_new/v_new are
         ``(slots, heads, 1, head_dim)`` (the
         :meth:`~bigdl_tpu.models.transformer.MultiHeadAttention.project_qkv`
         output), ki_new ``(slots, index_dim)`` the index key of an
-        index-key pool; dead slots' ``-1`` page indices drop."""
+        index-key pool; dead slots' ``-1`` page indices drop.  In a ring
+        (``layer``'s kind) the column is the page's index modulo the
+        ring's width."""
+        col = lengths // self.page_size
+        if self.kind_of(layer).ring:
+            col = col % tables.shape[1]
         pidx = self._oob(jnp.take_along_axis(
-            tables, (lengths // self.page_size)[:, None], axis=1)[:, 0])
+            tables, col[:, None], axis=1)[:, 0], layer_pool)
         off = lengths % self.page_size
         out = dict(layer_pool)
         for key, new in (("k", k_new), ("v", v_new)):
@@ -404,7 +607,7 @@ class PagedKVCache:
         rows, which the per-slot attention mask never exposes, so
         dropping them is exact)."""
         pg = self.page_size
-        table = self._oob(table)
+        table = self._oob(table, layer_pool)
         out = dict(layer_pool)
         for key, arr in (("k", k), ("v", v), ("ki", ki)):
             if arr is None:
@@ -438,7 +641,7 @@ class PagedKVCache:
         cannot pay a chunk (a float pool only)."""
         n = k.shape[2]
         at = jnp.arange(n)
-        pidx = jnp.take(self._oob(table), at // self.page_size)
+        pidx = jnp.take(self._oob(table, layer_pool), at // self.page_size)
         off = at % self.page_size
         out = dict(layer_pool)
         for key, rows in (("k", jnp.swapaxes(k[0], 0, 1)),
@@ -449,7 +652,19 @@ class PagedKVCache:
                     rows.astype(layer_pool[key].dtype), mode="drop")
         return out
 
-    def attend_chunk(self, layer_pool, table, start, q, index=None):
+    def chunk_pages(self, table, start, chunk: int,
+                    layer: Optional[str] = None):
+        """The ``chunk / page_size`` entries of ``table`` that a chunk at
+        ``start`` writes: consecutive columns of a table in order, the
+        columns modulo the ring's width of a ring."""
+        n, first = chunk // self.page_size, start // self.page_size
+        kind = self.kind_of(layer)
+        if kind.ring:
+            return jnp.take(table, (first + jnp.arange(n)) % kind.width)
+        return jax.lax.dynamic_slice_in_dim(table, first, n)
+
+    def attend_chunk(self, layer_pool, table, start, q, index=None,
+                     layer: Optional[str] = None):
         """A prefill chunk's attention against the slot's own pages, the
         chunk's rows (written before, :meth:`write_chunk`) included: q
         ``(1, heads, C, head_dim)`` at positions ``start + arange(C)``,
@@ -471,18 +686,38 @@ class PagedKVCache:
         under ONE int8 mask (causal bound, length and selection folded
         in) and skips none by the causal bound; elsewhere the window is
         gathered and :func:`~bigdl_tpu.ops.sparse_attention.attend` walks
-        it.  Returns ``(1, heads, C, head_dim)``."""
+        it.  A window layer (``layer``'s kind) hands its ring over as
+        ``table``: the chunk's own rows lie in it already, every key a
+        query of the chunk may see still does (the ring holds the window
+        and a chunk more), each column's rows are read at the position
+        the chunk's last page gives them, and the window's lower bound is
+        one more term of the same mask; the ring costs the same whatever
+        ``start`` is, as the longest prompt's table does.  Returns
+        ``(1, heads, C, head_dim)``."""
         chunk = q.shape[2]
+        kind, bounds = self.kind_of(layer), {}
+        if kind.ring:
+            k_pos = ring_positions(
+                jnp.asarray((start + chunk - 1) // self.page_size)[None],
+                kind.width, self.page_size)
+            pad = self.chunk_table_width(kind) - kind.width
+            if pad:
+                table = jnp.concatenate(
+                    [table, jnp.full((pad,), -1, table.dtype)])
+                k_pos = jnp.concatenate(
+                    [k_pos, jnp.full((1, pad * self.page_size), -1,
+                                     k_pos.dtype)], axis=1)
+            bounds = {"window": kind.window, "k_pos": k_pos}
         tab = table[None]
         q_pos = (start + jnp.arange(chunk))[None]
         kv_len = jnp.asarray(start + chunk)[None]
         if index is not None:
             index = (index[0], self.gather_index(layer_pool, tab), index[1])
         n_pages = table.shape[0]
-        route, why = self.chunk_attention_path(chunk, n_pages)
+        route, why = self.chunk_attention_path(chunk, n_pages, layer=layer)
         if route == "pallas":
             mask = attention_mask(q_pos, kv_len, n_pages * self.page_size,
-                                  index, self.index_top_k)[0]
+                                  index, self.index_top_k, **bounds)[0]
             return paged_chunk_attention(q, layer_pool["k"],
                                          layer_pool["v"], table, mask)
         if jax.default_backend() == "tpu" and not self.int8:
@@ -493,7 +728,7 @@ class PagedKVCache:
                           stacklevel=2)
         k_win, v_win = self.gather_window(layer_pool, tab)
         return attend_rows(q, k_win, v_win, q_pos, kv_len, index,
-                           self.index_top_k)
+                           self.index_top_k, **bounds)
 
 
 __all__ = ["PagedKVCache", "PagePoolError"]

@@ -71,7 +71,23 @@ SIZES = dict(
                            max_len=1024, dtype="bfloat16"),
                    slots=4, max_context=1024, max_prompt=768,
                    prefill_chunk=256, prompts=(300, 700),
-                   new_tokens=(16, 16))),
+                   new_tokens=(16, 16)),
+               # a third: layers of two kinds (window 256 with rope, global
+               # without), 7 query heads a KV head, ReGLU experts routed
+               # from the block's input; short and long prompts mixed, the
+               # longest twice past a ring of 2 + 2 + 1 pages
+               windowed=dict(
+                   lm=dict(vocab_size=1024, d_model=512, n_heads=14,
+                           n_kv_heads=2, head_dim=128, n_layers=4, d_ff=256,
+                           moe_experts=8, moe_top_k=2,
+                           moe_capacity_factor=None, moe_activation="relu",
+                           moe_router_pre_attention=True,
+                           windows=(0, 256, 256, 256),
+                           rope_layers=(False, True, True, True),
+                           max_len=2048, dtype="bfloat16"),
+                   window=256, page_size=128, slots=4, max_context=2048,
+                   max_prompt=1536, prefill_chunk=256,
+                   prompts=(100, 700, 1400), new_tokens=(16, 16, 16))),
     flash_shape=(8, 8, 2048, 128),
     optim_leaf=(32000, 1024),
     four_conv_batch=1024, four_conv_iters=3,
@@ -318,20 +334,23 @@ def stage_serve(ctx):
                "decode attention gathers the window on the chip: "
                + eng.kv.attention_path()[1])
     sparse = _serve_sparse(ctx, s["sparse"])
-    return dict(compile_s=warm_s + sparse.pop("compile_s"),
-                run_s=run_s + sparse.pop("run_s"),
+    windowed = _serve_windowed(ctx, s["windowed"])
+    return dict(compile_s=warm_s + sparse.pop("compile_s")
+                + windowed.pop("compile_s"),
+                run_s=run_s + sparse.pop("run_s") + windowed.pop("run_s"),
                 attn_route=st["attn_route"],
                 kv_pages_read_share=st["kv_pages_read_share"],
                 warmup_compiles=int(st["warmup_compiles"]),
                 max_prompt=s["max_prompt"], requests=len(streams),
                 tokens=sum(len(t) for t in streamed),
-                decode_steps=int(st["steps"]), sparse=sparse)
+                decode_steps=int(st["steps"]), sparse=sparse,
+                windowed=windowed)
 
 
-def _serve_sparse(ctx, s):
-    """A small model with grouped KV heads, an indexer and routed experts
-    through the same engine: prompts past one chunk and past its top-k,
-    so the chunk program, the selection and the experts all run."""
+def _serve_small(s, what):
+    """A small second model through the same engine, its prompts in
+    chunks: build, warm up, serve `s["prompts"]`, and the checks every
+    such model shares.  -> (engine, stats, recorder, warm_s, run_s)."""
     from bigdl_tpu.models.transformer import TransformerConfig, TransformerLM
     from bigdl_tpu.serving import DecodeEngine, ModelRegistry
     model = TransformerLM(TransformerConfig(dropout=0.0, **s["lm"]))
@@ -345,6 +364,7 @@ def _serve_sparse(ctx, s):
                        max_context=s["max_context"],
                        max_prompt=s["max_prompt"],
                        prefill_chunk=s["prefill_chunk"],
+                       page_size=s.get("page_size", 16),
                        max_new_tokens=max(s["new_tokens"]))
     try:
         t0 = time.perf_counter()
@@ -362,14 +382,24 @@ def _serve_sparse(ctx, s):
     st, rec = eng.stats(), eng.recorder
     _check(all(len(r) == n + m for r, n, m in
                zip(results, s["prompts"], s["new_tokens"])),
-           "a sparse request came back short")
+           f"a {what} request came back short")
     _check(st["errors"] == 0 and st["recompiles"] == 0
            and rec.counter_value("decode/nonfinite") == 0,
-           f"sparse serve: errors {st['errors']}, recompiles "
+           f"{what} serve: errors {st['errors']}, recompiles "
            f"{st['recompiles']}")
     chunks = sum(-(-n // s["prefill_chunk"]) for n in s["prompts"])
     _check(st["prefill_chunks"] == chunks,
            f"{st['prefill_chunks']} prefill chunks, expected {chunks}")
+    _check(rec.counter_value("moe/experts_touched") > 0,
+           "the routed experts counted nothing")
+    return eng, st, rec, warm_s, run_s
+
+
+def _serve_sparse(ctx, s):
+    """A small model with grouped KV heads, an indexer and routed experts
+    through the same engine: prompts past one chunk and past its top-k,
+    so the chunk program, the selection and the experts all run."""
+    eng, st, rec, warm_s, run_s = _serve_small(s, "sparse")
     _check(st["attn_route"] == "sparse"
            and rec.gauge_value("decode/attn_route") == 2.0,
            "decode attention did not take the sparse route: "
@@ -382,12 +412,46 @@ def _serve_sparse(ctx, s):
     _check(0 < st["kv_rows_attended_share"] < 1,
            f"rows attended share {st['kv_rows_attended_share']}: the "
            "prompts lie past top-k, so less than every row is attended")
-    _check(rec.counter_value("moe/experts_touched") > 0,
-           "the routed experts counted nothing")
     return dict(compile_s=warm_s, run_s=run_s, attn_route=st["attn_route"],
                 chunk_attn_route=st["chunk_attn_route"],
                 prefill_chunks=int(st["prefill_chunks"]),
                 kv_rows_attended_share=st["kv_rows_attended_share"])
+
+
+def _serve_windowed(ctx, s):
+    """A small model of two kinds of layer (sliding windows with rope
+    beside global attention without, 7 query heads a KV head, ReGLU
+    experts routed from the block's input): short and long prompts in one
+    queue, the longest past the ring twice over, so window layers hold a
+    ring that recycles while global layers hold the context."""
+    eng, st, rec, warm_s, run_s = _serve_small(s, "windowed")
+    kinds = st["kv_kinds"]
+    ring = s["window"] // s["page_size"] \
+        + s["prefill_chunk"] // s["page_size"] + 1
+    _check(set(kinds) == {"global", "window"}
+           and kinds["window"]["pages_per_slot"] == ring
+           and kinds["global"]["pages_per_slot"]
+           == s["max_context"] // s["page_size"],
+           f"the cache's kinds of table are {kinds}: a ring of {ring} "
+           "pages was expected beside the context's table")
+    _check(max(s["prompts"]) > 2 * ring * s["page_size"]
+           and rec.counter_value("kv/pages_recycled") > 0,
+           "no page of a ring was recycled")
+    live = rec.counter_value("attn/rows_live")
+    _check(0 < rec.counter_value("attn/rows_attended_window")
+           < live * kinds["window"]["layers"] / len(eng.model.blocks),
+           "window layers' queries saw every live row")
+    _check(st["attn_route"] == "gather", "decode attention's route is "
+           + st["attn_route"])
+    if ctx.native:
+        routes = {k.name: eng.kv.chunk_attention_path(
+            s["prefill_chunk"], eng._chunk_pages, layer=k.layers[0])
+            for k in eng.kv.kinds}
+        _check(all(r[0] == "pallas" for r in routes.values()),
+               f"a prompt's chunks gather the window on the chip: {routes}")
+    return dict(compile_s=warm_s, run_s=run_s, kv_kinds=kinds,
+                prefill_chunks=int(st["prefill_chunks"]),
+                pages_recycled=int(rec.counter_value("kv/pages_recycled")))
 
 
 def stage_kernels(ctx):

@@ -123,7 +123,20 @@ def test_chip_smoke_stages_tiny_on_cpu():
                                max_len=128, dtype="bfloat16"),
                        slots=2, max_context=64, max_prompt=40,
                        prefill_chunk=16, prompts=(20, 40),
-                       new_tokens=(4, 4))),
+                       new_tokens=(4, 4)),
+                   windowed=dict(
+                       lm=dict(vocab_size=128, d_model=64, n_heads=7,
+                               n_kv_heads=1, head_dim=16, n_layers=4,
+                               d_ff=32, moe_experts=8, moe_top_k=2,
+                               moe_capacity_factor=None,
+                               moe_activation="relu",
+                               moe_router_pre_attention=True,
+                               windows=(0, 8, 8, 8),
+                               rope_layers=(False, True, True, True),
+                               max_len=128, dtype="bfloat16"),
+                       window=8, page_size=4, slots=3, max_context=96,
+                       max_prompt=64, prefill_chunk=8, prompts=(3, 30, 60),
+                       new_tokens=(4, 4, 4))),
         flash_shape=(1, 2, 256, 128), optim_leaf=(300, 130),
         four_conv_batch=8, four_conv_iters=3)
     old = fa._INTERPRET
@@ -139,3 +152,7 @@ def test_chip_smoke_stages_tiny_on_cpu():
     assert results["serve"]["warmup_compiles"] == 6
     assert results["serve"]["sparse"]["attn_route"] == "sparse"
     assert results["serve"]["sparse"]["chunk_attn_route"] == "window"
+    windowed = results["serve"]["windowed"]
+    assert windowed["kv_kinds"]["window"]["pages_per_slot"] == 5
+    assert windowed["kv_kinds"]["global"]["pages_per_slot"] == 24
+    assert windowed["pages_recycled"] > 0
