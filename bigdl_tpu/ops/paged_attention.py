@@ -1,8 +1,8 @@
-"""Paged attention: Pallas TPU kernels + gathered-window route.
+"""Paged attention: Pallas TPU kernels + gathered routes.
 
 Decode: one new token per slot attends to that slot's K and V where they
 lie in the page pool (``serving/kvcache.py``: ``(n_pages, page_size,
-n_heads, head_dim)``, one pool a layer), up to the slot's length, in the
+kv_heads, head_dim)``, one pool a layer), up to the slot's length, in the
 dtype they are stored in.  Prefill in chunks: the queries of one chunk of
 one prompt attend to that slot's pages the same way
 (:func:`paged_chunk_attention`, below the decode kernel).
@@ -15,21 +15,35 @@ one prompt attend to that slot's pages the same way
     slot, or the first of the next live slot) are already in flight.
     One page is one contiguous copy for all heads.  Scores, softmax and
     both accumulations are float32; K and V enter the MXU as stored.
-  * window route — :func:`attend_window` over a window gathered by
-    ``PagedKVCache.gather_window``: what runs on CPU, for an int8 pool,
-    and what the kernel is tested against.
+    Query heads may be grouped over fewer KV heads (K and V are never
+    repeated), and a layer may have a sliding window: its table is then
+    a RING of pages, the work list holds only the blocks with a page the
+    window shows, and a row is masked by its POSITION
+    (``sparse_attention.ring_positions``' rule), not by its column.  All
+    of that is static: one algorithm, and with as many KV heads as query
+    heads and no window the arithmetic it had before it learnt them.
+  * gathered routes — what runs on CPU, for an int8 pool, for rows that
+    do not tile, and what the kernel is tested against:
+    :func:`attend_window` over a window gathered by
+    ``PagedKVCache.gather_window``, and :func:`gathered_attention`
+    (grouped heads, masks by position) for a cache with window layers.
 
 :func:`paged_attention_path` is the one place that decides which route a
 pool takes and says why (as ``ops.attention_path`` does for the flash
-kernel).
+kernel); :func:`decode_blocks` chooses the pages a block from the page's
+shape.  A cache with window layers calls :func:`_window_attend`, a jitted
+entry under a name of its own, which picks the kernel or the gathered
+math by the same function.
 
 The kernel keeps the pool's token-major layout, so K of a block is
-``(tokens * heads, head_dim)`` after a free reshape, and the scores of
-all heads come from ONE matmul ``q (heads, head_dim) @ K^T -> (heads,
-tokens * heads)`` of which only the entries whose column's head is the
-row's own are kept: ``heads`` times the useful FLOPs on an MXU that idles
-anyway, and no relayout of K or V.  The masked probabilities are exactly
-zero, so ``p @ V`` over the same flat axis is the per-head value sum.
+``(tokens * kv_heads, head_dim)`` after a free reshape (a row of 16 or 8
+heads is whole sublane tiles, a row of 4 or 2 a tile of its own: the
+pool's HBM tiles are the buffer's), and the scores of all heads come from
+ONE matmul ``q (heads, head_dim) @ K^T -> (heads, tokens * kv_heads)`` of
+which only the entries whose column's head is the row's own KV head are
+kept: ``kv_heads`` times the useful FLOPs on an MXU that idles anyway,
+and no relayout of K or V.  The masked probabilities are exactly zero, so
+``p @ V`` over the same flat axis is the per-head value sum.
 """
 from __future__ import annotations
 
@@ -49,11 +63,6 @@ from .sparse_attention import (index_scores, masked_attention,
 # Test hook: when True the kernel runs in interpret mode, so the TPU
 # code path itself (not the window route) is exercised on CPU.
 _INTERPRET = False
-
-# Pages fetched per wait: 8 pages of 16 tokens are 128 tokens a block,
-# 512 KiB each of K and V in bf16 at 16 heads x 128, double-buffered.
-_PAGES_PER_BLOCK = 8
-
 
 def attend_window(q, k_win, v_win, positions):
     """Single-token attention of q (B, H, 1, Dh) against a gathered
@@ -118,26 +127,10 @@ def sparse_paged_attention(q, qi, w, k_pool, v_pool, ki_win, tables,
     return _sparse_attend(q, k_pool, v_pool, tables, positions)
 
 
-# The decode attention of grouped heads over a float pool with no index
-# keys (what `PagedKVCache.attention_path` calls "gather"), jitted under a
-# name of its own for the reason the two above are: the step's device
-# trace shows the gather and the attention of every layer apart from the
-# rest.  A Pallas kernel for grouped heads with a window bound would take
-# this function's place (ROADMAP R1).
-@functools.partial(jax.jit, static_argnames=("window",))
-def _window_attend(q, k_pool, v_pool, tables, lengths, *, window):
-    """q (S, H, Dh) of each slot's new token, at position ``lengths[s]``,
-    over the pages of ``tables`` (S, P; ``-1``: none), gathered: a table
-    as wide as the longest sequence, or (``window`` > 0: a sliding-window
-    layer) a RING of P pages in which logical page ``j`` lies in column
-    ``j % P``.  The mask goes by each row's position
-    (:func:`~bigdl_tpu.ops.sparse_attention.ring_positions`), not by its
-    column: ``lengths[s] - window < position <= lengths[s]``, so rows not
-    yet written, and a recycled page's stale rows (a ring that holds
-    ``window`` rows and a page more never shows one inside the window),
-    are hidden, their K masked by a select and their V zeroed.  K and V
-    enter the matmuls as stored, the probabilities in V's dtype; scores,
-    softmax and both sums are float32.  -> (S, H, Dh) in q's dtype."""
+def gathered_attention(q, k_pool, v_pool, tables, lengths, window):
+    """:func:`_window_attend` in XLA: every slot's whole table gathered
+    (``-1``: zeros), then masked by position.  What runs where the kernel
+    does not, and what the kernel is tested against."""
     n_pages, page_size, hkv, dh = k_pool.shape
     s, h, _ = q.shape
     idx = jnp.where(tables < 0, n_pages, tables)
@@ -165,11 +158,48 @@ def _window_attend(q, k_pool, v_pool, tables, lengths, *, window):
     return o.reshape(s, h, dh).astype(q.dtype)
 
 
+# The decode attention of a cache with sliding-window layers (its global
+# layers too), jitted under a name of its own for the reason the two
+# above are: the step's device trace shows every layer's attention apart
+# from the rest, whichever route it takes.  The route is chosen here, while
+# tracing, from what `paged_attention_path` can observe; the test hook
+# `_INTERPRET` is read then too, so a test that turns it clears this
+# function's cache.
+@functools.partial(jax.jit, static_argnames=("window",))
+def _window_attend(q, k_pool, v_pool, tables, lengths, *, window):
+    """q (S, H, Dh) of each slot's new token, at position ``lengths[s]``,
+    over the pages of ``tables`` (S, P; ``-1``: none): a table as wide as
+    the longest sequence, or (``window`` > 0: a sliding-window layer) a
+    RING of P pages in which logical page ``j`` lies in column ``j % P``.
+    The mask goes by each row's position
+    (:func:`~bigdl_tpu.ops.sparse_attention.ring_positions`), not by its
+    column: ``lengths[s] - window < position <= lengths[s]``, so rows not
+    yet written, and a recycled page's stale rows (a ring that holds
+    ``window`` rows and a page more never shows one inside the window),
+    are hidden, their K masked by a select and their V zeroed.  K and V
+    enter the matmuls as stored, the probabilities in V's dtype; scores,
+    softmax and both sums are float32.  On a TPU, where the rows tile
+    (:func:`paged_attention_path`), the kernel reads the live slots'
+    visible pages in place; elsewhere :func:`gathered_attention`.
+    -> (S, H, Dh) in q's dtype."""
+    _, page_size, hkv, dh = k_pool.shape
+    route, _ = paged_attention_path(k_pool.dtype, hkv, dh,
+                                    q_heads=q.shape[1], page_size=page_size)
+    if route == "pallas":
+        return paged_attention(q, k_pool, v_pool, tables, lengths,
+                               window=window)
+    return gathered_attention(q, k_pool, v_pool, tables, lengths, window)
+
+
 def paged_attention_path(pool_dtype, n_heads: int, head_dim: int, *,
+                         q_heads: Optional[int] = None,
+                         page_size: Optional[int] = None,
                          backend: Optional[str] = None) -> Tuple[str, str]:
     """Which route decode attention takes over a pool of this dtype and
-    row geometry, and why: ``("pallas", reason)`` or ``("gather",
-    reason)``.  ``backend`` defaults to ``jax.default_backend()``."""
+    row geometry (``n_heads`` KV heads a row, ``q_heads`` query heads over
+    them, as many by default), and why: ``("pallas", reason)`` or
+    ``("gather", reason)``.  ``backend`` defaults to
+    ``jax.default_backend()``."""
     if backend is None:
         backend = jax.default_backend()
     if backend != "tpu" and not _INTERPRET:
@@ -181,21 +211,53 @@ def paged_attention_path(pool_dtype, n_heads: int, head_dim: int, *,
     if head_dim % 128:
         return "gather", (f"head_dim {head_dim} is not a multiple of 128 "
                           "(one lane tile)")
-    if n_heads % 8:
+    q_heads = q_heads or n_heads
+    if q_heads % n_heads:
+        return "gather", (f"{q_heads} query heads do not group over "
+                          f"{n_heads} KV heads")
+    # a page's rows as (page_size x heads, head_dim) have to be the page
+    # as it lies: whole sublane tiles of heads, or a tile of just the
+    # heads (4 or 2 of them, 32 bits a sublane at least)
+    if n_heads % 8 and (8 % n_heads or n_heads * dtype.itemsize < 4):
         return "gather", (f"n_heads {n_heads} is not a multiple of 8 "
-                          "(one sublane tile)")
+                          "(one sublane tile) nor a tile of its own")
+    if page_size is not None and decode_blocks(
+            page_size, n_heads, head_dim, dtype) * page_size * n_heads % 128:
+        return "gather", (f"a block of pages of {page_size} rows of "
+                          f"{n_heads} heads is no whole lane tile of keys")
+    how = "rows tile" if q_heads == n_heads else \
+        f"{q_heads // n_heads} query heads a KV head, rows tile"
     return "pallas", ("interpret mode" if backend != "tpu"
-                      else "tpu backend, float pool, rows tile")
+                      else "tpu backend") + f", float pool, {how}"
+
+
+# K of one block of pages, at most: V takes as much, and both are
+# double-buffered (4 MiB of VMEM in all); and the pages of one, at most.
+# On a v5e at 28 / 4 heads x 128 over pages of 128 rows (PERF.md, PR 33):
+# 2 / 4 / 8 / 16 pages a block take 173 / 143 / 136 / 152 us a call.
+_BLOCK_BYTES = 2 ** 20
+_BLOCK_MAX_PAGES = 8
+
+
+def decode_blocks(page_size: int, kv_heads: int, head_dim: int,
+                  dtype) -> int:
+    """Pages the decode kernel fetches per wait, from the page's shape: 8
+    pages of 16 rows x 16 heads x 128 in bf16 are 128 keys, 512 KiB each
+    of K and V; 8 pages of 128 rows x 4 heads are 1,024 keys, 1 MiB."""
+    page_bytes = page_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    return max(1, min(_BLOCK_MAX_PAGES, _BLOCK_BYTES // page_bytes))
 
 
 def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
             item_slot, item_blk, k_buf, v_buf, sem, *,
-            page_size, max_pages, sm_scale):
+            page_size, max_pages, sm_scale, window):
     n_slots, n_heads, head_dim = q_ref.shape
-    ppb = k_buf.shape[1]
+    ppb, kv_heads = k_buf.shape[1], k_buf.shape[3]
+    group = n_heads // kv_heads           # query heads a KV head
     block = ppb * page_size               # tokens a block
-    flat = block * n_heads                # the matmuls' flat key axis
+    flat = block * kv_heads               # the matmuls' flat key axis
     max_items = item_slot.shape[0]
+    n_blocks = max_items // n_slots
 
     def live_pages(s):
         # a dead slot (no first page) has no work; a live one attends
@@ -203,28 +265,69 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         return jnp.where(tables_ref[s * max_pages] >= 0,
                          lengths_ref[s] // page_size + 1, 0)
 
+    def rows_seen(s, c):
+        # [lo, hi) of the rows of table column `c` that slot `s`'s query
+        # at t sees through a window: the column holds the LATEST logical
+        # page `c (mod max_pages)` up to t's own (a table in order reads
+        # `c` itself, or below 0 past t's page), and a row's position is
+        # its page's and its own: t - window < position <= t
+        t = lengths_ref[s]
+        top = t // page_size
+        page = top - lax.rem(
+            top - jnp.minimum(c, max_pages - 1) + max_pages, max_pages)
+        lo = jnp.maximum(t - window + 1 - page * page_size, 0)
+        hi = jnp.minimum(t + 1 - page * page_size, page_size)
+        return lo, jnp.where((c < max_pages) & (page >= 0), hi, 0)
+
     def list_slot(s, n):
         def put(b, n):
             item_slot[n] = s
             item_blk[n] = b
             return n + 1
-        return lax.fori_loop(0, pl.cdiv(live_pages(s), ppb), put, n)
+
+        if not window:
+            return lax.fori_loop(0, pl.cdiv(live_pages(s), ppb), put, n)
+
+        # the pages a window shows lie in `seen` columns of the ring from
+        # column `first` on, around its end: the blocks that hold one
+        t = lengths_ref[s]
+        oldest = jnp.maximum(t - window + 1, 0) // page_size
+        seen = jnp.where(tables_ref[s * max_pages] >= 0,
+                         t // page_size + 1 - oldest, 0)
+        first = lax.rem(oldest, max_pages)
+
+        def put_if_seen(b, n):
+            c0, c1 = b * ppb, jnp.minimum((b + 1) * ppb, max_pages)
+            hit = ((c0 < jnp.minimum(first + seen, max_pages)) & (c1 > first)
+                   | (c0 < first + seen - max_pages)) & (seen > 0)
+            return jnp.where(hit, put(b, n), n)
+        return lax.fori_loop(0, n_blocks, put_if_seen, n)
 
     n_items = lax.fori_loop(0, n_slots, list_slot, 0)
 
     def copies(i, buf, act):
         s, b = item_slot[i], item_blk[i]
-        pages = live_pages(s)
-        for j in range(ppb):
+
+        def copy_page(j, _):
             pj = b * ppb + j
             page = tables_ref[s * max_pages + jnp.minimum(pj, max_pages - 1)]
+            if window:
+                lo, hi = rows_seen(s, pj)
+                wanted = lo < hi
+            else:
+                wanted = pj < live_pages(s)
 
             # a -1 entry is never dereferenced
-            @pl.when((pj < pages) & (page >= 0))
+            @pl.when(wanted & (page >= 0))
             def _():
                 for hbm, vmem, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
                     act(pltpu.make_async_copy(
                         hbm.at[page], vmem.at[buf, j], sem.at[which, buf]))
+
+        # (a loop, not `for j in range(ppb)`: the kernel is traced in every
+        # process that serves, and eight copies of this at each of its
+        # three sites were most of a second of it)
+        lax.fori_loop(0, ppb, copy_page, None)
 
     @pl.when(n_items > 0)
     def _():
@@ -242,36 +345,64 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         copies(i, buf, lambda c: c.wait())
         s, b = item_slot[i], item_blk[i]
-        # keys of this block that the slot attends: its first `keys`
-        keys = lengths_ref[s] + 1 - b * block
-        first = b == 0
+        # the slot's first block (block 0 of a table; of a ring the one
+        # where the window's oldest page lies)
+        first = (i == 0) | (item_slot[jnp.maximum(i - 1, 0)] != s)
         m = jnp.where(first, DEFAULT_MASK_VALUE, m)
         l = jnp.where(first, 0.0, l)
         acc = jnp.where(first, 0.0, acc)
+
+        # which keys of the block the slot sees.  Masked probabilities are
+        # exactly 0, but 0 * NaN = NaN: the V rows it does not see (a
+        # recycled page's stale rows, or what an earlier item left in the
+        # buffer) are scrubbed
+        col = lax.broadcasted_iota(jnp.int32, (n_heads, flat), 1)
+        if window:
+            # a row's place in its page decides, page by page
+            at = lax.broadcasted_iota(jnp.int32, (1, flat), 1)
+
+            def page_rows(j, seen):
+                lo, hi = rows_seen(s, b * ppb + j)
+
+                @pl.when((lo > 0) | (hi < page_size))
+                def _():
+                    tok = lax.broadcasted_iota(jnp.int32, v_buf.shape[2:], 0)
+                    v_buf[buf, j] = jnp.where((tok >= lo) & (tok < hi),
+                                              v_buf[buf, j], 0)
+
+                return jnp.where((at >= (j * page_size + lo) * kv_heads)
+                                 & (at < (j * page_size + hi) * kv_heads),
+                                 1, seen)
+
+            seen = lax.fori_loop(0, ppb, page_rows,
+                                 jnp.zeros((1, flat), jnp.int32)) > 0
+        else:
+            # the block's first `keys`; only the slot's last block has
+            # rows past them
+            keys = lengths_ref[s] + 1 - b * block
+            seen = col < keys * kv_heads
+
+            @pl.when(keys < block)
+            def _():
+                shape = v_buf.shape[1:]
+                tok = (lax.broadcasted_iota(jnp.int32, shape, 0) * page_size
+                       + lax.broadcasted_iota(jnp.int32, shape, 1))
+                v_buf[buf] = jnp.where(tok < keys, v_buf[buf], 0)
 
         q = q_ref[s]                                      # (H, Dh)
         k = k_buf[buf].reshape(flat, head_dim)
         s_ = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) * sm_scale
-        col = lax.broadcasted_iota(jnp.int32, (n_heads, flat), 1)
+        # a column's KV head is the row's own
         row = lax.broadcasted_iota(jnp.int32, (n_heads, flat), 0)
-        mask = (lax.rem(col, n_heads) == row) & (col < keys * n_heads)
-        s_ = jnp.where(mask, s_, DEFAULT_MASK_VALUE)
+        if group > 1:
+            row = row // group
+        s_ = jnp.where((lax.rem(col, kv_heads) == row) & seen, s_,
+                       DEFAULT_MASK_VALUE)
         m_new = jnp.maximum(m, s_.max(axis=-1, keepdims=True))
         p = jnp.exp(s_ - m_new)
         alpha = jnp.exp(m - m_new)
         l = alpha * l + p.sum(axis=-1, keepdims=True)
-
-        # masked probabilities are exactly 0, but 0 * NaN = NaN: scrub
-        # the V rows past the slot's length (a recycled page's stale
-        # rows, or what an earlier item left in the buffer); only the
-        # slot's last block has any
-        @pl.when(keys < block)
-        def _():
-            shape = v_buf.shape[1:]
-            tok = (lax.broadcasted_iota(jnp.int32, shape, 0) * page_size
-                   + lax.broadcasted_iota(jnp.int32, shape, 1))
-            v_buf[buf] = jnp.where(tok < keys, v_buf[buf], 0)
 
         v = v_buf[buf].reshape(flat, head_dim)
         acc = alpha * acc + jnp.dot(p.astype(v.dtype), v,
@@ -290,17 +421,24 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         jnp.zeros((n_heads, head_dim), jnp.float32)))
 
 
-def paged_attention(q, k_pool, v_pool, tables, lengths, *,
-                    pages_per_block: int = _PAGES_PER_BLOCK):
+def paged_attention(q, k_pool, v_pool, tables, lengths, *, window: int = 0,
+                    pages_per_block: Optional[int] = None):
     """Decode attention over a page pool, in place.
 
     q (slots, heads, head_dim); k_pool / v_pool (n_pages, page_size,
-    heads, head_dim) as ``PagedKVCache`` lays them out; tables (slots,
-    max_pages) int32, ``-1`` where no page is held; lengths (slots,)
-    int32, each slot's length BEFORE the token just written, which is
-    attended too.  Returns (slots, heads, head_dim) in q's dtype; a
-    dead slot (``tables[s, 0] < 0``) reads zeros."""
+    kv_heads, head_dim) as ``PagedKVCache`` lays them out, ``heads`` a
+    multiple of ``kv_heads``; tables (slots, max_pages) int32, ``-1``
+    where no page is held; lengths (slots,) int32, each slot's length
+    BEFORE the token just written, which is attended too.  ``window`` >
+    0: a sliding-window layer, whose table is a ring (see
+    :func:`_window_attend`) and whose query sees ``window`` keys.
+    ``pages_per_block``: :func:`decode_blocks`'s where not given.
+    Returns (slots, heads, head_dim) in q's dtype; a dead slot
+    (``tables[s, 0] < 0``) reads zeros."""
+    if pages_per_block is None:
+        pages_per_block = decode_blocks(*k_pool.shape[1:], k_pool.dtype)
     return _paged_attention(q, k_pool, v_pool, tables, lengths,
+                            window=int(window),
                             pages_per_block=int(pages_per_block),
                             interpret=_INTERPRET)
 
@@ -308,18 +446,19 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
 # jitted, so that a step which calls it once a layer traces the kernel
 # and lowers it to Mosaic once: sixteen lowerings of the same kernel were
 # 3 s of every DecodeEngine.warmup(), on a warm compile cache too
-@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
-def _paged_attention(q, k_pool, v_pool, tables, lengths, *, pages_per_block,
-                     interpret):
+@functools.partial(jax.jit, static_argnames=("window", "pages_per_block",
+                                             "interpret"))
+def _paged_attention(q, k_pool, v_pool, tables, lengths, *, window,
+                     pages_per_block, interpret):
     n_slots, n_heads, head_dim = q.shape
-    _, page_size, _, _ = k_pool.shape
+    _, page_size, kv_heads, _ = k_pool.shape
     max_pages = tables.shape[1]
     ppb = min(pages_per_block, max_pages)
     max_items = n_slots * -(-max_pages // ppb)
-    buf = pltpu.VMEM((2, ppb, page_size, n_heads, head_dim), k_pool.dtype)
+    buf = pltpu.VMEM((2, ppb, page_size, kv_heads, head_dim), k_pool.dtype)
     kernel = functools.partial(_kernel, page_size=page_size,
                                max_pages=max_pages,
-                               sm_scale=head_dim ** -0.5)
+                               sm_scale=head_dim ** -0.5, window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -610,5 +749,6 @@ def _paged_chunk_attention(q, k_pool, v_pool, table, mask, *, blocks,
 
 
 __all__ = ["paged_attention", "paged_attention_path", "attend_window",
+           "decode_blocks", "gathered_attention",
            "sparse_paged_attention", "paged_chunk_attention",
            "paged_chunk_attention_path", "chunk_blocks"]

@@ -19,16 +19,18 @@ step never sees the allocator — it takes the page tables as a plain
     scatter; dead slots carry table entries of ``-1``, whose writes
     XLA **drops** (out-of-bounds scatter, ``mode="drop"``),
   * **attends** the new token's q to the slot's pages
-    (:meth:`PagedKVCache.attend`), by one of two routes that
+    (:meth:`PagedKVCache.attend`), by one of the routes that
     :func:`bigdl_tpu.ops.paged_attention_path` picks from what the code
     can observe.  On a TPU, for a float pool whose rows tile, a Pallas
-    kernel reads the live pages where they lie, up to each slot's
-    length, in the dtype they are stored in.  A pool that also holds
+    kernel reads the live slots' pages where they lie, up to each slot's
+    length, in the dtype they are stored in: query heads grouped over
+    fewer KV heads or not, and of a sliding-window layer only the pages
+    its window shows.  A pool that also holds
     **index keys** (``index_dim``: one narrow row a token beside K and
     V, in the same pages under the same tables) takes the **sparse**
     route: score the slot's live index keys, take the ``index_top_k``
     best, gather only those K and V rows.  Elsewhere (CPU, an int8
-    pool, query heads grouped over fewer KV heads) the pages are
+    pool, rows that do not tile) the pages are
     **gathered** back into a contiguous window
     ``(slots, heads, max_pages * page_size, head_dim)`` — a fixed-shape
     gather; ``-1`` entries **fill** with zeros (``mode="fill"``),
@@ -51,7 +53,9 @@ is left of them is stale.  Every mask over a ring goes by each row's
 POSITION (``ops/sparse_attention.py`` ``ring_positions``), which the
 column and the slot's length give, so a stale row reads as the position
 it is about to hold, past the newest, and is hidden like an unwritten one
-(masked K, zeroed V).  One ``alloc_for`` grows every kind or none;
+(masked K, zeroed V): in the gathered math and in the decode kernel,
+whose work list names only the ring's blocks that hold a page the window
+shows.  One ``alloc_for`` grows every kind or none;
 ``free_slot`` and eviction return every kind's pages, and a readmission's
 re-prefill and replay rebuild a ring as they rebuild a table.
 
@@ -475,24 +479,20 @@ class PagedKVCache:
     def attention_path(self, backend: Optional[str] = None
                        ) -> Tuple[str, str]:
         """``(route, why)`` of :meth:`attend` for this pool: ``"sparse"``
-        (index keys scored, the best rows gathered), ``"pallas"`` (pages
-        read in place) or ``"gather"`` (window, then float32 math) —
-        for the last two :func:`~bigdl_tpu.ops.paged_attention_path` over
-        the pool's dtype and row geometry."""
+        (index keys scored, the best rows gathered), ``"pallas"`` (the
+        live slots' visible pages read in place: a float pool on a TPU,
+        its query heads grouped over the KV heads or not, its layers
+        windowed or not) or ``"gather"`` (every slot's whole table, then
+        float32 math) — for the last two
+        :func:`~bigdl_tpu.ops.paged_attention_path` over the pool's dtype
+        and row geometry."""
         if self.index_dim:
             return "sparse", (f"the pool holds index keys: top "
                               f"{self.index_top_k} rows a slot")
-        if self.windowed:
-            return "gather", ("layers with a sliding window hold a ring of "
-                              "pages, masked by position: the kernel takes "
-                              "a table in order")
-        if self.q_heads != self.n_heads:
-            return "gather", (f"{self.q_heads} query heads over "
-                              f"{self.n_heads} KV heads: the kernel reads "
-                              "one KV head a query head")
         return paged_attention_path(
             jnp.int8 if self.int8 else self.dtype, self.n_heads,
-            self.head_dim, backend=backend)
+            self.head_dim, q_heads=self.q_heads, page_size=self.page_size,
+            backend=backend)
 
     def chunk_table_width(self, kind: _TableKind,
                           n_pages: Optional[int] = None) -> int:
@@ -537,9 +537,12 @@ class PagedKVCache:
         token is never emitted).  ``index`` = (qi ``(slots, index heads,
         index_dim)``, w ``(slots, index heads)``) of an index-key pool.
         ``layer`` says whose kind ``tables`` is (the first kind's by
-        default): a window layer's is a ring, and the mask is by position
-        (``ops/paged_attention.py`` ``_window_attend``, which grouped
-        heads over a float pool take for a global table too).
+        default): a window layer's is a ring, and the mask is by position.
+        A cache with window layers attends every layer through
+        ``ops/paged_attention.py`` ``_window_attend``, which picks the
+        kernel or the gathered math as :meth:`attention_path` says (one
+        name in the step's device trace for every layer's attention,
+        whichever route it takes).
         Returns ``(slots, heads, 1, head_dim)`` in q's dtype."""
         route, why = self.attention_path()
         if route == "sparse":
@@ -548,12 +551,8 @@ class PagedKVCache:
                 q[:, :, 0], qi, w, layer_pool["k"], layer_pool["v"],
                 self.gather_index(layer_pool, tables), tables, lengths,
                 self.index_top_k)[:, :, None]
-        if route == "pallas":
-            return paged_attention(q[:, :, 0], layer_pool["k"],
-                                   layer_pool["v"], tables,
-                                   lengths)[:, :, None]
-        if jax.default_backend() == "tpu" and not self.int8 \
-                and self.q_heads == self.n_heads and not self.windowed:
+        if jax.default_backend() == "tpu" and route == "gather" \
+                and not self.int8:
             # on the chip the window is never the intended route for a
             # float pool: say so (once per call site)
             warnings.warn("PagedKVCache.attend gathers every slot's "
@@ -563,6 +562,10 @@ class PagedKVCache:
             return _window_attend(
                 q[:, :, 0], layer_pool["k"], layer_pool["v"], tables,
                 lengths, window=self.kind_of(layer).window)[:, :, None]
+        if route == "pallas":
+            return paged_attention(q[:, :, 0], layer_pool["k"],
+                                   layer_pool["v"], tables,
+                                   lengths)[:, :, None]
         k_win, v_win = self.gather_window(layer_pool, tables)
         return attend_window(q, k_win, v_win, lengths)
 

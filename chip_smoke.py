@@ -441,8 +441,14 @@ def _serve_windowed(ctx, s):
     _check(0 < rec.counter_value("attn/rows_attended_window")
            < live * kinds["window"]["layers"] / len(eng.model.blocks),
            "window layers' queries saw every live row")
-    _check(st["attn_route"] == "gather", "decode attention's route is "
-           + st["attn_route"])
+    # the decode kernel takes grouped heads, a ring and a window's bound
+    # on the chip; the gathered math runs elsewhere
+    route = "pallas" if ctx.native else "gather"
+    _check(st["attn_route"] == route
+           and rec.gauge_value("decode/attn_route")
+           == float(route == "pallas"),
+           f"decode attention's route is {st['attn_route']}, not {route}: "
+           + eng.kv.attention_path()[1])
     if ctx.native:
         routes = {k.name: eng.kv.chunk_attention_path(
             s["prefill_chunk"], eng._chunk_pages, layer=k.layers[0])
@@ -450,6 +456,7 @@ def _serve_windowed(ctx, s):
         _check(all(r[0] == "pallas" for r in routes.values()),
                f"a prompt's chunks gather the window on the chip: {routes}")
     return dict(compile_s=warm_s, run_s=run_s, kv_kinds=kinds,
+                attn_route=st["attn_route"],
                 prefill_chunks=int(st["prefill_chunks"]),
                 pages_recycled=int(rec.counter_value("kv/pages_recycled")))
 

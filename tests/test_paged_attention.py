@@ -1,20 +1,27 @@
-"""The paged decode-attention kernel against the gathered-window route.
+"""The paged decode-attention kernel against the gathered routes.
 
 The kernel (``ops/paged_attention.py``) runs here in Pallas interpret
 mode through its ``_INTERPRET`` hook; ``PagedKVCache.gather_window`` +
-``attend_window`` is the reference.  Pinned:
+``attend_window``, and for grouped heads and window layers
+``gathered_attention``, are the reference.  Pinned:
 
-  * kernel == reference over pool dtype, page size and ragged lengths
+  * kernel == reference over pool dtype, page size (8, 16, 128), query /
+    KV heads (8 / 8, 8 / 2, 28 / 4) and ragged lengths: a table in order
     (a dead slot, 1 key, a page less one, a page, a page and one, the
-    whole context)
-  * non-finite rows past a slot's length (the tail of its last page, a
-    page it holds but has not reached, every free page) never leak
+    whole context) and a ring under a window (before the window is full,
+    a page's last row, a dead slot between live ones, inside the first
+    lap, past one lap and past several)
+  * non-finite rows no query may see (the tail of a slot's last page, a
+    page it holds but has not reached, a recycled page's rows behind the
+    window and past the newest row, every free page) never leak
   * ``paged_attention_path`` picks the route from backend, pool dtype
-    and row geometry, and says why
+    and row geometry (grouped heads and page size too), and says why;
+    ``decode_blocks`` picks the pages a block from the page's shape
   * one ``DecodeEngine`` stream through the kernel emits the greedy
     tokens of the gather route, eviction and readmission included
   * the kernel compiles under Mosaic for a described v5e at the serve
-    cell's widths (no chip needed)
+    cell's widths and at the mixed cell's two kinds of layer (no chip
+    needed)
   * the chunk kernel (``paged_chunk_attention``) == ``gather_window`` +
     ``sparse_attention.attend`` over grouped and equal heads, an index
     mask and the causal mask alone, the first, a middle and the last
@@ -35,90 +42,147 @@ from bigdl_tpu.ops import paged_attention_mod as pa
 from bigdl_tpu.ops import paged_attention_path
 from bigdl_tpu.ops import sparse_attention as sa
 from bigdl_tpu.serving import DecodeEngine, ModelRegistry, PagedKVCache
+from bigdl_tpu.serving import kvcache
 
 H, D, CTX = 8, 128, 64
+# query heads / KV heads: equal, grouped, and the mixed cell's 28 / 4
+HEADS = [(H, H), (8, 2), (28, 4)]
+RING = 5                 # a window of 3 pages, a page of slack and one more
 
 
 @pytest.fixture()
 def interpret(monkeypatch):
+    """The kernels interpreted on the CPU.  `_window_attend` reads the
+    hook while it traces, so its traces are dropped around the test."""
+    pa._window_attend.clear_cache()
     monkeypatch.setattr(pa, "_INTERPRET", True)
+    yield
+    pa._window_attend.clear_cache()
 
 
-def _lengths(page):
+def _lengths(page, kind="table"):
     # slot 0 is dead (no page, length 0); the rest are live
-    return [0, 0, 1, page - 1, page, page + 1, CTX - 1]
+    if kind == "table":
+        return [0, 0, 1, page - 1, page, page + 1, max(CTX, 4 * page) - 1]
+    # a ring of RING pages, a window of three: before the window is full,
+    # the last row of a page, a dead slot between live ones, inside the
+    # first lap past the window, the lap's last row, past one lap (and at
+    # a page's last row there), past several
+    lap = RING * page
+    return [0, 1, 3 * page - 2, 2 * page - 1, 0, 3 * page + 3, lap - 1,
+            lap + 2, 2 * lap - page - 1, 3 * lap + 2 * page + 5]
 
 
-def _case(dtype, page, poison=False, pages_per_block=2):
-    """(kernel output, reference, live mask) for one pool: slot ``s``
-    holds ``_lengths(page)[s]`` tokens and attends one more."""
-    lens = _lengths(page)
+def _case(dtype, page, poison=False, pages_per_block=2, heads=(H, H),
+          kind="table"):
+    """(kernel output, reference) for one pool: slot ``s`` holds
+    ``_lengths(page, kind)[s]`` tokens and attends one more.  ``kind``
+    ``"table"``: no window, a table as wide as the context; ``"ring"``: a
+    window of three pages over a ring of RING.  ``poison``: every row no
+    query may see is NaN in K and V."""
+    lens = _lengths(page, kind)
     n_slots = len(lens)
-    kv = PagedKVCache(["a"], n_heads=H, head_dim=D,
-                      n_pages=n_slots * CTX // page + 2, page_size=page,
-                      n_slots=n_slots, max_context=CTX, dtype=dtype)
+    dead = [s for s, n in enumerate(lens) if n == 0 and s in (0, 4)]
+    q_heads, kv_heads = heads
+    window = 3 * page if kind == "ring" else 0
+    ctx = max(lens) + 1
+    width = RING if window else ctx // page
+    kv = PagedKVCache(["a"], n_heads=kv_heads, q_heads=q_heads, head_dim=D,
+                      n_pages=n_slots * width + 2, page_size=page,
+                      n_slots=n_slots, max_context=ctx, dtype=dtype,
+                      windows=[window], ring_slack=page)
+    assert kv.kinds[0].width == width
     rng = np.random.default_rng(page)
-    shape = (kv.n_pages, page, H, D)
+    shape = (kv.n_pages, page, kv_heads, D)
     k = rng.standard_normal(shape).astype(np.float32)
     v = rng.standard_normal(shape).astype(np.float32)
     for s, n in enumerate(lens):
-        if s:
-            # slot 3 also holds a page it has not reached yet
-            assert kv.alloc_for(s, n + 1 + (page if s == 3 else 0))
-    if poison:
-        for arr in (k, v):
-            arr[kv._free] = np.nan                       # freed pages
-            for s, n in enumerate(lens):
-                for j, p in enumerate(kv.tables[s]):
-                    if p >= 0:                           # rows past n
-                        arr[p, max(n + 1 - j * page, 0):] = np.nan
+        if s not in dead:
+            # slot 3 of a table also holds a page it has not reached yet
+            assert kv.alloc_for(s, n + 1 + (page if s == 3 and not window
+                                            else 0))
     tables = jnp.asarray(kv.tables)
     lengths = jnp.asarray(np.asarray(lens, np.int32))
+    if poison:
+        # by position, as the cache's masks go: rows past the newest, a
+        # recycled page's rows behind the window, pages never reached
+        pos = np.asarray(sa.ring_positions(lengths // page, kv.tables.shape[1],
+                                           page)).reshape(n_slots, -1, page)
+        at = np.asarray(lens)[:, None, None]
+        hidden = (pos < 0) | (pos > at) | ((pos <= at - window) & (window > 0))
+        for arr in (k, v):
+            arr[kv._free] = np.nan                       # freed pages
+            for s, c in zip(*np.nonzero(kv.tables >= 0)):
+                arr[kv.tables[s, c], hidden[s, c]] = np.nan
     k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
-    q = jnp.asarray(rng.standard_normal((n_slots, H, 1, D)), dtype)
-    k_win, v_win = kv.gather_window({"k": k, "v": v}, tables)
-    ref = pa.attend_window(q, k_win, v_win, lengths)[:, :, 0]
+    q = jnp.asarray(rng.standard_normal((n_slots, q_heads, 1, D)), dtype)
+    if heads == (H, H) and not window:
+        k_win, v_win = kv.gather_window({"k": k, "v": v}, tables)
+        ref = pa.attend_window(q, k_win, v_win, lengths)[:, :, 0]
+    else:
+        ref = pa.gathered_attention(q[:, :, 0], k, v, tables, lengths,
+                                    window)
     out = pa.paged_attention(q[:, :, 0], k, v, tables, lengths,
-                             pages_per_block=pages_per_block)
+                             window=window, pages_per_block=pages_per_block)
     return (np.asarray(out, np.float32), np.asarray(ref, np.float32))
 
 
 _cached_case = functools.lru_cache(maxsize=None)(_case)
+_SLOTS = [("table", s) for s in range(7)] + [("ring", s) for s in range(10)]
 
 
-@pytest.mark.parametrize("slot", range(7))
-@pytest.mark.parametrize("page", [8, 16])
-@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+@pytest.mark.parametrize("kind,slot", _SLOTS)
+@pytest.mark.parametrize("page,heads", [(p, h) for h in HEADS
+                                        for p in (8, 16)] + [(128, (28, 4))])
+@pytest.mark.parametrize("dtype,tol", [("float32", 3e-6),
                                        ("bfloat16", 1.6e-2)])
-def test_kernel_matches_gathered_window(interpret, dtype, tol, page, slot):
-    out, ref = _cached_case(dtype, page)
+def test_kernel_matches_gathered_window(interpret, dtype, tol, page, heads,
+                                        kind, slot):
+    out, ref = _cached_case(dtype, page, heads=heads, kind=kind)
+    length = _lengths(page, kind)[slot]
     assert np.isfinite(out).all()
-    if slot == 0:
-        assert not out[0].any()          # a dead slot reads zeros
+    if not length and slot in (0, 4):
+        assert not out[slot].any()       # a dead slot reads zeros
     else:
-        assert np.abs(out[slot] - ref[slot]).max() <= tol, \
-            f"length {_lengths(page)[slot]}"
+        assert np.abs(out[slot] - ref[slot]).max() <= tol, f"length {length}"
 
 
+@pytest.mark.parametrize("heads,kind", [((H, H), "table"), ((28, 4), "ring")])
 @pytest.mark.parametrize("pages_per_block", [1, 3, 8])
 def test_kernel_block_size_does_not_change_the_result(interpret,
-                                                      pages_per_block):
-    out, ref = _case("float32", 8, pages_per_block=pages_per_block)
-    assert np.abs(out[1:] - ref[1:]).max() <= 2e-6
+                                                      pages_per_block, heads,
+                                                      kind):
+    out, ref = _case("float32", 8, pages_per_block=pages_per_block,
+                     heads=heads, kind=kind)
+    live = np.asarray(_lengths(8, kind)) > 0
+    assert np.abs(out[live] - ref[live]).max() <= 3e-6
+    # and the block the shape gives: pages of 16 rows of 16 heads in bf16
+    # come eight at a time, pages of 128 rows of 4 heads too (1 MiB of K)
+    assert pa.decode_blocks(16, 16, 128, "bfloat16") == 8
+    assert pa.decode_blocks(128, 4, 128, "bfloat16") == 8
+    assert pa.decode_blocks(128, 16, 128, "float32") == 1
 
 
-@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+@pytest.mark.parametrize("heads,kind", [((H, H), "table"), ((8, 2), "table"),
+                                        ((8, 2), "ring"), ((28, 4), "ring")])
+@pytest.mark.parametrize("dtype,tol", [("float32", 3e-6),
                                        ("bfloat16", 1.6e-2)])
-def test_non_finite_rows_past_the_length_do_not_leak(interpret, dtype,
-                                                     tol):
+def test_non_finite_rows_past_the_length_do_not_leak(interpret, dtype, tol,
+                                                     heads, kind):
     """NaN in the masked tail of a slot's last page, in a page the slot
-    holds but has not reached, and in every free page: 0 * NaN would
-    be NaN, so both routes scrub the masked V rows."""
-    out, ref = _case(dtype, 8, poison=True)
-    clean, _ = _cached_case(dtype, 8)
+    holds but has not reached, in a recycled page's rows behind the
+    window and past the newest row, and in every free page: 0 * NaN
+    would be NaN, so both routes scrub the masked V rows."""
+    out, ref = _case(dtype, 8, poison=True, heads=heads, kind=kind)
+    clean, _ = _cached_case(dtype, 8, heads=heads, kind=kind)
+    live = np.asarray(_lengths(8, kind)) > 0
     assert np.isfinite(ref).all() and np.isfinite(out).all()
-    assert np.abs(out[1:] - ref[1:]).max() <= tol
+    assert np.abs(out[live] - ref[live]).max() <= tol
     assert np.array_equal(out, clean)    # the poison changed nothing
+
+
+_GROUPED = dict(pool_dtype="bfloat16", n_heads=4, q_heads=28, head_dim=128,
+                page_size=128, backend="tpu")
 
 
 @pytest.mark.parametrize("kw,route,why", [
@@ -134,16 +198,33 @@ def test_non_finite_rows_past_the_length_do_not_leak(interpret, dtype,
      "pallas", "tpu backend"),
     (dict(pool_dtype="float32", n_heads=8, head_dim=128, backend="tpu"),
      "pallas", "tpu backend"),
+    # grouped heads: the mixed cell's 28 / 4 and chip_smoke.py's 14 / 2
+    (_GROUPED, "pallas", "tpu backend, float pool, 7 query heads a KV head"),
+    (dict(_GROUPED, n_heads=2, q_heads=14), "pallas",
+     "7 query heads a KV head, rows tile"),
+    (dict(_GROUPED, backend="cpu"), "gather", "backend 'cpu' is not tpu"),
+    (dict(_GROUPED, pool_dtype="int8"), "gather", "int8 is not a float"),
+    (dict(_GROUPED, q_heads=30), "gather",
+     "30 query heads do not group over 4 KV heads"),
+    # one bfloat16 head a row is half a sublane's 32 bits; one float32 is one
+    (dict(_GROUPED, n_heads=1, q_heads=7), "gather",
+     "n_heads 1 is not a multiple of 8 (one sublane tile) nor a tile"),
+    (dict(_GROUPED, n_heads=1, q_heads=7, pool_dtype="float32"), "pallas",
+     "7 query heads a KV head"),
+    (dict(_GROUPED, n_heads=2, q_heads=8, page_size=4), "gather",
+     "a block of pages of 4 rows of 2 heads is no whole lane tile"),
 ])
 def test_paged_attention_path_says_which_route_and_why(kw, route, why):
     got, reason = paged_attention_path(**kw)
     assert got == route and why in reason, (got, reason)
 
 
-def test_cache_routes_by_what_it_holds(interpret):
+def test_cache_routes_by_what_it_holds(interpret, monkeypatch):
     """``PagedKVCache.attention_path`` is ``paged_attention_path`` over
     the pool it built: the hook stands in for the TPU backend, an int8
-    pool and the tiny preset's head_dim 64 stay on the window."""
+    pool and the tiny preset's head_dim 64 stay on the window; grouped
+    heads and window layers take the kernel where the rows tile, and a
+    cache with window layers reaches it through ``_window_attend``."""
     mk = lambda **kw: PagedKVCache(["a"], n_pages=4, page_size=8,
                                    n_slots=2, max_context=16, **kw)
     assert mk(n_heads=H, head_dim=D).attention_path()[0] == "pallas"
@@ -152,6 +233,47 @@ def test_cache_routes_by_what_it_holds(interpret):
     assert mk(n_heads=2, head_dim=64).attention_path()[0] == "gather"
     assert mk(n_heads=H, head_dim=D).attention_path(backend="tpu") \
         == paged_attention_path("float32", H, D, backend="tpu")
+    grouped = mk(n_heads=4, q_heads=28, head_dim=D)
+    ring = mk(n_heads=4, q_heads=28, head_dim=D, windows=[8], ring_slack=0)
+    for kv in (grouped, ring):
+        assert kv.attention_path() == paged_attention_path(
+            "float32", 4, D, q_heads=28, page_size=8)
+        assert kv.attention_path()[0] == "pallas"
+    assert mk(n_heads=4, q_heads=28, head_dim=64, windows=[8]
+              ).attention_path()[0] == "gather"
+    # which entry `attend` calls, and what that entry runs
+    calls = []
+    for name in ("_window_attend", "paged_attention"):
+        real = getattr(kvcache, name)
+        monkeypatch.setattr(kvcache, name, lambda *a, _n=name, _f=real, **kw:
+                            calls.append(_n) or _f(*a, **kw))
+    rng = np.random.default_rng(0)
+    k, v = (jnp.asarray(rng.standard_normal((4, 8, 4, D)), jnp.float32)
+            for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((2, 28, 1, D)), jnp.float32)
+    lengths = jnp.asarray([11, 5])
+    for kv, entry, window in ((grouped, "paged_attention", 0),
+                              (ring, "_window_attend", 8)):
+        assert kv.alloc_for(0, 12) and kv.alloc_for(1, 6)
+        tables = jnp.asarray(kv.tables)
+        got = kv.attend({"k": k, "v": v}, tables, lengths, q, layer="a")
+        assert calls == [entry]
+        want = pa.gathered_attention(q[:, :, 0], k, v, tables, lengths,
+                                     window)
+        assert np.abs(np.asarray(got[:, :, 0]) - np.asarray(want)).max() \
+            <= 3e-6
+        calls.clear()
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda *a: pa._window_attend(*a, window=8))(
+            q[:, :, 0], k, v, tables, lengths))
+    monkeypatch.setattr(pa, "_INTERPRET", False)
+    pa._window_attend.clear_cache()
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda *a: pa._window_attend(*a, window=8))(
+            q[:, :, 0], k, v, tables, lengths))
+    for kv in (grouped, ring):
+        assert kv.attention_path() == ("gather", "backend 'cpu' is not tpu")
+        assert kv.attention_path(backend="tpu")[0] == "pallas"
 
 
 def _stream(lm, prompts, **kw):
@@ -408,21 +530,33 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("dtype,heads", [("bfloat16", 16), ("bfloat16", 8),
-                                         ("float32", 8)])
-def test_kernel_compiles_for_v5e_without_a_pool_copy(one_chip, dtype,
-                                                     heads):
+@pytest.mark.parametrize("dtype,heads,kv_heads,page,cols,window", [
+    ("bfloat16", 16, 16, 16, 32, 0), ("bfloat16", 8, 8, 16, 32, 0),
+    ("float32", 8, 8, 16, 32, 0),
+    # the mixed cell: a global layer's table, a window layer's ring
+    ("bfloat16", 28, 4, 128, 128, 0), ("bfloat16", 28, 4, 128, 37, 4096),
+    # chip_smoke.py's windowed stage (4 slots there)
+    ("bfloat16", 14, 2, 128, 5, 256),
+])
+def test_kernel_compiles_for_v5e_without_a_pool_copy(one_chip, dtype, heads,
+                                                     kv_heads, page, cols,
+                                                     window):
     """32 slots x 512 tokens, 1,024 pages of 16 rows of ``heads`` x 128
-    (the serve cell's pool, and chip_smoke.py's 8 heads): Mosaic takes
-    the kernel as written, and XLA hands it the pool without a relayout
-    copy (no temporaries at all)."""
+    (the serve cell's pool, and chip_smoke.py's 8 heads); 32 slots of 28 /
+    4 heads x 128 over pages of 128 rows, a table of 128 columns and a
+    ring of 37 under a window of 4,096 (the mixed cell's two kinds of
+    layer): Mosaic takes the kernel as written, and XLA hands it the pool
+    without a relayout copy (no temporaries at all)."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
 
-    pool = sds((1024, 16, heads, 128), dtype)
-    compiled = jax.jit(pa.paged_attention).lower(
+    assert paged_attention_path(dtype, kv_heads, 128, q_heads=heads,
+                                page_size=page, backend="tpu")[0] == "pallas"
+    pool = sds((32 * cols, page, kv_heads, 128), dtype)
+    compiled = jax.jit(functools.partial(
+        pa.paged_attention, window=window)).lower(
         sds((32, heads, 128), dtype), pool, pool,
-        sds((32, 32), "int32"), sds((32,), "int32")).compile()
+        sds((32, cols), "int32"), sds((32,), "int32")).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 

@@ -477,6 +477,56 @@ def test_engine_evicts_and_replays_with_a_ring(f32):
     assert gaps.max() < F32_TOL, gaps
 
 
+def test_engine_stream_through_the_decode_kernel_is_the_gather_routes(
+        monkeypatch):
+    """A model of two kinds of layer at heads of 128 (pages of 16, window
+    64, a ring of 4 + 2 + 1 pages, 7 query heads a KV head), prompts
+    under a chunk and past the ring in one queue, pools small enough to
+    evict: every decode step through the Pallas decode kernel
+    (interpreted; `_window_attend` picks it) streams the gather route's
+    tokens, token for token, and the engine says which route it took."""
+    from bigdl_tpu.ops import paged_attention_mod as pa
+    wide = dict(TOY, head_dim=128, num_hidden_layers=4,
+                sliding_window_layout=[0, 1, 1, 1], rope_layout=[0, 1, 1, 1],
+                sliding_window_size=64, max_position_embeddings=256)
+    rng = np.random.default_rng(7)
+    lens = (150, 9, 70, 200)
+    prompts = [rng.integers(0, 128, n).astype(np.int32) for n in lens]
+
+    def serve():
+        eng = engine_for(model, slots=3, page_size=16, max_context=256,
+                         max_prompt=224, prefill_chunk=32, max_new_tokens=24,
+                         pool_pages={"global": 22, "window": 14})
+        try:
+            streams = [eng.stream("lm", p, max_new_tokens=24)
+                       for p in prompts]
+            outs = [np.asarray(s.result(600)) for s in streams]
+            eng.kv.check_invariants()
+            return outs, eng.stats(), eng.recorder
+        finally:
+            eng.shutdown()
+
+    with jax.default_matmul_precision("highest"):
+        model, params = build(wide)
+        model.set_params(params, {})
+        want, st, rec = serve()
+        assert st["attn_route"] == "gather"
+        assert rec.gauge_value("decode/attn_route", -1.0) == 0.0
+        monkeypatch.setattr(pa, "_INTERPRET", True)
+        pa._window_attend.clear_cache()   # it reads the hook as it traces
+        try:
+            got, st, rec = serve()
+        finally:
+            pa._window_attend.clear_cache()
+    assert st["attn_route"] == "pallas"
+    assert rec.gauge_value("decode/attn_route", -1.0) == 1.0
+    assert st["kv_kinds"]["window"]["pages_per_slot"] == 7
+    assert rec.counter_value("kv/pages_recycled") > 0
+    assert st["evictions"] > 0 and st["recompiles"] == 0
+    for a, b, n in zip(want, got, lens):
+        assert len(a) == n + 24 and np.array_equal(a, b)
+
+
 # --------------------------------------------------------------------- #
 # a model of one kind of layer is what it was
 # --------------------------------------------------------------------- #
