@@ -25,7 +25,8 @@ TPU-first design decisions:
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
+import math
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,8 +81,38 @@ class TransformerConfig:
     # (a router placed before attention) rather than the experts' own
     moe_activation: str = "silu"
     moe_router_pre_attention: bool = False
+    # latent attention (kv_lora_rank > 0): a token caches ONE row of
+    # kv_lora_rank + qk_rope_dim columns (a normed latent, then the one
+    # rotated key every head shares) from which each head's key and value
+    # are up-projected; queries go through a low-rank pair (q_lora_rank)
+    # to n_heads x (qk_nope_dim + qk_rope_dim), values are v_head_dim wide
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (latent attention's rope): {"factor", "original_max_position_
+    # embeddings", "beta_fast", "beta_slow", "mscale", "mscale_all_dim"}
+    rope_scaling: Optional[dict] = None
+    # the first dense_layers blocks of a model of routed experts keep a
+    # dense SwiGLU, dense_d_ff wide
+    dense_layers: int = 0
+    dense_d_ff: Optional[int] = None
+    # RoutedExperts' routing (its constructor's arguments of these names):
+    # how the router scores, group-limited selection, the gates' scale, a
+    # selection-only bias, which experts this chip holds (first, count)
+    # of the moe_experts the router scores, and a shared expert's width
+    moe_scoring: str = "softmax"
+    moe_groups: int = 0
+    moe_top_groups: int = 0
+    moe_routed_scale: float = 1.0
+    moe_router_bias: bool = False
+    moe_held: Optional[Tuple[int, int]] = None
+    moe_shared_d_ff: int = 0
 
     def __post_init__(self):
+        if self.head_dim is None and self.kv_lora_rank:
+            self.head_dim = self.qk_nope_dim + self.qk_rope_dim
         if self.head_dim is None:
             assert self.d_model % self.n_heads == 0
             self.head_dim = self.d_model // self.n_heads
@@ -93,6 +124,9 @@ class TransformerConfig:
         assert not (self.windows and any(self.windows)
                     and self.index_heads), \
             "a sliding window and a learned selection do not combine"
+        assert not (self.kv_lora_rank and (
+            self.index_heads or self.windows and any(self.windows))), \
+            "latent attention is global and dense"
 
     def layer_window(self, i: int) -> int:
         return int(self.windows[i]) if self.windows else 0
@@ -101,10 +135,44 @@ class TransformerConfig:
         return bool(self.rope_layers[i]) if self.rope_layers else True
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """Rotary position embedding. x: (B, H, S, D), positions: (S,) global."""
+def rope_frequencies(dim: int, theta: float, scaling: Optional[dict] = None):
+    """The ``dim / 2`` inverse frequencies of a rotary embedding:
+    ``theta ** (-2j / dim)``, or under YaRN (``scaling``, the published
+    ``rope_scaling`` group) ``(1 - g_j) / (factor theta_j) + g_j /
+    theta_j`` with ``g`` one minus the linear ramp between the correction
+    dims of ``beta_fast`` and ``beta_slow`` rotations over the original
+    length: fast frequencies keep their own, slow ones are interpolated."""
+    freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if not scaling:
+        return freqs
+    orig = scaling["original_max_position_embeddings"]
+
+    def correction_dim(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / scaling["factor"] * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_mscale(scaling: Optional[dict]) -> float:
+    """YaRN's ``m = 0.1 mscale_all_dim ln(factor) + 1``, whose square
+    scales the softmax (1 with no scaling).  cos and sin stay unscaled:
+    that holds where ``mscale == mscale_all_dim``, all this supports."""
+    if not scaling or scaling["factor"] <= 1:
+        return 1.0
+    assert scaling["mscale"] == scaling["mscale_all_dim"], scaling
+    return 0.1 * scaling["mscale_all_dim"] * math.log(scaling["factor"]) + 1.0
+
+
+def apply_rope(x, positions, theta: float = 10000.0, freqs=None):
+    """Rotary position embedding. x: (B, H, S, D), positions: (S,) global;
+    ``freqs`` (D/2,) in place of the plain ``theta`` ladder."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # (S, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     x1, x2 = x[..., 0::2], x[..., 1::2]
@@ -115,14 +183,15 @@ def apply_rope(x, positions, theta: float = 10000.0):
     return y.astype(x.dtype)
 
 
-def apply_rope_rows(x, positions, theta: float = 10000.0):
+def apply_rope_rows(x, positions, theta: float = 10000.0, freqs=None):
     """:func:`apply_rope` with a PER-ROW position: x (B, H, 1, D),
     positions (B,) — the continuous-batching decode shape, where every
     batch row (slot) sits at its own global offset.  Same op sequence as
     :func:`apply_rope` (freqs → angles → cos/sin → rotate) so a row here
     is bitwise the row ``apply_rope`` would produce at that position."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # (B, D/2)
     cos = jnp.cos(angles)[:, None, None, :]          # (B, 1, 1, D/2)
     sin = jnp.sin(angles)[:, None, None, :]
@@ -196,6 +265,9 @@ class MultiHeadAttention(Module):
     rotates neither q nor k: what ``TransformerConfig.windows`` /
     ``rope_layers`` say of the layer, handed over by the block.
     """
+
+    latent = False        # per-head K and V rows (LatentAttention: one row)
+    rope_freqs = None     # the plain theta ladder
 
     def __init__(self, cfg: TransformerConfig, name=None, window: int = 0,
                  rope: bool = True):
@@ -369,26 +441,206 @@ def _rms(y, gain, eps=1e-6):
             * gain).astype(y.dtype)
 
 
+class LatentAttention(Module):
+    """Causal self-attention whose cache is ONE row a token (multi-head
+    latent attention).  With ``h`` the block's normed input:
+
+        c_q = RMSNorm(h W_qa)                      (q_lora_rank)
+        [q_n,i ; q_r,i] = c_q W_qb                 (qk_nope + qk_rope a head)
+        [c ; k_r] = h W_kva;  c <- RMSNorm(c)      (kv_lora_rank + qk_rope)
+        k_r <- rope(k_r)  (one a token, every head's);  q_r,i <- rope(q_r,i)
+        [k_n,i ; v_i] = c W_kvb                    (qk_nope + v_head a head)
+        score_i(t, s) = (q_n,i(t) . k_n,i(s) + q_r,i(t) . k_r(s)) * scale
+        a_i = softmax_s(score_i) v_i;  out = concat_i(a_i) W_o
+
+    ``scale`` is ``(qk_nope + qk_rope) ** -0.5`` times the square of
+    YaRN's ``m`` (:func:`yarn_mscale`); the rotation is YaRN's
+    (:func:`rope_frequencies`).  What is cached is the row ``[c ; k_r]``
+    (:meth:`latent_rows`), never a per-head key or value.  The full
+    forward and the static cache up-project every cached row
+    (:meth:`attend_rows`); through a paged cache a decode step is
+    ABSORBED: with ``W_kvb = [W_uk,i ; W_uv,i]`` the query ``q~_i =
+    [q_n,i W_uk,i^T ; q_r,i]`` (:meth:`absorb`) scores against the row
+    itself, the cache returns ``o~_i = softmax . c`` and ``a_i = o~_i
+    W_uv,i`` (:meth:`up_values`); a prompt chunk hands the cache its
+    un-absorbed queries and both halves of ``W_kvb``
+    (:meth:`up_weights`), and the cache's route picks the formula.
+
+    tp layout: heads column-sharded on ``wq_b`` / ``wkv_b``, ``wo``
+    row-sharded; the two down-projections are replicated.
+    """
+
+    latent = True
+    window, rope, sparse = 0, True, False
+
+    def __init__(self, cfg: TransformerConfig, name=None):
+        super().__init__(name=name)
+        self.cfg = cfg
+        self.pspec = {"wq_b": P(None, "tp"), "wkv_b": P(None, "tp"),
+                      "wo": P("tp", None)}
+        self.attention_fn = None          # (the spmd trainer's hook: unused)
+        self.sm_scale = float((cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+                              * yarn_mscale(cfg.rope_scaling) ** 2)
+
+    @property
+    def rope_freqs(self):
+        cfg = self.cfg
+        return rope_frequencies(cfg.qk_rope_dim, cfg.rope_theta,
+                                cfg.rope_scaling)
+
+    @property
+    def row_dim(self):
+        """Columns of the cached row: the latent, then the rope key."""
+        return self.cfg.kv_lora_rank + self.cfg.qk_rope_dim
+
+    def init(self, rng):
+        cfg = self.cfg
+        ks = jax.random.split(rng, 5)
+        h, qd = cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim
+        mk = lambda k, m, n: jax.random.normal(k, (m, n), jnp.float32) \
+            * m ** -0.5
+        return {self.name: {
+            "wq_a": mk(ks[0], cfg.d_model, cfg.q_lora_rank),
+            "q_norm": jnp.ones((cfg.q_lora_rank,), jnp.float32),
+            "wq_b": mk(ks[1], cfg.q_lora_rank, h * qd),
+            "wkv_a": mk(ks[2], cfg.d_model, self.row_dim),
+            "kv_norm": jnp.ones((cfg.kv_lora_rank,), jnp.float32),
+            "wkv_b": mk(ks[3], cfg.kv_lora_rank,
+                        h * (cfg.qk_nope_dim + cfg.v_head_dim)),
+            "wo": mk(ks[4], h * cfg.v_head_dim, cfg.d_model)}}
+
+    # -- projections ------------------------------------------------------ #
+    def queries(self, params, x, rope):
+        """x (B, S, d_model) -> q_n (B, H, S, qk_nope) and q_r (B, H, S,
+        qk_rope), the latter rotated by ``rope``."""
+        cfg, p, dt = self.cfg, self.own(params), x.dtype
+        b, s, _ = x.shape
+        c_q = _rms(jnp.dot(x, p["wq_a"].astype(dt)), p["q_norm"])
+        q = jnp.dot(c_q, p["wq_b"].astype(dt)).reshape(
+            b, s, cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim)
+        q = jnp.transpose(q, (0, 2, 1, 3))
+        return q[..., :cfg.qk_nope_dim], rope(q[..., cfg.qk_nope_dim:])
+
+    def latent_rows(self, params, x, rope):
+        """x (B, S, d_model) -> the rows a cache holds, (B, S, kv_lora_rank
+        + qk_rope): the latent under its norm, then the ONE rotated key."""
+        p, dt, rank = self.own(params), x.dtype, self.cfg.kv_lora_rank
+        kv = jnp.dot(x, p["wkv_a"].astype(dt))
+        c = _rms(kv[..., :rank], p["kv_norm"])
+        return jnp.concatenate([c, rope(kv[..., rank:][:, None])[:, 0]], -1)
+
+    def up_weights(self, params, dtype):
+        """``W_kvb`` by head: W_uk (rank, H, qk_nope), W_uv (rank, H,
+        v_head)."""
+        cfg = self.cfg
+        w = self.own(params)["wkv_b"].astype(dtype).reshape(
+            cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim + cfg.v_head_dim)
+        return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
+
+    def absorb(self, params, q_n, q_r):
+        """The query that scores against a cached row as it lies:
+        ``[q_n,i W_uk,i^T ; q_r,i]`` (B, H, S, rank + qk_rope)."""
+        w_uk, _ = self.up_weights(params, q_n.dtype)
+        return jnp.concatenate(
+            [jnp.einsum("bhsn,rhn->bhsr", q_n, w_uk), q_r], -1)
+
+    def up_values(self, params, o):
+        """An absorbed result ``softmax . c`` (B, H, S, rank) -> a head's
+        values (B, H, S, v_head)."""
+        _, w_uv = self.up_weights(params, o.dtype)
+        return jnp.einsum("bhsr,rhv->bhsv", o, w_uv)
+
+    def project_out(self, params, o):
+        b, _, s, _ = o.shape
+        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, -1)
+        return jnp.dot(o, self.own(params)["wo"].astype(o.dtype))
+
+    def attend_rows(self, params, q_n, q_r, rows, mask):
+        """Un-absorbed attention of queries (B, H, S, .) over cached rows
+        (B, L, rank + qk_rope), every row's key and value up-projected;
+        ``mask`` (B, S, L) bool.  Scores and softmax float32."""
+        rank = self.cfg.kv_lora_rank
+        w_uk, w_uv = self.up_weights(params, rows.dtype)
+        c, k_r = rows[..., :rank], rows[..., rank:]
+        k_n = jnp.einsum("blr,rhn->bhln", c, w_uk)
+        v = jnp.einsum("blr,rhv->bhlv", c, w_uv)
+        s_ = (jnp.einsum("bhsn,bhln->bhsl", q_n, k_n,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhsr,blr->bhsl", q_r, k_r,
+                           preferred_element_type=jnp.float32)) \
+            * self.sm_scale
+        w_ = jax.nn.softmax(
+            jnp.where(mask[:, None], s_, DEFAULT_MASK_VALUE), axis=-1)
+        return jnp.einsum("bhsl,bhlv->bhsv", w_.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32
+                          ).astype(q_n.dtype)
+
+    # -- the entry points that own their keys -------------------------------- #
+    def apply(self, params, x, ctx):
+        s = x.shape[1]
+        positions = jnp.arange(s)
+        rope = lambda t: apply_rope(t, positions, freqs=self.rope_freqs)
+        q_n, q_r = self.queries(params, x, rope)
+        rows = self.latent_rows(params, x, rope)
+        mask = positions[None, :, None] >= positions[None, None, :]
+        return self.project_out(
+            params, self.attend_rows(params, q_n, q_r, rows, mask))
+
+    def apply_cached(self, params, x, cache, start):
+        """:meth:`MultiHeadAttention.apply_cached` over a static cache of
+        latent rows ``{"latent": (B, L, rank + qk_rope)}``."""
+        s = x.shape[1]
+        positions = start + jnp.arange(s)
+        rope = lambda t: apply_rope(t, positions, freqs=self.rope_freqs)
+        q_n, q_r = self.queries(params, x, rope)
+        rows = self.latent_rows(params, x, rope)
+        new = {"latent": lax.dynamic_update_slice(
+            cache["latent"], rows.astype(cache["latent"].dtype),
+            (0, start, 0))}
+        k_pos = jnp.arange(new["latent"].shape[1])
+        mask = _attn_mask(positions, k_pos, start + s, True)[None]
+        return self.project_out(params, self.attend_rows(
+            params, q_n, q_r, new["latent"], mask)), new
+
+    def through_cache(self, params, x, rope, kv_io, chunk: bool):
+        """The paged seam (see :meth:`TransformerBlock.apply_decode`) for
+        a latent layer: ``kv_io(name, q, rows[, up])`` writes the rows
+        (B, S, rank + qk_rope) and attends.  A decode step hands it the
+        absorbed query and takes ``softmax . c`` (B, H, 1, rank) back; a
+        chunk hands it the un-absorbed query ``[q_n ; q_r]`` and ``up`` =
+        (W_uk, W_uv), and takes the heads' values (1, H, C, v_head)."""
+        q_n, q_r = self.queries(params, x, rope)
+        rows = self.latent_rows(params, x, rope)
+        if chunk:
+            a = kv_io(self.name, jnp.concatenate([q_n, q_r], -1), rows,
+                      up=self.up_weights(params, x.dtype))
+        else:
+            a = self.up_values(params, kv_io(
+                self.name, self.absorb(params, q_n, q_r), rows))
+        return self.project_out(params, a)
+
+
 class SwiGLU(Module):
     """Gated MLP: (silu(x w1) * x w3) w2 — two column-sharded matmuls in,
     one row-sharded out; XLA fuses the gate elementwise into the matmul
     epilogue, so the MXU sees three big GEMMs and HBM sees no extra trip."""
 
-    def __init__(self, cfg: TransformerConfig, name=None):
+    def __init__(self, cfg: TransformerConfig, name=None, d_ff=None):
         super().__init__(name=name)
         self.cfg = cfg
+        self.d_ff = int(d_ff or cfg.d_ff)    # (a leading dense layer's own)
         self.pspec = {"w1": P(None, "tp"), "w3": P(None, "tp"),
                       "w2": P("tp", None)}
 
     def init(self, rng):
-        cfg = self.cfg
+        cfg, d_ff = self.cfg, self.d_ff
         k1, k2, k3 = jax.random.split(rng, 3)
         s_in = cfg.d_model ** -0.5
-        s_out = cfg.d_ff ** -0.5
+        s_out = d_ff ** -0.5
         return {self.name: {
-            "w1": jax.random.normal(k1, (cfg.d_model, cfg.d_ff)) * s_in,
-            "w3": jax.random.normal(k3, (cfg.d_model, cfg.d_ff)) * s_in,
-            "w2": jax.random.normal(k2, (cfg.d_ff, cfg.d_model)) * s_out,
+            "w1": jax.random.normal(k1, (cfg.d_model, d_ff)) * s_in,
+            "w3": jax.random.normal(k3, (cfg.d_model, d_ff)) * s_in,
+            "w2": jax.random.normal(k2, (d_ff, cfg.d_model)) * s_out,
         }}
 
     def apply(self, params, x, ctx):
@@ -403,7 +655,9 @@ class TransformerBlock(Module):
     """Pre-norm attention, then the MLP (dense, or routed experts), each
     around a residual.  ``layer`` is the block's index: what kind of
     attention layer it is comes from ``cfg.windows`` / ``cfg.rope_layers``
-    there.  With ``cfg.moe_router_pre_attention`` the experts' router
+    there (``cfg.kv_lora_rank`` > 0: :class:`LatentAttention` in every
+    layer), and whether a model of routed experts keeps a dense MLP in
+    it from ``cfg.dense_layers``.  With ``cfg.moe_router_pre_attention`` the experts' router
     reads the block's attention-normed input ``norm1(x)``, while the
     experts themselves read ``norm2(x + attention)`` as ever."""
 
@@ -411,16 +665,28 @@ class TransformerBlock(Module):
         super().__init__(name=name)
         self.cfg = cfg
         self.norm1 = RMSNorm(cfg.d_model, name=f"{self.name}.norm1")
-        self.attn = MultiHeadAttention(cfg, name=f"{self.name}.attn",
-                                       window=cfg.layer_window(layer),
-                                       rope=cfg.layer_rope(layer))
+        if cfg.kv_lora_rank:
+            self.attn = LatentAttention(cfg, name=f"{self.name}.attn")
+        else:
+            self.attn = MultiHeadAttention(cfg, name=f"{self.name}.attn",
+                                           window=cfg.layer_window(layer),
+                                           rope=cfg.layer_rope(layer))
         self.norm2 = RMSNorm(cfg.d_model, name=f"{self.name}.norm2")
         self.router_pre_attention = False
-        if cfg.moe_experts > 0 and cfg.moe_capacity_factor is None:
+        if cfg.moe_experts > 0 and layer < cfg.dense_layers:
+            # a leading dense layer of a model of routed experts
+            self.mlp = SwiGLU(cfg, name=f"{self.name}.mlp",
+                              d_ff=cfg.dense_d_ff)
+        elif cfg.moe_experts > 0 and cfg.moe_capacity_factor is None:
             from ..nn.moe import RoutedExperts
-            self.mlp = RoutedExperts(cfg.d_model, cfg.d_ff, cfg.moe_experts,
-                                     cfg.moe_top_k, name=f"{self.name}.moe",
-                                     activation=cfg.moe_activation)
+            self.mlp = RoutedExperts(
+                cfg.d_model, cfg.d_ff, cfg.moe_experts, cfg.moe_top_k,
+                name=f"{self.name}.moe", activation=cfg.moe_activation,
+                scoring=cfg.moe_scoring, n_groups=cfg.moe_groups,
+                top_groups=cfg.moe_top_groups,
+                routed_scale=cfg.moe_routed_scale,
+                router_bias=cfg.moe_router_bias, held=cfg.moe_held,
+                shared_d_ff=cfg.moe_shared_d_ff)
             self.router_pre_attention = cfg.moe_router_pre_attention
         elif cfg.moe_experts > 0:
             from ..nn.moe import SwitchFFN
@@ -471,10 +737,14 @@ class TransformerBlock(Module):
         computed depends on where the pages lie, not on the model.  A
         sparse attention hands it ``index`` = (qi (B, 1, Hi, Di), ki
         (B, 1, Di), w (B, 1, Hi)) as well: the index key is cached with
-        k and v, and the selection is the cache's to apply."""
+        k and v, and the selection is the cache's to apply.  A latent
+        layer's seam is ``kv_io(attn_name, q, rows[, up])``
+        (:meth:`LatentAttention.through_cache`): one row a token in, and
+        for a decode step an absorbed query's ``softmax . c`` out."""
         return self._through_cache(
             params, x, ctx, kv_io,
-            lambda t: apply_rope_rows(t, positions, self.cfg.rope_theta))
+            lambda t: apply_rope_rows(t, positions, self.cfg.rope_theta,
+                                      self.attn.rope_freqs))
 
     def apply_chunk(self, params, x, ctx, start, kv_io):
         """A chunk of ONE sequence's prompt through the same seam: x
@@ -484,11 +754,15 @@ class TransformerBlock(Module):
         positions = start + jnp.arange(x.shape[1])
         return self._through_cache(
             params, x, ctx, kv_io,
-            lambda t: apply_rope(t, positions, self.cfg.rope_theta))
+            lambda t: apply_rope(t, positions, self.cfg.rope_theta,
+                                 self.attn.rope_freqs), chunk=True)
 
-    def _through_cache(self, params, x, ctx, kv_io, rope):
-        rope = self.attn.rotation(rope)
+    def _through_cache(self, params, x, ctx, kv_io, rope, chunk=False):
         n1 = self.norm1.apply(params, x, ctx)
+        if self.attn.latent:
+            h = x + self.attn.through_cache(params, n1, rope, kv_io, chunk)
+            return h + self._mlp(params, h, n1, ctx)
+        rope = self.attn.rotation(rope)
         qkv = self.attn.project_qkv(params, n1, rope)
         if self.attn.sparse:
             qkv += (self.attn.project_index(params, n1, rope),)
@@ -629,12 +903,33 @@ class TransformerLM(Module):
         shape = (batch, cfg.n_kv_heads, n, cfg.head_dim)
 
         def one():
+            if cfg.kv_lora_rank:
+                return {"latent": jnp.zeros(
+                    (batch, n, cfg.kv_lora_rank + cfg.qk_rope_dim), dt)}
             out = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
             if cfg.index_heads:
                 out["ki"] = jnp.zeros((batch, n, cfg.index_dim), dt)
             return out
 
         return {blk.attn.name: one() for blk in self.blocks}
+
+    def kv_geometry(self):
+        """What a paged cache has to know of this model's rows
+        (``PagedKVCache``'s arguments of these names): KV heads of
+        ``head_dim`` under ``q_heads`` query heads, with index keys or
+        not; or, for latent attention, ONE row a token of ``head_dim`` =
+        rank + rope columns whose first ``latent_rank`` are the value too,
+        scored at the model's own ``sm_scale``."""
+        cfg = self.cfg
+        if cfg.kv_lora_rank:
+            attn = self.blocks[0].attn
+            return dict(n_heads=1, q_heads=cfg.n_heads,
+                        head_dim=attn.row_dim, latent_rank=cfg.kv_lora_rank,
+                        sm_scale=attn.sm_scale)
+        return dict(n_heads=cfg.n_kv_heads, q_heads=cfg.n_heads,
+                    head_dim=cfg.head_dim,
+                    index_dim=cfg.index_dim if cfg.index_heads else 0,
+                    index_top_k=cfg.index_top_k if cfg.index_heads else 0)
 
     def apply_with_cache(self, params, tokens, cache, start):
         """logits for ``tokens`` (B, s) written at global offset ``start``

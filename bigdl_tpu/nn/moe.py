@@ -213,58 +213,144 @@ class RoutedExperts(Module):
     ``moe/pairs``, ``moe/experts_touched`` (experts with at least one
     token), ``moe/expert_load_max`` (the fullest expert's tokens).
 
+    The constructor's other arguments, each default today's behaviour:
+
+    ``scoring`` "sigmoid": a score is ``s = sigmoid(r W_r)``; the gates
+    of the chosen are ``routed_scale * s_e / sum_chosen(s)``.
+    ``router_bias``: a learned ``router_bias`` (n_experts,) added to the
+    scores for the SELECTION only (``s' = s + b``; the gates are from
+    ``s``).  ``n_groups`` / ``top_groups``: group-limited selection: the
+    experts lie in ``n_groups`` equal groups, a group scores the sum of
+    its two largest ``s'``, and the ``top_k`` are taken among the experts
+    of the ``top_groups`` best groups.
+    ``held`` = (first, count): this layer HOLDS experts ``first ..
+    first + count - 1`` of the ``n_experts`` its router scores (one
+    chip's share under expert parallelism: the other chips hold the
+    rest).  Its weights have ``count`` experts; pairs whose expert is
+    absent are dropped before the sort, exactly as masked tokens are, and
+    the layer returns its own share of the sum: what the absent experts
+    would add is the other chips'.  ``moe/pairs`` counts the held pairs
+    and ``moe/pairs_routed`` (counted only here) all the valid tokens'.
+    ``shared_d_ff`` > 0: a shared SwiGLU expert of that width beside the
+    routed ones, which every token goes through (``shared_w1`` /
+    ``shared_w3`` / ``shared_w2``).
+
     Input (B, S, d_model) -> output (B, S, d_model).  The expert dim is
     sharded over 'ep' as :class:`SwitchFFN`'s is.
     """
 
     def __init__(self, d_model, d_ff, n_experts, top_k, name=None,
-                 activation="silu"):
+                 activation="silu", scoring="softmax", n_groups=0,
+                 top_groups=0, routed_scale=1.0, router_bias=False,
+                 held=None, shared_d_ff=0):
         super().__init__(name=name)
         if not 0 < top_k <= n_experts:
             raise ValueError(f"top_k {top_k} of {n_experts} experts")
         if activation not in _GATE_ACTIVATIONS:
             raise ValueError(f"activation {activation!r} is none of "
                              f"{sorted(_GATE_ACTIVATIONS)}")
-        self.activation = activation
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {scoring!r} is neither softmax nor "
+                             "sigmoid")
+        if n_groups and (n_experts % n_groups or not
+                         0 < top_groups <= n_groups
+                         or top_k > top_groups * (n_experts // n_groups)):
+            raise ValueError(f"{n_experts} experts in {n_groups} groups, "
+                             f"top {top_k} of the best {top_groups}")
+        if held is not None and not (
+                0 <= held[0] and held[1] > 0
+                and held[0] + held[1] <= n_experts):
+            raise ValueError(f"held {held} of {n_experts} experts")
+        self.activation, self.scoring = activation, scoring
         self.d_model, self.d_ff = d_model, d_ff
         self.n_experts, self.top_k = int(n_experts), int(top_k)
+        self.n_groups, self.top_groups = int(n_groups), int(top_groups)
+        self.routed_scale = float(routed_scale)
+        self.router_bias = bool(router_bias)
+        self.held = None if held is None else (int(held[0]), int(held[1]))
+        self.shared_d_ff = int(shared_d_ff)
         self.pspec = {"router": P(None, None),
                       "w1": P("ep", None, "tp"), "w3": P("ep", None, "tp"),
-                      "w2": P("ep", "tp", None)}
+                      "w2": P("ep", "tp", None),
+                      "shared_w1": P(None, "tp"), "shared_w3": P(None, "tp"),
+                      "shared_w2": P("tp", None)}
+
+    @property
+    def n_held(self):
+        """Experts whose weights this layer has."""
+        return self.n_experts if self.held is None else self.held[1]
 
     def init(self, rng):
         k0, k1, k2, k3 = jax.random.split(rng, 4)
-        E, D, F = self.n_experts, self.d_model, self.d_ff
+        E, D, F = self.n_held, self.d_model, self.d_ff
         s_in, s_out = D ** -0.5, F ** -0.5
-        return {self.name: {
-            "router": jax.random.normal(k0, (D, E), jnp.float32) * s_in,
+        p = {
+            "router": jax.random.normal(k0, (D, self.n_experts),
+                                        jnp.float32) * s_in,
             "w1": jax.random.normal(k1, (E, D, F), jnp.float32) * s_in,
             "w3": jax.random.normal(k3, (E, D, F), jnp.float32) * s_in,
             "w2": jax.random.normal(k2, (E, F, D), jnp.float32) * s_out,
-        }}
+        }
+        if self.router_bias:
+            p["router_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
+        if self.shared_d_ff:
+            Fs = self.shared_d_ff
+            ks = jax.random.split(jax.random.fold_in(rng, 4), 3)
+            p["shared_w1"] = jax.random.normal(ks[0], (D, Fs)) * s_in
+            p["shared_w3"] = jax.random.normal(ks[1], (D, Fs)) * s_in
+            p["shared_w2"] = jax.random.normal(ks[2], (Fs, D)) * Fs ** -0.5
+        return {self.name: p}
 
     def route(self, params, xt):
         """xt (N, D) -> (expert ids (N, top_k), gates (N, top_k) f32)."""
-        probs = jax.nn.softmax(jnp.dot(
-            xt.astype(jnp.float32),
-            self.own(params)["router"].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST), axis=-1)
-        picked, idx = lax.top_k(probs, self.top_k)
-        return idx, picked / picked.sum(-1, keepdims=True)
+        p = self.own(params)
+        logits = jnp.dot(
+            xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits) if self.scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        choice = scores
+        if self.router_bias:
+            choice = choice + p["router_bias"].astype(jnp.float32)
+        if self.n_groups:
+            n, g = choice.shape[0], self.n_groups
+            by_group = choice.reshape(n, g, -1)
+            best = lax.top_k(lax.top_k(by_group, 2)[0].sum(-1),
+                             self.top_groups)[1]          # (N, top_groups)
+            kept = (best[:, :, None] == jnp.arange(g)[None, None, :]).any(1)
+            choice = jnp.where(kept[:, :, None], by_group,
+                               -jnp.inf).reshape(n, -1)
+        if choice is scores:
+            picked, idx = lax.top_k(scores, self.top_k)
+        else:
+            # chosen by the corrected scores, weighed by the scores
+            _, idx = lax.top_k(choice, self.top_k)
+            picked = jnp.take_along_axis(scores, idx, axis=-1)
+        gate = picked / picked.sum(-1, keepdims=True)
+        return idx, gate if self.routed_scale == 1.0 \
+            else self.routed_scale * gate
 
     def apply(self, params, x, ctx, router_input=None):
         p = self.own(params)
         dt = x.dtype
         B, S, D = x.shape
-        N, K, E = B * S, self.top_k, self.n_experts
+        N, K, E = B * S, self.top_k, self.n_held
         xt = x.reshape(N, D)
         idx, gate = self.route(params, xt if router_input is None
                                else router_input.reshape(N, D))
         valid = jnp.ones((N,), bool) if ctx.token_mask is None \
             else ctx.token_mask.reshape(N)
-        # pairs sorted by expert; those of masked tokens sort behind
-        # every group and are never touched
-        key = jnp.where(valid[:, None], idx, E).reshape(N * K)
+        if self.held is None:
+            mine = valid[:, None]
+        else:
+            # the experts this layer holds, numbered from 0: pairs of the
+            # others are the other chips'
+            idx = idx - self.held[0]
+            mine = valid[:, None] & (idx >= 0) & (idx < E)
+            ctx.count("moe/pairs_routed", valid.sum() * K)
+        # pairs sorted by expert; those of masked tokens (and of absent
+        # experts) sort behind every group and are never touched
+        key = jnp.where(mine, idx, E).reshape(N * K)
         order = jnp.argsort(key, stable=True)
         # (a compare-and-sum, not a scatter-add: thousands of updates
         # into a few dozen bins serialise on a TPU)
@@ -283,4 +369,9 @@ class RoutedExperts(Module):
         ctx.count("moe/pairs", load.sum())
         ctx.count("moe/experts_touched", (load > 0).sum())
         ctx.count("moe/expert_load_max", load.max())
+        if self.shared_d_ff:
+            h = jax.nn.silu(jnp.dot(xt, p["shared_w1"].astype(dt))) \
+                * jnp.dot(xt, p["shared_w3"].astype(dt))
+            out = out + jnp.dot(h, p["shared_w2"].astype(dt)
+                                ).astype(jnp.float32)
         return out.astype(dt).reshape(B, S, D)

@@ -191,6 +191,145 @@ def _window_attend(q, k_pool, v_pool, tables, lengths, *, window):
     return gathered_attention(q, k_pool, v_pool, tables, lengths, window)
 
 
+# Latent attention: a token's ONE cached row (``rank`` columns of latent,
+# which are the value too, then the rope key every head shares) under many
+# query heads.  Both pieces gather the table's rows (a row is narrow: a
+# slot's whole table is a few megabytes) and are jitted under names of
+# their own, as the pieces above are, so that a device trace shows them
+# apart; neither is a kernel yet.
+@functools.partial(jax.jit, static_argnames=("rank", "sm_scale"))
+def _latent_attend(q, new_rows, pool, tables, lengths, *, rank, sm_scale):
+    """Absorbed decode attention: q (S, H, W) = ``[q_n W_uk^T ; q_r]`` of
+    each slot's new token, at position ``lengths[s]``, over the rows
+    BEFORE it in ``pool`` (n_pages, page_size, W) through ``tables`` (S,
+    P; ``-1``: none), and over the token's own row ``new_rows`` (S, W),
+    which need not be in the pool yet: the pool is read as the step was
+    handed it (its rows' write may come after), and the result is that of
+    write-then-attend.  Rows from the position on are masked and their
+    values zeroed (a recycled page's stale rows).  Rows enter the matmuls
+    as stored, the probabilities in their dtype; scores, softmax and both
+    sums are float32.  One LIVE slot at a time, in a loop as long as
+    there are slots with a page, its pages scored as they lie (never
+    flattened to rows): a slot's table is a few megabytes, where all the
+    slots' at once are five passes over a pool's worth (on a v5e, 24
+    slots x 14,336 rows: 8.4 ms a layer at once; a slot at a time 2.1 ms
+    with 24 live and 1.1 with 8; my chip runs, PR 34).  A dead slot reads
+    zeros.  -> ``softmax . c`` (S, H, rank) in q's dtype."""
+    _, page_size, _ = pool.shape
+    s, h, _ = q.shape
+    live = tables[:, 0] >= 0
+    order = jnp.argsort(~live, stable=True)        # the live slots first
+    k_pos = jnp.arange(tables.shape[1])[:, None] * page_size \
+        + jnp.arange(page_size)[None, :]
+    qk = jnp.promote_types(q.dtype, pool.dtype)
+
+    def one(i, out):
+        slot = order[i]
+        q_s = lax.dynamic_index_in_dim(q, slot, 0, keepdims=False)
+        own = lax.dynamic_index_in_dim(new_rows, slot, 0, keepdims=False)
+        table = lax.dynamic_index_in_dim(tables, slot, 0, keepdims=False)
+        pages = jnp.take(pool, jnp.maximum(table, 0), axis=0, mode="clip")
+        # (a column with no page lies past the position: masked)
+        mask = k_pos < lengths[slot]                       # (P, page)
+        c = jnp.where(mask[:, :, None], pages[..., :rank], 0)
+        sc = jnp.einsum("hd,pld->hpl", q_s.astype(qk), pages.astype(qk),
+                        preferred_element_type=jnp.float32) * sm_scale
+        sc = jnp.where(mask[None], sc, DEFAULT_MASK_VALUE)
+        sc_own = jnp.einsum("hd,d->h", q_s.astype(qk), own.astype(qk),
+                            preferred_element_type=jnp.float32) * sm_scale
+        top = jnp.maximum(sc.max(axis=(1, 2)), sc_own)
+        e = jnp.exp(sc - top[:, None, None])
+        e_own = jnp.exp(sc_own - top)
+        o = jnp.einsum("hpl,plr->hr", e.astype(c.dtype), c,
+                       preferred_element_type=jnp.float32) \
+            + e_own.astype(c.dtype).astype(jnp.float32)[:, None] \
+            * own[:rank].astype(jnp.float32)[None, :]
+        den = e.sum(axis=(1, 2)) + e_own
+        return lax.dynamic_update_index_in_dim(
+            out, (o / den[:, None]).astype(q.dtype), slot, 0)
+
+    return lax.fori_loop(0, live.sum(), one, jnp.zeros((s, h, rank), q.dtype))
+
+
+# Heads a step of the chunk's attention: the float32 scores of one block
+# of heads are ``block x chunk x keys x 4`` bytes (2 x 512 x 12,288: 50
+# MB), never those of all the heads at once (128: 3.2 GB).  On a v5e at
+# 128 heads x 512 queries x 12,288 keys, up-projected (my chip runs, PR
+# 34): 1 / 2 / 4 / 8 / 16 heads a step take 7.5 / 8.0 / 8.6 / 15.7 / 16.4
+# ms a call.
+_LATENT_HEAD_BLOCK = 2
+# Which formula a chunk takes (the same attention either way): absorbed
+# scores the queries against the rows as they lie (2 x 512 x keys x 128 x
+# 1,088 FLOPs); up-projected makes a block of heads' keys and values from
+# the rows first (2 x keys x 512 x 32,768 + 2 x 512 x keys x 128 x 320:
+# half the work).  At the shapes above: absorbed 12.2-12.3 ms a call at
+# any block, up-projected 8.0 (59% of the chip's peak).
+_LATENT_CHUNK_ABSORBED = False
+
+
+def latent_chunk_formula() -> str:
+    return "absorbed" if _LATENT_CHUNK_ABSORBED else "up-projected"
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "absorbed"))
+def _latent_chunk_attend(q, w_uk, w_uv, pool, table, start, *, rank,
+                         sm_scale, absorbed):
+    """A prompt chunk's attention over its slot's latent rows: q (H, C,
+    nope + rope) un-absorbed, at positions ``start + arange(C)``; W_uk
+    (rank, H, nope) and W_uv (rank, H, v) the two halves of the
+    up-projection; ``table`` (P,) the pages (``-1``: none), every one of
+    which is scored whatever ``start`` is.  In blocks of
+    ``_LATENT_HEAD_BLOCK`` heads, so that neither the scores nor the
+    up-projected keys and values of all heads are ever whole.  ``absorbed``
+    picks the formula.  -> (H, C, v) in q's dtype."""
+    n_pages, _, width = pool.shape
+    h, chunk, _ = q.shape
+    nope = w_uk.shape[-1]
+    rows = jnp.take(pool, jnp.where(table < 0, n_pages, table), axis=0,
+                    mode="fill", fill_value=0).reshape(-1, width)
+    k_pos = jnp.arange(rows.shape[0])
+    mask = k_pos[None, :] <= (start + jnp.arange(chunk))[:, None]   # (C, L)
+    c = jnp.where((k_pos < start + chunk)[:, None], rows[:, :rank], 0)
+    k_r = rows[:, rank:]
+    scrubbed = jnp.concatenate([c, k_r], -1) if absorbed else None
+    hb = min(_LATENT_HEAD_BLOCK, h)
+    assert h % hb == 0, (h, hb)
+
+    def block(args):
+        q_b, uk, uv = args          # (hb, C, .), (hb, rank, nope), (hb, rank, v)
+        if absorbed:
+            q_b = jnp.concatenate(
+                [jnp.einsum("hcn,hrn->hcr", q_b[..., :nope], uk),
+                 q_b[..., nope:]], -1)
+            sc = jnp.einsum("hcd,ld->hcl", q_b, scrubbed,
+                            preferred_element_type=jnp.float32)
+        else:
+            keys = jnp.concatenate(
+                [jnp.einsum("lr,hrn->hln", c, uk),
+                 jnp.broadcast_to(k_r, (hb,) + k_r.shape)], -1)
+            sc = jnp.einsum("hcd,hld->hcl", q_b, keys,
+                            preferred_element_type=jnp.float32)
+        sc = jnp.where(mask[None], sc * sm_scale, DEFAULT_MASK_VALUE)
+        e = jnp.exp(sc - sc.max(axis=-1, keepdims=True))
+        den = e.sum(axis=-1, keepdims=True)
+        if absorbed:
+            o = jnp.einsum("hcl,lr->hcr", e.astype(c.dtype), c,
+                           preferred_element_type=jnp.float32) / den
+            o = jnp.einsum("hcr,hrv->hcv", o.astype(uv.dtype), uv,
+                           preferred_element_type=jnp.float32)
+        else:
+            o = jnp.einsum("hcl,hlv->hcv", e.astype(c.dtype),
+                           jnp.einsum("lr,hrv->hlv", c, uv),
+                           preferred_element_type=jnp.float32) / den
+        return o.astype(q.dtype)
+
+    by_block = lambda a: a.reshape((h // hb, hb) + a.shape[1:])
+    out = lax.map(block, (by_block(q),
+                          by_block(jnp.transpose(w_uk, (1, 0, 2))),
+                          by_block(jnp.transpose(w_uv, (1, 0, 2)))))
+    return out.reshape((h,) + out.shape[2:])
+
+
 def paged_attention_path(pool_dtype, n_heads: int, head_dim: int, *,
                          q_heads: Optional[int] = None,
                          page_size: Optional[int] = None,

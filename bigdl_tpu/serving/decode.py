@@ -264,8 +264,9 @@ class DecodeEngine(EngineIntrospection):
         # then so does every prompt: the ladder is empty
         self.prefill_chunk = int(prefill_chunk)
         windows = [cfg.layer_window(i) for i in range(cfg.n_layers)]
+        # (latent rows go into pages by the chunk program alone)
         self.chunked = self.max_prompt > self.prefill_chunk \
-            or any(windows)
+            or any(windows) or bool(cfg.kv_lora_rank)
         if self.chunked and self.prefill_chunk % page_size:
             raise ValueError(f"prefill_chunk {prefill_chunk} must be a "
                              f"multiple of page_size {page_size}")
@@ -274,17 +275,17 @@ class DecodeEngine(EngineIntrospection):
         self._chunk_pages = -(-self.max_prompt // self.prefill_chunk) \
             * self.prefill_chunk // page_size
         self.kv = PagedKVCache(
-            [blk.attn.name for blk in model.blocks],
-            n_heads=cfg.n_kv_heads, q_heads=cfg.n_heads,
-            head_dim=cfg.head_dim,
-            index_dim=cfg.index_dim if cfg.index_heads else 0,
-            index_top_k=cfg.index_top_k if cfg.index_heads else 0,
+            [blk.attn.name for blk in model.blocks], **model.kv_geometry(),
             n_pages=pool_pages, page_size=page_size, n_slots=self.slots,
             max_context=self.max_context,
             dtype=kv_dtype or jnp.dtype(cfg.dtype), int8=int8_kv,
             windows=windows if any(windows) else None,
             ring_slack=self.prefill_chunk, recorder=self.recorder)
         self.recorder.gauge("kv/index_bytes", self.kv.index_bytes())
+        if self.kv.latent_rank:
+            self.recorder.gauge("kv/latent_row_bytes",
+                                self.kv.latent_row_bytes())
+            self.recorder.gauge("kv/latent_bytes", self.kv.latent_bytes())
         self._base_key = jax.random.PRNGKey(int(seed))
         self._pool = self.kv.init_pool()
         self._pool_avals = jax.tree_util.tree_map(
@@ -490,6 +491,10 @@ class DecodeEngine(EngineIntrospection):
         out["attn_route"] = self.kv.attention_path()[0]
         # which kinds of layer the cache holds, and each kind's table
         out["kv_kinds"] = {k.name: k.describe() for k in self.kv.kinds}
+        if self.kv.latent_rank:
+            # ... and what a row of it is, where it is not K and V
+            for kind in out["kv_kinds"].values():
+                kind["content"] = "latent"
         # a prompt's chunks (None: this engine takes prompts whole)
         out["chunk_attn_route"] = self.chunk_attention_path()[0]
         # the sparse route: rows the steps' attention read of the rows
@@ -570,6 +575,19 @@ class DecodeEngine(EngineIntrospection):
                 live = kv.table_of(tables)[:, 0] >= 0
                 ctx.token_mask = live
 
+                def latent_io(name, q, rows):
+                    # a latent layer: the token's one row in, the absorbed
+                    # query's `softmax . c` a head out
+                    # (attended from the pool as it came in, beside the
+                    # token's own row, and then written: the same result,
+                    # and the pool is read in the layout it lies in)
+                    o = kv.attend(pool[name], tables, lengths, q, rows=rows)
+                    new_pool[name] = kv.write_token(pool[name], tables,
+                                                    lengths, rows)
+                    ctx.count("mla/rows_live",
+                              jnp.where(live, lengths + 1, 0).sum())
+                    return o
+
                 def kv_io(name, q, k_new, v_new, index=None):
                     # each layer its kind's table (the one table of a
                     # model of one kind of layer)
@@ -602,8 +620,9 @@ class DecodeEngine(EngineIntrospection):
                     return kv.attend(new_pool[name], tab, lengths, q,
                                      (qi[:, 0], w[:, 0]))
 
-                logits = model.decode_tokens(params, tokens, lengths,
-                                             kv_io, ctx)
+                logits = model.decode_tokens(
+                    params, tokens, lengths,
+                    latent_io if kv.latent_rank else kv_io, ctx)
                 tok = _select_tokens(logits, temps, step, base_key)
                 # poisoned-weights sentinel: argmax of NaN logits is a
                 # VALID token id, so without this a poisoned publish
@@ -633,6 +652,20 @@ class DecodeEngine(EngineIntrospection):
                     kv.table_of(table, k.layers[0]), start, chunk,
                     k.layers[0]) for k in kv.kinds}
 
+                def latent_io(name, q, rows, up):
+                    # a latent layer: the chunk's rows in, its un-absorbed
+                    # queries and the up-projection over to the cache
+                    new_pool[name] = kv.write_chunk(new_pool[name],
+                                                    pages[kv.kinds[0].name],
+                                                    rows)
+                    # keys the chunk's valid queries may see, and the
+                    # slot's rows up to the chunk's end
+                    ctx.count("mla/chunk_rows_visible",
+                              n_valid * start + n_valid * (n_valid + 1) // 2)
+                    ctx.count("mla/chunk_rows_live", start + n_valid)
+                    return kv.attend_chunk(new_pool[name], table, start, q,
+                                           up=up)
+
                 def kv_io(name, q, k, v, index=None):
                     qi, ki, w = index or (None, None, None)
                     new_pool[name] = kv.write_chunk(
@@ -642,8 +675,9 @@ class DecodeEngine(EngineIntrospection):
                         new_pool[name], kv.table_of(table, name), start, q,
                         None if index is None else (qi, w), layer=name)
 
-                last = model.prefill_chunk(params, tokens, start, n_valid,
-                                           kv_io, ctx)
+                last = model.prefill_chunk(
+                    params, tokens, start, n_valid,
+                    latent_io if kv.latent_rank else kv_io, ctx)
                 tok = _select_tokens(last[None, :], temp[None], step,
                                      base_key)[0]
                 bad = ~jnp.isfinite(last).all()
@@ -710,7 +744,9 @@ class DecodeEngine(EngineIntrospection):
         for name, value in zip(self._count_names[kind],
                                np.asarray(counts[0]) if counts else ()):
             head, _, leaf = name.partition("/")
-            self.recorder.inc(f"{head}/{prefix}{leaf}", float(value))
+            # (a name that says `chunk_` is a chunk's own already)
+            self.recorder.inc(name if leaf.startswith("chunk_")
+                              else f"{head}/{prefix}{leaf}", float(value))
 
     def _params_for_step(self, entry):
         """Device-placed params of the CURRENT snapshot, cached per
@@ -1309,8 +1345,8 @@ class DecodeEngine(EngineIntrospection):
             counters=counters)
 
 
-_ATTN_ROUTES = ("gather", "pallas", "sparse")
-_CHUNK_ATTN_ROUTES = ("window", "pallas")
+_ATTN_ROUTES = ("gather", "pallas", "sparse", "latent")
+_CHUNK_ATTN_ROUTES = ("window", "pallas", "latent")
 
 
 def _select_tokens(logits, temps, step, base_key):

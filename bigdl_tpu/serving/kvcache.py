@@ -59,6 +59,17 @@ shows.  One ``alloc_for`` grows every kind or none;
 ``free_slot`` and eviction return every kind's pages, and a readmission's
 re-prefill and replay rebuild a ring as they rebuild a table.
 
+**A latent pool.**  A model of latent attention (``latent_rank`` > 0)
+caches ONE row a token a layer: ``latent_rank`` columns of normed latent,
+which every head's key AND value are up-projected from, then the one
+rotated key all heads share.  Its layer's pool is the single array
+``{"latent": (n_pages, page_size, head_dim)}``: no V pool, no heads.  The
+decode step scores ABSORBED queries against the rows as they lie
+(``ops/paged_attention.py`` ``_latent_attend``) and a chunk hands over
+its un-absorbed queries and the up-projection
+(``_latent_chunk_attend``).  The allocator, the tables and :meth:`pack`
+do not notice: a row is a row.
+
 Page tables are data, not shapes: admissions, retirements and
 evictions change *values* only, so one compiled decode program serves
 every batch composition — the zero-recompile discipline of the PR-2
@@ -89,8 +100,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import Recorder
-from ..ops.paged_attention import (_CHUNK_MAX_PAGES, _window_attend,
-                                   attend_window, paged_attention,
+from ..ops.paged_attention import (_CHUNK_MAX_PAGES, _latent_attend,
+                                   _latent_chunk_attend, _window_attend,
+                                   attend_window, latent_chunk_formula,
+                                   paged_attention,
                                    paged_attention_path,
                                    paged_chunk_attention,
                                    paged_chunk_attention_path,
@@ -158,6 +171,10 @@ class PagedKVCache:
     ``ring_slack``    rows a ring holds beyond its window: the longest
                       run of rows written before any of them is attended
                       (a prefill chunk)
+    ``latent_rank`` / ``sm_scale``  > 0: a latent pool (the module's
+                      docstring): ``n_heads`` 1, ``head_dim`` the row's
+                      width, its first ``latent_rank`` columns the value,
+                      scores scaled by the model's own ``sm_scale``
 
     The allocator side (``alloc_for`` / ``free_slot``) is guarded by
     one lock and keeps the invariant ``free + sum(owned) == n_pages`` for
@@ -175,10 +192,20 @@ class PagedKVCache:
                  q_heads: Optional[int] = None, index_dim: int = 0,
                  index_top_k: int = 0,
                  windows: Optional[Sequence[int]] = None,
-                 ring_slack: int = 0,
+                 ring_slack: int = 0, latent_rank: int = 0,
+                 sm_scale: Optional[float] = None,
                  recorder: Optional[Recorder] = None):
         if page_size < 1 or n_slots < 1:
             raise ValueError("page_size, n_pages and n_slots must be >= 1")
+        if latent_rank and (int8 or index_dim or n_heads != 1
+                            or windows is not None and any(windows)
+                            or not 0 < latent_rank < head_dim
+                            or sm_scale is None):
+            raise ValueError("a latent pool is a float pool of one row a "
+                             "token (n_heads 1, latent_rank < head_dim, "
+                             "an sm_scale), global, with no index keys")
+        self.latent_rank = int(latent_rank)
+        self.sm_scale = None if sm_scale is None else float(sm_scale)
         if index_dim and (int8 or index_top_k < 1):
             raise ValueError("an index-key pool is a float pool with "
                              "index_top_k >= 1")
@@ -263,9 +290,13 @@ class PagedKVCache:
         "v_scale"][, "ki"]}}`` with pages laid out ``(n_pages, page_size,
         n_heads, head_dim)`` (scales ``(n_pages, page_size, n_heads,
         1)``, index keys ``(n_pages, page_size, index_dim)``), ``n_pages``
-        the layer's kind's.  Zero pages read back as the zero rows of a
-        fresh contiguous cache."""
+        the layer's kind's; a latent pool ``{layer: {"latent": (n_pages,
+        page_size, head_dim)}}`` and nothing else.  Zero pages read back
+        as the zero rows of a fresh contiguous cache."""
         def one(n_pages):
+            if self.latent_rank:
+                return {"latent": jnp.zeros(
+                    (n_pages, self.page_size, self.head_dim), self.dtype)}
             shape = (n_pages, self.page_size, self.n_heads, self.head_dim)
             sshape = shape[:-1] + (1,)
             if self.int8:
@@ -282,6 +313,16 @@ class PagedKVCache:
 
         return {name: one(self.kind_of(name).n_pages)
                 for name in self.layer_names}
+
+    def latent_row_bytes(self) -> int:
+        """Bytes one token costs a latent layer (``kv/latent_row_bytes``;
+        0 for a pool of K and V)."""
+        return self.head_dim * self.dtype.itemsize if self.latent_rank else 0
+
+    def latent_bytes(self) -> int:
+        """Bytes of the latent pools, every layer (``kv/latent_bytes``)."""
+        return len(self.layer_names) * self.n_pages * self.page_size \
+            * self.latent_row_bytes()
 
     def index_bytes(self) -> int:
         """Bytes the index keys take of the pool (``kv/index_bytes``)."""
@@ -446,7 +487,8 @@ class PagedKVCache:
         dead slot's write clobbered whichever request owned it.  A
         positive out-of-range index is what ``mode="drop"`` /
         ``mode="fill"`` actually drop/fill."""
-        return jnp.where(idx < 0, layer_pool["k"].shape[0], idx)
+        pages = layer_pool["latent" if "latent" in layer_pool else "k"]
+        return jnp.where(idx < 0, pages.shape[0], idx)
 
     def gather_window(self, layer_pool, tables):
         """(k_win, v_win) each ``(slots, heads, window, head_dim)``
@@ -455,6 +497,11 @@ class PagedKVCache:
         in table order, so a slot's window is exactly the contiguous
         cache a ``init_cache``-path request would hold."""
         tables = self._oob(tables, layer_pool)
+        if self.latent_rank:
+            # a latent pool's window is its rows: (slots, window, head_dim)
+            return jnp.take(layer_pool["latent"], tables, axis=0,
+                            mode="fill", fill_value=0).reshape(
+                                tables.shape[0], -1, self.head_dim)
 
         def one(q, scale):
             pages = jnp.take(q, tables, axis=0, mode="fill",
@@ -489,6 +536,10 @@ class PagedKVCache:
         if self.index_dim:
             return "sparse", (f"the pool holds index keys: top "
                               f"{self.index_top_k} rows a slot")
+        if self.latent_rank:
+            return "latent", (f"one row of {self.head_dim} a token: "
+                              "absorbed queries over each slot's gathered "
+                              "table")
         return paged_attention_path(
             jnp.int8 if self.int8 else self.dtype, self.n_heads,
             self.head_dim, q_heads=self.q_heads, page_size=self.page_size,
@@ -521,6 +572,10 @@ class PagedKVCache:
         An index-key pool takes the same routes: its selection reaches
         either as a mask, and so does a window layer's lower bound
         (``layer``: whose kind's table; the first kind's by default)."""
+        if self.latent_rank:
+            return "latent", (f"one row of {self.head_dim} a token: the "
+                              f"{latent_chunk_formula()} formula over the "
+                              "gathered table, a block of heads a step")
         return paged_chunk_attention_path(
             jnp.int8 if self.int8 else self.dtype, self.q_heads,
             self.n_heads, self.head_dim, self.page_size, chunk,
@@ -528,7 +583,7 @@ class PagedKVCache:
             backend=backend)
 
     def attend(self, layer_pool, tables, lengths, q, index=None,
-               layer: Optional[str] = None):
+               layer: Optional[str] = None, rows=None):
         """Single-token attention of q ``(slots, heads, 1, head_dim)``
         over each slot's pages, the row :meth:`write_token` just wrote
         at ``lengths[s]`` included (write, then attend).  Keys past it
@@ -538,6 +593,9 @@ class PagedKVCache:
         index_dim)``, w ``(slots, index heads)``) of an index-key pool.
         ``layer`` says whose kind ``tables`` is (the first kind's by
         default): a window layer's is a ring, and the mask is by position.
+        A latent pool takes q absorbed and the tokens' own ``rows``, and
+        attends them beside the rows BEFORE each slot's position: its
+        write comes after (the same result; ``_latent_attend`` says why).
         A cache with window layers attends every layer through
         ``ops/paged_attention.py`` ``_window_attend``, which picks the
         kernel or the gathered math as :meth:`attention_path` says (one
@@ -545,6 +603,16 @@ class PagedKVCache:
         whichever route it takes).
         Returns ``(slots, heads, 1, head_dim)`` in q's dtype."""
         route, why = self.attention_path()
+        if route == "latent":
+            # q (slots, heads, 1, head_dim) absorbed -> softmax . c
+            # (slots, heads, 1, latent_rank); `rows` are the tokens' own
+            # (slots, 1, head_dim), which the pool need not hold yet: a
+            # latent pool is attended as it came in and written after
+            # (see `_latent_attend`)
+            return _latent_attend(
+                q[:, :, 0], rows[:, 0], layer_pool["latent"], tables,
+                lengths, rank=self.latent_rank,
+                sm_scale=self.sm_scale)[:, :, None]
         if route == "sparse":
             qi, w = index
             return sparse_paged_attention(
@@ -569,7 +637,7 @@ class PagedKVCache:
         k_win, v_win = self.gather_window(layer_pool, tables)
         return attend_window(q, k_win, v_win, lengths)
 
-    def write_token(self, layer_pool, tables, lengths, k_new, v_new,
+    def write_token(self, layer_pool, tables, lengths, k_new, v_new=None,
                     ki_new=None, layer: Optional[str] = None):
         """Scatter one new k/v row per slot into the pool at
         ``(table[len // page], len % page)``.  k_new/v_new are
@@ -578,13 +646,19 @@ class PagedKVCache:
         output), ki_new ``(slots, index_dim)`` the index key of an
         index-key pool; dead slots' ``-1`` page indices drop.  In a ring
         (``layer``'s kind) the column is the page's index modulo the
-        ring's width."""
+        ring's width.  A latent pool takes the token's one row as
+        ``k_new`` ``(slots, 1, head_dim)`` and nothing else
+        (:func:`_write_rows`)."""
         col = lengths // self.page_size
         if self.kind_of(layer).ring:
             col = col % tables.shape[1]
         pidx = self._oob(jnp.take_along_axis(
             tables, col[:, None], axis=1)[:, 0], layer_pool)
         off = lengths % self.page_size
+        if self.latent_rank:
+            # k_new: the token's one row, (slots, 1, head_dim)
+            return {"latent": _write_rows(layer_pool["latent"], pidx, off,
+                                          k_new)}
         out = dict(layer_pool)
         for key, new in (("k", k_new), ("v", v_new)):
             row = new[:, :, 0, :]                     # (S, H, Dh)
@@ -635,13 +709,21 @@ class PagedKVCache:
                     pages.astype(layer_pool[key].dtype), mode="drop")
         return out
 
-    def write_chunk(self, layer_pool, table, k, v, ki=None):
+    def write_chunk(self, layer_pool, table, k, v=None, ki=None):
         """A prefill chunk's rows into the pages of ``table`` (the chunk's
         own, ``C / page_size`` entries): k/v ``(1, heads, C, head_dim)``,
         ki ``(1, C, index_dim)``.  Row by row, as :meth:`write_token`
         scatters: a scatter of whole pages has the compiler re-lay every
         layer's whole pool for it and back, which a pool of gigabytes
-        cannot pay a chunk (a float pool only)."""
+        cannot pay a chunk (a float pool only).  A latent pool takes the
+        chunk's rows as ``k`` ``(1, C, head_dim)``: whole pages of them,
+        an in-place update a page (:func:`_write_rows`)."""
+        if self.latent_rank:
+            # k: the chunk's rows, (1, C, head_dim): whole pages of them
+            rows = k[0].reshape(-1, self.page_size, k.shape[-1])
+            return {"latent": _write_rows(
+                layer_pool["latent"], self._oob(table, layer_pool),
+                jnp.zeros_like(table), rows)}
         n = k.shape[2]
         at = jnp.arange(n)
         pidx = jnp.take(self._oob(table, layer_pool), at // self.page_size)
@@ -667,7 +749,7 @@ class PagedKVCache:
         return jax.lax.dynamic_slice_in_dim(table, first, n)
 
     def attend_chunk(self, layer_pool, table, start, q, index=None,
-                     layer: Optional[str] = None):
+                     layer: Optional[str] = None, up=None):
         """A prefill chunk's attention against the slot's own pages, the
         chunk's rows (written before, :meth:`write_chunk`) included: q
         ``(1, heads, C, head_dim)`` at positions ``start + arange(C)``,
@@ -696,8 +778,17 @@ class PagedKVCache:
         the chunk's last page gives them, and the window's lower bound is
         one more term of the same mask; the ring costs the same whatever
         ``start`` is, as the longest prompt's table does.  Returns
-        ``(1, heads, C, head_dim)``."""
+        ``(1, heads, C, head_dim)``.  A latent pool takes q un-absorbed
+        (``(1, heads, C, nope + rope)``) and ``up`` = (W_uk ``(rank,
+        heads, nope)``, W_uv ``(rank, heads, v)``), and returns the heads'
+        values ``(1, heads, C, v)``: ``_latent_chunk_attend``, the table
+        gathered, a block of heads a step."""
         chunk = q.shape[2]
+        if self.latent_rank:
+            return _latent_chunk_attend(
+                q[0], up[0], up[1], layer_pool["latent"], table, start,
+                rank=self.latent_rank, sm_scale=self.sm_scale,
+                absorbed=latent_chunk_formula() == "absorbed")[None]
         kind, bounds = self.kind_of(layer), {}
         if kind.ring:
             k_pos = ring_positions(
@@ -732,6 +823,28 @@ class PagedKVCache:
         k_win, v_win = self.gather_window(layer_pool, tab)
         return attend_rows(q, k_win, v_win, q_pos, kv_len, index,
                            self.index_top_k, **bounds)
+
+
+def _write_rows(pages, pidx, off, rows):
+    """``rows[i]`` (n, r, width) into ``pages`` (n_pages, page_size,
+    width) at ``(pidx[i], off[i])``, one in-place update a write; a
+    ``pidx`` past the pool (a dead slot, a page the slot does not hold)
+    writes back what lies at the clamped place.  A latent pool's writes:
+    its rows are 576 wide, which the TPU lays out with the page's rows,
+    not the row's columns, along the lanes, and a row scatter
+    (``.at[pidx, off].set``) has the compiler re-lay the whole pool for
+    it and back, 2.6 ms a layer a step at 396 MB (my chip runs, PR 34);
+    an update of a slice keeps whatever layout the pool has."""
+    rows = rows.astype(pages.dtype)
+
+    def one(i, pages):
+        new = jax.lax.dynamic_index_in_dim(rows, i, 0, keepdims=True)
+        at = (jnp.minimum(pidx[i], pages.shape[0] - 1), off[i], 0)
+        old = jax.lax.dynamic_slice(pages, at, new.shape)
+        return jax.lax.dynamic_update_slice(
+            pages, jnp.where(pidx[i] < pages.shape[0], new, old), at)
+
+    return jax.lax.fori_loop(0, rows.shape[0], one, pages)
 
 
 __all__ = ["PagedKVCache", "PagePoolError"]

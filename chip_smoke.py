@@ -87,6 +87,29 @@ SIZES = dict(
                            max_len=2048, dtype="bfloat16"),
                    window=256, page_size=128, slots=4, max_context=2048,
                    max_prompt=1536, prefill_chunk=256,
+                   prompts=(100, 700, 1400), new_tokens=(16, 16, 16)),
+               # a fourth: latent attention (one cached row of 128 + 64 a
+               # token under 8 heads, YaRN) and one chip's 8 of 32
+               # sigmoid-routed, group-limited experts beside a shared
+               # one, after a leading dense layer
+               latent=dict(
+                   lm=dict(vocab_size=1024, d_model=512, n_heads=8,
+                           n_layers=3, d_ff=256, q_lora_rank=256,
+                           kv_lora_rank=128, qk_nope_dim=128,
+                           qk_rope_dim=64, v_head_dim=128,
+                           rope_scaling=dict(
+                               factor=40, beta_fast=32, beta_slow=1,
+                               original_max_position_embeddings=256,
+                               mscale=1, mscale_all_dim=1),
+                           dense_layers=1, dense_d_ff=1024, moe_experts=32,
+                           moe_top_k=4, moe_capacity_factor=None,
+                           moe_scoring="sigmoid", moe_groups=4,
+                           moe_top_groups=2, moe_routed_scale=2.5,
+                           moe_router_bias=True, moe_held=(8, 8),
+                           moe_shared_d_ff=256, max_len=2048,
+                           dtype="bfloat16"),
+                   page_size=128, slots=4, max_context=2048,
+                   max_prompt=1536, prefill_chunk=256,
                    prompts=(100, 700, 1400), new_tokens=(16, 16, 16))),
     flash_shape=(8, 8, 2048, 128),
     optim_leaf=(32000, 1024),
@@ -335,16 +358,18 @@ def stage_serve(ctx):
                + eng.kv.attention_path()[1])
     sparse = _serve_sparse(ctx, s["sparse"])
     windowed = _serve_windowed(ctx, s["windowed"])
+    latent = _serve_latent(ctx, s["latent"])
     return dict(compile_s=warm_s + sparse.pop("compile_s")
-                + windowed.pop("compile_s"),
-                run_s=run_s + sparse.pop("run_s") + windowed.pop("run_s"),
+                + windowed.pop("compile_s") + latent.pop("compile_s"),
+                run_s=run_s + sparse.pop("run_s") + windowed.pop("run_s")
+                + latent.pop("run_s"),
                 attn_route=st["attn_route"],
                 kv_pages_read_share=st["kv_pages_read_share"],
                 warmup_compiles=int(st["warmup_compiles"]),
                 max_prompt=s["max_prompt"], requests=len(streams),
                 tokens=sum(len(t) for t in streamed),
                 decode_steps=int(st["steps"]), sparse=sparse,
-                windowed=windowed)
+                windowed=windowed, latent=latent)
 
 
 def _serve_small(s, what):
@@ -416,6 +441,42 @@ def _serve_sparse(ctx, s):
                 chunk_attn_route=st["chunk_attn_route"],
                 prefill_chunks=int(st["prefill_chunks"]),
                 kv_rows_attended_share=st["kv_rows_attended_share"])
+
+
+def _serve_latent(ctx, s):
+    """A small model of latent attention and a held share of routed
+    experts: the pool holds one row a token a layer and nothing else, the
+    decode step is absorbed, and this chip's experts take their share of
+    the pairs the router chose."""
+    eng, st, rec, warm_s, run_s = _serve_small(s, "latent")
+    lm = s["lm"]
+    width = lm["kv_lora_rank"] + lm["qk_rope_dim"]
+    pages = s["slots"] * s["max_context"] // s["page_size"]
+    pool = {k: tuple(v.shape) for k, v in
+            eng._pool[eng.kv.layer_names[0]].items()}
+    _check(pool == {"latent": (pages, s["page_size"], width)},
+           f"a latent layer's pool is {pool}, not one array of rows "
+           f"{width} wide")
+    _check(st["attn_route"] == st["chunk_attn_route"] == "latent"
+           and rec.gauge_value("decode/attn_route") == 3.0
+           and rec.gauge_value("decode/chunk_attn_route") == 2.0,
+           f"the routes are {st['attn_route']} / {st['chunk_attn_route']}, "
+           "not latent: " + eng.kv.attention_path()[1])
+    _check(rec.gauge_value("kv/latent_row_bytes") == 2 * width
+           and st["kv_kinds"]["global"].get("content") == "latent",
+           "the cache does not say that it holds latent rows")
+    routed = rec.counter_value("moe/pairs_routed")
+    mine = rec.counter_value("moe/pairs")
+    _check(routed == lm["moe_top_k"] * (lm["n_layers"] - lm["dense_layers"])
+           * rec.counter_value("decode/tokens") and 0 < mine < routed,
+           f"{mine} of {routed} routed pairs were this chip's")
+    _check(rec.counter_value("mla/rows_live") > 0
+           and rec.counter_value("mla/chunk_rows_visible") > 0,
+           "the latent cache counted no rows")
+    return dict(compile_s=warm_s, run_s=run_s, attn_route=st["attn_route"],
+                chunk_attn_route=st["chunk_attn_route"], pool=pool,
+                prefill_chunks=int(st["prefill_chunks"]),
+                local_pairs_share=mine / routed)
 
 
 def _serve_windowed(ctx, s):

@@ -136,6 +136,26 @@ def test_chip_smoke_stages_tiny_on_cpu():
                                max_len=128, dtype="bfloat16"),
                        window=8, page_size=4, slots=3, max_context=96,
                        max_prompt=64, prefill_chunk=8, prompts=(3, 30, 60),
+                       new_tokens=(4, 4, 4)),
+                   latent=dict(
+                       lm=dict(vocab_size=128, d_model=64, n_heads=4,
+                               n_layers=3, d_ff=32, q_lora_rank=32,
+                               kv_lora_rank=16, qk_nope_dim=16,
+                               qk_rope_dim=8, v_head_dim=16,
+                               rope_scaling=dict(
+                                   factor=40, beta_fast=32, beta_slow=1,
+                                   original_max_position_embeddings=16,
+                                   mscale=1, mscale_all_dim=1),
+                               dense_layers=1, dense_d_ff=96,
+                               moe_experts=16, moe_top_k=4,
+                               moe_capacity_factor=None,
+                               moe_scoring="sigmoid", moe_groups=4,
+                               moe_top_groups=2, moe_routed_scale=2.5,
+                               moe_router_bias=True, moe_held=(4, 8),
+                               moe_shared_d_ff=32, max_len=128,
+                               dtype="bfloat16"),
+                       page_size=4, slots=3, max_context=96,
+                       max_prompt=64, prefill_chunk=8, prompts=(3, 30, 60),
                        new_tokens=(4, 4, 4))),
         flash_shape=(1, 2, 256, 128), optim_leaf=(300, 130),
         four_conv_batch=8, four_conv_iters=3)
@@ -156,3 +176,7 @@ def test_chip_smoke_stages_tiny_on_cpu():
     assert windowed["kv_kinds"]["window"]["pages_per_slot"] == 5
     assert windowed["kv_kinds"]["global"]["pages_per_slot"] == 24
     assert windowed["pages_recycled"] > 0
+    latent = results["serve"]["latent"]
+    assert latent["pool"] == {"latent": (72, 4, 24)}
+    assert latent["attn_route"] == latent["chunk_attn_route"] == "latent"
+    assert 0 < latent["local_pairs_share"] < 1
