@@ -7,7 +7,9 @@ canonical arrivals and sizes from the file's own `draw_seed` (seeded
 Poisson in virtual time, arrivals.py) and lets `--seed` only PERMUTE
 them and draw the prompts' token ids: every seed offers the same set of
 gaps and sizes in another order, so seeds change the order of the work
-and not its amount.  `run_open_loop` paces the schedule by the wall clock
+and not its amount.  With `"replay": true` among the arrivals the seed
+draws the token ids alone and every seed replays the draw's own
+timeline.  `run_open_loop` paces the schedule by the wall clock
 (the pacing loop of scripts/serve_bench.py `run_open_loop`, copied), one
 short-lived reader thread per request, each request timed from when it
 was DUE, and reports how late the generator itself ran.
@@ -38,10 +40,13 @@ def make_schedule(traffic, seed, seconds, vocab_size):
     p_len = _lengths(base, traffic["prompt_len"], n)
     o_len = _lengths(base, traffic["output_len"], n)
     rng = np.random.default_rng(seed)
-    if arr["shape"] == "steady":
-        # under a phased shape the order of the gaps is the shape itself
-        gaps = rng.permutation(gaps)
-    order = rng.permutation(n)
+    if arr.get("replay"):
+        order = np.arange(n)
+    else:
+        if arr["shape"] == "steady":
+            # under a phased shape the order of the gaps is the shape itself
+            gaps = rng.permutation(gaps)
+        order = rng.permutation(n)
     due = np.cumsum(gaps)
     return [(float(due[i]),
              rng.integers(0, vocab_size, int(p_len[j]), dtype=np.int32),

@@ -2,15 +2,16 @@
 how steady `tpot_ms_p95` CAN be over sets of six windows of a draw, before
 any chip time is spent.  On the CPU, in seconds:
 
-    python3 benchmarks/tools/tick_sim.py --workload W [--rate 0.58] \\
+    python3 benchmarks/tools/tick_sim.py --workload W [--rate 1.36] \\
         [--draws 4] [--sets 20]
 
 The loop is `DecodeEngine`'s as the chunked cells drive it: requests first
 come, first served; a tick runs one chunk of the oldest waiting prompt if
 there is one, then one decode step over the live slots; a reply's gap is
 the time between two of its steps.  The three costs are MEASURED numbers
-of the cell, given here, not modelled (`TICK_MS`: my chip runs, PR 32,
-PERF.md section 5).  What comes out is a count from a model: the share of
+of the cell, given here, not modelled (`TICK_MS`: a line fitted to six
+chip windows of PR 33's program at three rates, PR 36, PERF.md section
+5).  What comes out is a count from a model: the share of
 sets of six whose spread passes half the metric's bound, the median p95
 and the share of gaps that hold a chunk.  It is never a device metric,
 and it chooses nothing: `controls_window_moe.representative_draw` names
@@ -27,8 +28,14 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-# a chunk at the host; a decode tick with no slot live; a live slot more
-TICK_MS = {"smallthinker21b-l8-serve-mixed": (26.6, 48.4, 0.65)}
+# a chunk at the host; a decode tick with no slot live; a live slot more.
+# Builder's chip runs, PR 36 (chiprun_out/controls_<cell>.jsonl): six
+# windows of tools/controls_window_moe.py at 0.68, 1.0 and 1.4 requests/s,
+# two each; the chunk is the median of their mean `decode.prefill` spans
+# (26.17-27.06 ms), the tick a line fitted by least squares to each
+# window's median `decode.tick` (8.82-13.64 ms) against its mean live
+# slots (2.98-8.15; residuals within 0.63 ms)
+TICK_MS = {"smallthinker21b-l8-serve-mixed": (26.3, 7.01, 0.81)}
 CLOCK_MS = 0.4                 # what the host's clock adds to a p95
 
 

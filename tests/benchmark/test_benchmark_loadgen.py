@@ -40,6 +40,21 @@ def test_every_seed_offers_the_same_work():
     assert abs(a[-1][0] - b[-1][0]) < 1e-9 and a[-1][0] < 45.0
 
 
+def test_a_replayed_draw_is_one_timeline_whatever_the_seed():
+    """`"replay": true`: every seed offers the draw's own gaps and sizes in
+    the draw's own order, and draws the prompts' token ids alone."""
+    tr = traffic()
+    tr["arrivals"] = dict(tr["arrivals"], replay=True)
+    a = loadgen.make_schedule(tr, 1, 45.0, 50304)
+    b = loadgen.make_schedule(tr, 2 ** 31 + 99, 45.0, 50304)
+    shuffled = loadgen.make_schedule(traffic(), 1, 45.0, 50304)
+    timeline = lambda s: [(d, len(p), m) for d, p, m in s]
+    assert timeline(a) == timeline(b) != timeline(shuffled)
+    assert sorted(x[1:] for x in timeline(a)) \
+        == sorted(x[1:] for x in timeline(shuffled))
+    assert any((x[1] != y[1]).any() for x, y in zip(a, b))
+
+
 def test_open_loop_times_from_due_and_counts_failures():
     class Stream:
         def __init__(self, n):
