@@ -251,13 +251,16 @@ def _latent_attend(q, new_rows, pool, tables, lengths, *, rank, sm_scale):
     return lax.fori_loop(0, live.sum(), one, jnp.zeros((s, h, rank), q.dtype))
 
 
-# Heads a step of the chunk's attention: the float32 scores of one block
-# of heads are ``block x chunk x keys x 4`` bytes (2 x 512 x 12,288: 50
-# MB), never those of all the heads at once (128: 3.2 GB).  On a v5e at
-# 128 heads x 512 queries x 12,288 keys, up-projected (my chip runs, PR
-# 34): 1 / 2 / 4 / 8 / 16 heads a step take 7.5 / 8.0 / 8.6 / 15.7 / 16.4
-# ms a call.
+# Heads a block of the chunk's attention: the float32 scores of one step
+# of the walk are ``heads x chunk x keys x 4`` bytes (2 x 512 x 1,024: 4
+# MB), never those of all the heads at once (128 x 512 x 1,024: 268 MB),
+# nor those of the whole table (2 x 512 x 12,288: 50 MB).  On a v5e at 128
+# heads x 512 queries x 12,288 keys, up-projected, the whole table scored
+# in one step: 1 / 2 / 4 / 8 / 16 heads a step took 7.5 / 8.0 / 8.6 /
+# 15.7 / 16.4 ms a call.
 _LATENT_HEAD_BLOCK = 2
+# Keys a step of the walk, at most (whole pages: 8 of 128 rows).
+_LATENT_KEY_BLOCK = 1024
 # Which formula a chunk takes (the same attention either way): absorbed
 # scores the queries against the rows as they lie (2 x 512 x keys x 128 x
 # 1,088 FLOPs); up-projected makes a block of heads' keys and values from
@@ -271,27 +274,49 @@ def latent_chunk_formula() -> str:
     return "absorbed" if _LATENT_CHUNK_ABSORBED else "up-projected"
 
 
+def latent_chunk_walk(start, chunk: int, page_size: int, n_pages: int):
+    """``(steps, keys)`` of a chunk's latent walk over a table of
+    ``n_pages`` pages: ``keys`` rows a step (whole pages, 1,024 rows in
+    pages of 128, the table's at most) and the steps that reach the
+    chunk's last row, ``cdiv(start + chunk, keys)`` (traced where
+    ``start`` is)."""
+    keys = max(1, min(n_pages, _LATENT_KEY_BLOCK // page_size)) * page_size
+    return (start + chunk + keys - 1) // keys, keys
+
+
 @functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "absorbed"))
 def _latent_chunk_attend(q, w_uk, w_uv, pool, table, start, *, rank,
                          sm_scale, absorbed):
     """A prompt chunk's attention over its slot's latent rows: q (H, C,
     nope + rope) un-absorbed, at positions ``start + arange(C)``; W_uk
     (rank, H, nope) and W_uv (rank, H, v) the two halves of the
-    up-projection; ``table`` (P,) the pages (``-1``: none), every one of
-    which is scored whatever ``start`` is.  In blocks of
-    ``_LATENT_HEAD_BLOCK`` heads, so that neither the scores nor the
-    up-projected keys and values of all heads are ever whole.  ``absorbed``
-    picks the formula.  -> (H, C, v) in q's dtype."""
-    n_pages, _, width = pool.shape
+    up-projection; ``table`` (P,) the pages (``-1``: none).  Only the
+    rows up to the chunk's end are scored: the table's rows are walked in
+    steps of :func:`latent_chunk_walk`'s keys, as many as reach ``start +
+    C`` (a traced count: one program for every offset), under a running
+    maximum, denominator and sum, all float32.  A step's rows at or past
+    ``start + C`` (a recycled page's stale ones) are zeroed before any
+    matmul.  In blocks of ``_LATENT_HEAD_BLOCK`` heads, so that neither
+    the scores nor the up-projected keys and values of all heads are ever
+    whole; the blocks of heads are the outer loop and the walk the inner
+    one, so that a block's accumulators (``heads x C x v``) are the only
+    ones that live, and each block reads the weights once where a walk
+    outside would read all of them every step (on a v5e at 128 heads x
+    512 queries, two heads a block: 4.30 / 8.93 ms a call at 4,608 /
+    11,776 keys, against 4.52 / 9.67 with the walk outside).
+    ``absorbed`` picks the formula.  -> (H, C, v) in q's dtype."""
+    n_pages, page_size, width = pool.shape
     h, chunk, _ = q.shape
     nope = w_uk.shape[-1]
-    rows = jnp.take(pool, jnp.where(table < 0, n_pages, table), axis=0,
-                    mode="fill", fill_value=0).reshape(-1, width)
-    k_pos = jnp.arange(rows.shape[0])
-    mask = k_pos[None, :] <= (start + jnp.arange(chunk))[:, None]   # (C, L)
-    c = jnp.where((k_pos < start + chunk)[:, None], rows[:, :rank], 0)
-    k_r = rows[:, rank:]
-    scrubbed = jnp.concatenate([c, k_r], -1) if absorbed else None
+    steps, kb = latent_chunk_walk(start, chunk, page_size, table.shape[0])
+    # the table in whole steps, a page of -1 (or of the padding) read as
+    # zeros; gathered once, a few megabytes, as the pool lies
+    idx = jnp.where(table < 0, n_pages, table)
+    idx = jnp.pad(idx, (0, -idx.shape[0] % (kb // page_size)),
+                  constant_values=n_pages)
+    rows = jnp.take(pool, idx, axis=0, mode="fill",
+                    fill_value=0).reshape(-1, kb, width)
+    q_pos = start + jnp.arange(chunk)
     hb = min(_LATENT_HEAD_BLOCK, h)
     assert h % hb == 0, (h, hb)
 
@@ -301,26 +326,49 @@ def _latent_chunk_attend(q, w_uk, w_uv, pool, table, start, *, rank,
             q_b = jnp.concatenate(
                 [jnp.einsum("hcn,hrn->hcr", q_b[..., :nope], uk),
                  q_b[..., nope:]], -1)
-            sc = jnp.einsum("hcd,ld->hcl", q_b, scrubbed,
-                            preferred_element_type=jnp.float32)
-        else:
-            keys = jnp.concatenate(
-                [jnp.einsum("lr,hrn->hln", c, uk),
-                 jnp.broadcast_to(k_r, (hb,) + k_r.shape)], -1)
-            sc = jnp.einsum("hcd,hld->hcl", q_b, keys,
-                            preferred_element_type=jnp.float32)
-        sc = jnp.where(mask[None], sc * sm_scale, DEFAULT_MASK_VALUE)
-        e = jnp.exp(sc - sc.max(axis=-1, keepdims=True))
-        den = e.sum(axis=-1, keepdims=True)
+
+        def step(i, carry):
+            top, den, acc = carry
+            k_pos = i * kb + jnp.arange(kb)
+            r = jnp.where((k_pos < start + chunk)[:, None],
+                          lax.dynamic_index_in_dim(rows, i, 0, False), 0)
+            c = r[:, :rank]
+            if absorbed:
+                sc = jnp.einsum("hcd,ld->hcl", q_b, r,
+                                preferred_element_type=jnp.float32)
+            else:
+                keys = jnp.concatenate(
+                    [jnp.einsum("lr,hrn->hln", c, uk),
+                     jnp.broadcast_to(r[:, rank:], (hb, kb, width - rank))],
+                    -1)
+                sc = jnp.einsum("hcd,hld->hcl", q_b, keys,
+                                preferred_element_type=jnp.float32)
+            sc = jnp.where((k_pos[None, :] <= q_pos[:, None])[None],
+                           sc * sm_scale, DEFAULT_MASK_VALUE)
+            new_top = jnp.maximum(top, sc.max(axis=-1))
+            e = jnp.exp(sc - new_top[..., None])
+            scale = jnp.exp(top - new_top)
+            if absorbed:
+                o = jnp.einsum("hcl,lr->hcr", e.astype(c.dtype), c,
+                               preferred_element_type=jnp.float32)
+            else:
+                o = jnp.einsum("hcl,hlv->hcv", e.astype(c.dtype),
+                               jnp.einsum("lr,hrv->hlv", c, uv),
+                               preferred_element_type=jnp.float32)
+            return (new_top, scale * den + e.sum(axis=-1),
+                    scale[..., None] * acc + o)
+
+        # (every query sees key 0, so the first step sets a finite max)
+        _, den, acc = lax.fori_loop(
+            0, steps, step,
+            (jnp.full((hb, chunk), DEFAULT_MASK_VALUE, jnp.float32),
+             jnp.zeros((hb, chunk), jnp.float32),
+             jnp.zeros((hb, chunk, rank if absorbed else uv.shape[-1]),
+                       jnp.float32)))
+        o = acc / den[..., None]
         if absorbed:
-            o = jnp.einsum("hcl,lr->hcr", e.astype(c.dtype), c,
-                           preferred_element_type=jnp.float32) / den
             o = jnp.einsum("hcr,hrv->hcv", o.astype(uv.dtype), uv,
                            preferred_element_type=jnp.float32)
-        else:
-            o = jnp.einsum("hcl,hlv->hcv", e.astype(c.dtype),
-                           jnp.einsum("lr,hrv->hlv", c, uv),
-                           preferred_element_type=jnp.float32) / den
         return o.astype(q.dtype)
 
     by_block = lambda a: a.reshape((h // hb, hb) + a.shape[1:])

@@ -82,6 +82,7 @@ import numpy as np
 from .. import faults as faultplane
 from ..nn.module import Ctx
 from ..observability import Recorder
+from ..ops.paged_attention import latent_chunk_walk
 from .buckets import BucketLadder
 from .kvcache import PagedKVCache
 from .queue import (EngineClosedError, EngineIntrospection,
@@ -495,6 +496,9 @@ class DecodeEngine(EngineIntrospection):
             # ... and what a row of it is, where it is not K and V
             for kind in out["kv_kinds"].values():
                 kind["content"] = "latent"
+            # rows the chunks' attention walked (summed over layers)
+            out["chunk_rows_walked"] = rec.counter_value(
+                "mla/chunk_rows_walked")
         # a prompt's chunks (None: this engine takes prompts whole)
         out["chunk_attn_route"] = self.chunk_attention_path()[0]
         # the sparse route: rows the steps' attention read of the rows
@@ -663,6 +667,10 @@ class DecodeEngine(EngineIntrospection):
                     ctx.count("mla/chunk_rows_visible",
                               n_valid * start + n_valid * (n_valid + 1) // 2)
                     ctx.count("mla/chunk_rows_live", start + n_valid)
+                    # ... and the rows its attention walks to reach them
+                    steps, keys = latent_chunk_walk(start, chunk,
+                                                    kv.page_size, n_pages)
+                    ctx.count("mla/chunk_rows_walked", steps * keys)
                     return kv.attend_chunk(new_pool[name], table, start, q,
                                            up=up)
 

@@ -575,7 +575,8 @@ class PagedKVCache:
         if self.latent_rank:
             return "latent", (f"one row of {self.head_dim} a token: the "
                               f"{latent_chunk_formula()} formula over the "
-                              "gathered table, a block of heads a step")
+                              "gathered table up to the chunk's end, a "
+                              "block of keys and of heads a step")
         return paged_chunk_attention_path(
             jnp.int8 if self.int8 else self.dtype, self.q_heads,
             self.n_heads, self.head_dim, self.page_size, chunk,
@@ -782,7 +783,10 @@ class PagedKVCache:
         (``(1, heads, C, nope + rope)``) and ``up`` = (W_uk ``(rank,
         heads, nope)``, W_uv ``(rank, heads, v)``), and returns the heads'
         values ``(1, heads, C, v)``: ``_latent_chunk_attend``, the table
-        gathered, a block of heads a step."""
+        gathered and walked a block of keys a step up to the chunk's end
+        only (``latent_chunk_walk``), a block of heads at a time: unlike
+        the routes above, a latent chunk costs more the later it falls in
+        its prompt."""
         chunk = q.shape[2]
         if self.latent_rank:
             return _latent_chunk_attend(

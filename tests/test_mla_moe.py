@@ -166,6 +166,125 @@ def test_chunked_prefill_through_pages_and_decode_equal_the_reference(
     kv.check_invariants()
 
 
+@pytest.fixture
+def key_block():
+    """Sets `_LATENT_KEY_BLOCK` (read as the walk traces, so the jitted
+    walk's cache is cleared with it) for one test; put back after."""
+    def set_to(rows):
+        pa._LATENT_KEY_BLOCK = rows
+        pa._latent_chunk_attend.clear_cache()
+    saved = pa._LATENT_KEY_BLOCK
+    yield set_to
+    set_to(saved)
+
+
+WALK_PAGES, WALK_KEYS = 13, 16    # a table of 52 rows, 4 pages a step
+
+
+def walk_case(seed=5):
+    """Seeded q, W_uk, W_uv, a pool of 20 pages of rank 8 + rope 4, and a
+    table of 13 of its pages in a shuffled order."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(k[0], (4, CHUNK, 12))
+    w_uk = jax.random.normal(k[1], (8, 4, 8)) * 0.5
+    w_uv = jax.random.normal(k[2], (8, 4, 8)) * 0.5
+    pool = np.array(jax.random.normal(k[3], (20, PAGE, 12)))
+    table = np.array(jax.random.permutation(k[4], 20)[:WALK_PAGES],
+                     np.int32)
+    return q, w_uk, w_uv, pool, table
+
+
+def full_table_attention(q, w_uk, w_uv, pool, table, start, sm_scale):
+    """The plain float32 chunk attention: every row of the table gathered
+    (a page of -1 reads zeros), each head's keys and values made from the
+    rows, masked by position, softmax."""
+    rows = np.where((table >= 0)[:, None, None],
+                    pool[np.maximum(table, 0)], 0).reshape(-1, 12)
+    c, k_r = rows[:, :8], rows[:, 8:]
+    keys = jnp.concatenate([jnp.einsum("lr,rhn->hln", c, w_uk),
+                            jnp.broadcast_to(k_r, (4,) + k_r.shape)], -1)
+    sc = jnp.einsum("hcd,hld->hcl", q, keys) * sm_scale
+    mask = np.arange(len(rows))[None, :] <= start + np.arange(CHUNK)[:, None]
+    p = jax.nn.softmax(jnp.where(mask[None], sc, -jnp.inf), axis=-1)
+    return jnp.einsum("hcl,hlv->hcv", p, jnp.einsum("lr,rhv->hlv", c, w_uv))
+
+
+@pytest.mark.parametrize("tail", ["stale_rows", "no_pages"])
+@pytest.mark.parametrize("start", [0, WALK_KEYS - PAGE, WALK_KEYS, 24,
+                                   WALK_PAGES * PAGE - CHUNK],
+                         ids=["first", "a_page_under_a_step", "one_step",
+                              "mid_table", "last_chunk"])
+@pytest.mark.parametrize("absorbed", [False, True],
+                         ids=["up_projected", "absorbed"])
+def test_the_chunk_walk_equals_the_full_table(key_block, absorbed, start,
+                                              tail):
+    """The walk over the keys up to the chunk's end (4 pages a step, the
+    last step past the table's 13 pages read as zeros) is the whole
+    table's attention; rows past the chunk's end hold NaN and 1e30
+    (`stale_rows`) or lie in no page (`no_pages`), and change nothing."""
+    key_block(WALK_KEYS)
+    q, w_uk, w_uv, pool, table = walk_case()
+    with jax.default_matmul_precision("highest"):
+        want = full_table_attention(q, w_uk, w_uv, pool, table, start, 0.3)
+        end = start + CHUNK
+        if tail == "no_pages":
+            table[-(-end // PAGE):] = -1
+        else:
+            rows = pool[table].reshape(-1, 12)
+            rows[end::2], rows[end + 1::2] = np.nan, 1e30
+            pool[table] = rows.reshape(-1, PAGE, 12)
+        got = pa._latent_chunk_attend(
+            q, w_uk, w_uv, jnp.asarray(pool), jnp.asarray(table),
+            jnp.int32(start), rank=8, sm_scale=0.3, absorbed=absorbed)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < F32_TOL
+
+
+def test_the_walk_is_one_program_for_every_offset(key_block):
+    """The steps are a traced count: three offsets, one trace."""
+    key_block(WALK_KEYS)
+    q, w_uk, w_uv, pool, table = walk_case()
+    assert pa.latent_chunk_walk(0, CHUNK, PAGE, WALK_PAGES) == (1, 16)
+    assert pa.latent_chunk_walk(44, CHUNK, PAGE, WALK_PAGES) == (4, 16)
+    # a step is at most the table
+    assert pa.latent_chunk_walk(0, CHUNK, PAGE, 2) == (1, 8)
+    for start in (0, 20, 44):
+        pa._latent_chunk_attend(q, w_uk, w_uv, jnp.asarray(pool),
+                                jnp.asarray(table), jnp.int32(start), rank=8,
+                                sm_scale=0.3, absorbed=False)
+    assert pa._latent_chunk_attend._cache_size() == 1
+
+
+def test_the_engine_counts_the_rows_each_chunk_walks(f32, key_block):
+    """`mla/chunk_rows_walked` is, a chunk, the layers x its steps x the
+    keys a step: 3 x cdiv(start + 8, 12) x 12 over a table of 8 pages of
+    4 rows; the chunks at offsets 0 to 24 compile nothing new."""
+    key_block(12)
+    model, params, _ = f32
+    with jax.default_matmul_precision("highest"):
+        model.set_params(params, {})
+        reg = ModelRegistry()
+        reg.register("lm", model)
+        eng = DecodeEngine(reg, "lm", slots=2, page_size=PAGE,
+                           max_context=48, max_prompt=32,
+                           prefill_chunk=CHUNK, max_new_tokens=2)
+        eng.warmup()
+        rec = eng.recorder
+        for n in (5, 21, 32):
+            before = rec.counter_value("mla/chunk_rows_walked")
+            list(eng.stream("lm", SEQ[:n]).tokens())
+            want = sum(3 * -(-(start + CHUNK) // 12) * 12
+                       for start in range(0, n, CHUNK))
+            assert rec.counter_value("mla/chunk_rows_walked") - before \
+                == want, n
+        st = eng.stats()
+        eng.shutdown()
+    assert st["chunk_rows_walked"] == rec.counter_value(
+        "mla/chunk_rows_walked") == 3 * 12 * (1 + (1 + 2 + 2)
+                                              + (1 + 2 + 2 + 3))
+    assert st["recompiles"] == 0
+
+
 def test_absorbed_attention_equals_the_up_projected(f32):
     """`absorb` + `_latent_attend` + `up_values` is `attend_rows`."""
     model, params, _ = f32
