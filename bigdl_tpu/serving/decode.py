@@ -691,9 +691,8 @@ class DecodeEngine(EngineIntrospection):
                 bad = ~jnp.isfinite(last).all()
                 return (tok, bad, new_pool) + self._counts(kind, ctx)
 
-            # (a name without `fn`: a reader of the device trace tells the
-            # decode step as the most frequent module with `fn` in it, and
-            # a window that opens on a long prompt runs more chunks)
+            # (a name without `fn`, as the ladder's: a reader of the device
+            # trace tells the decode step as the module with `fn` in it)
             fn = prefill_chunk
             args = (self._aval_params(), self._pool_avals,
                     jax.ShapeDtypeStruct((1, chunk), jnp.int32),
@@ -709,7 +708,7 @@ class DecodeEngine(EngineIntrospection):
             cache_dtype = kv.dtype if not kv.int8 \
                 else jnp.dtype(model.cfg.dtype)
 
-            def fn(params, pool, tokens, true_len, table, temp, step):
+            def prefill(params, pool, tokens, true_len, table, temp, step):
                 cache = model.init_cache(1, dtype=cache_dtype,
                                          cache_len=bucket)
                 logits, cache = model.apply_with_cache(
@@ -725,6 +724,10 @@ class DecodeEngine(EngineIntrospection):
                 bad = ~jnp.isfinite(last).all()
                 return tok, bad, new_pool
 
+            # each bucket's program under its own name, `jit_prefill_<bucket>`
+            # in a device trace; the decode step alone is `fn`
+            prefill.__name__ = f"prefill_{bucket}"
+            fn = prefill
             args = (self._aval_params(), self._pool_avals,
                     jax.ShapeDtypeStruct((1, bucket), jnp.int32),
                     jax.ShapeDtypeStruct((), jnp.int32),
@@ -990,15 +993,10 @@ class DecodeEngine(EngineIntrospection):
         prog = self._program("chunk")
         with rec.span("decode.prefill", trace_id=trace_id, slot=slot,
                       offset=start):
-            tok, bad, self._pool, *counts = prog(
-                self._params_for_step(entry), self._pool,
+            token, bad = self._prefill_call(prog, entry, lambda: (
                 jnp.asarray(toks), jnp.int32(start), jnp.int32(n),
                 self.kv.pack([jnp.asarray(t) for t in table]),
-                jnp.float32(req.temperature), jnp.int32(self._steps))
-            token = int(tok)
-            # what the layers counted, under a prefill's own names: a
-            # chunk's pairs are not a decode step's
-            self._count_in("chunk", counts, prefix="prefill_")
+                jnp.float32(req.temperature), jnp.int32(self._steps)))
         rec.inc("decode/prefill_chunks")
         if bool(bad):
             self._live.pop(slot, None)
@@ -1053,16 +1051,37 @@ class DecodeEngine(EngineIntrospection):
         table[:m] = self.kv.tables[slot, :m]
         entry = self.registry.get(self.model_name)
         with rec.span("decode.prefill", trace_id=trace_id, bucket=bucket):
-            tok, bad, self._pool = prog(
-                self._params_for_step(entry), self._pool,
+            token, bad = self._prefill_call(prog, entry, lambda: (
                 jnp.asarray(toks), jnp.int32(prompt.size),
                 jnp.asarray(table), jnp.float32(req.temperature),
-                jnp.int32(self._steps))
-            token = int(tok)
+                jnp.int32(self._steps)))
         if bool(bad):
             self._prefill_poisoned(slot, req, entry, bucket)
             return
         self._admitted(slot, req, token, bucket)
+
+    def _prefill_call(self, prog, entry, upload):
+        """One prefill program (a chunk or a ladder bucket) under three
+        leaves that cover it in order, as a tick's leaves cover the tick:
+        `prefill.stage` (the weights and ``upload()``'s device inputs),
+        `prefill.dispatch` (the call, until it returns its futures) and
+        `prefill.sync` (the token and what the layers counted, back on
+        the host).  Returns ``(token, bad)``, ``bad`` still on the device:
+        the caller reads it after its `decode.prefill` span has closed,
+        which ends on the token."""
+        rec = self.recorder
+        with rec.span("prefill.stage"):
+            params = self._params_for_step(entry)
+            inputs = upload()
+        with rec.span("prefill.dispatch"):
+            tok, bad, self._pool, *counts = prog(params, self._pool, *inputs)
+        with rec.span("prefill.sync"):
+            token = int(tok)
+            if counts:
+                # under a prefill's own names: a chunk's pairs are not a
+                # decode step's
+                self._count_in("chunk", counts, prefix="prefill_")
+        return token, bad
 
     def _admitted(self, slot: int, req: _DecodeRequest, token: int,
                   bucket: int):

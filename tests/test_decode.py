@@ -501,6 +501,7 @@ def test_replica_predict_never_splits_a_prompt(lm):
 # --------------------------------------------------------------------- #
 LEAVES = ["decode.admit", "decode.schedule", "decode.stage",
           "decode.dispatch", "decode.sync", "decode.emit"]
+PREFILL_LEAVES = ["prefill.stage", "prefill.dispatch", "prefill.sync"]
 
 
 @pytest.fixture
@@ -590,8 +591,10 @@ def test_request_queue_and_prefill_share_its_trace_id(lm, span_store):
         eng.shutdown()
     (tr,) = eng.trace_ring.traces()
     mine = span_store.by_trace(tr.trace_id)
-    assert sorted(s.name for s in mine) == ["decode.prefill", "decode.queue"]
-    queue, prefill = sorted(mine, key=lambda s: s.name, reverse=True)
+    assert sorted(s.name for s in mine) == ["decode.prefill", "decode.queue",
+                                            *sorted(PREFILL_LEAVES)]
+    (queue,) = [s for s in mine if s.name == "decode.queue"]
+    (prefill,) = [s for s in mine if s.name == "decode.prefill"]
     assert prefill.args == {"bucket": 8}
     by_name = {name: (t0, t1) for name, t0, t1, _ in tr.spans
                if name in ("queue", "prefill")}
@@ -638,9 +641,10 @@ def test_tick_path_mints_no_uuid_and_a_fixed_count_of_spans(
             eng.shutdown()
         # all three are admitted by the first tick and finish together:
         # every tick steps, and leaves its parent and six leaves; every
-        # request leaves its queue and prefill spans
+        # request leaves its queue and prefill spans, and the prefill's
+        # three leaves
         assert ticks == 4
-        assert len(span_store) == 7 * ticks + 2 * len(streams)
+        assert len(span_store) == 7 * ticks + 5 * len(streams)
         counts.append(len(span_store))
     assert counts[0] == counts[1]
 
@@ -656,7 +660,78 @@ def test_a_tick_that_runs_no_step_leaves_no_tick_span(lm, span_store):
     finally:
         eng.shutdown()
     names = sorted(s.name for s in span_store.spans())
-    assert names == ["decode.admit", "decode.prefill", "decode.queue"]
+    assert names == ["decode.admit", "decode.prefill", "decode.queue",
+                     *sorted(PREFILL_LEAVES)]
+
+
+@pytest.mark.parametrize("route", ["ladder", "chunk"])
+def test_every_prefill_leaves_three_ordered_leaves_that_cover_it(
+        lm, span_store, route):
+    """Inside each `decode.prefill`, a ladder bucket's or a chunk's: the
+    uploads, the call and the wait, in order, with the span's own
+    arguments and trace id; the span still ends on the token's sync."""
+    chunked = route == "chunk"
+    eng = hand_driven(lm, **({"max_prompt": 24, "prefill_chunk": 8}
+                             if chunked else {}))
+    assert eng.chunked == chunked
+    span_store.clear()          # the warm-up's spans
+    lengths = (5, 19) if chunked else (5, 13)
+    try:
+        streams = [eng.stream("lm", np.arange(1, n + 1, dtype=np.int32),
+                              max_new_tokens=2) for n in lengths]
+        drive(eng)
+        for n, st in zip(lengths, streams):
+            assert len(st.result(0)) == n + 2
+    finally:
+        eng.shutdown()
+    spans = span_store.spans()
+    kids = {}
+    for s in spans:
+        kids.setdefault(s.context.parent_span_id, []).append(s)
+    prefills = [s for s in spans if s.name == "decode.prefill"]
+    # a chunk a program: 1 + 3 of 8 tokens; a bucket a prompt
+    assert len(prefills) == (4 if chunked else 2)
+    coverage = []
+    for p in prefills:
+        leaves = sorted(kids[p.context.span_id], key=lambda s: s.t0)
+        assert [k.name for k in leaves] == PREFILL_LEAVES
+        assert {k.trace_id for k in leaves} == {p.trace_id}
+        assert set(p.args) == ({"slot", "offset"} if chunked else {"bucket"})
+        assert p.t0 <= leaves[0].t0 and leaves[-1].t1 <= p.t1
+        for a, b in zip(leaves, leaves[1:]):
+            assert a.t0 <= a.t1 <= b.t0          # disjoint, in order
+        coverage.append(sum(k.duration() for k in leaves) / p.duration())
+    assert sorted(coverage)[len(coverage) // 2] >= 0.95
+    if chunked:
+        assert sorted(p.args["offset"] for p in prefills) == [0, 0, 8, 16]
+    # the leaves are the prefill's: the runner's reading of `decode.prefill`
+    # is still the sum of its spans, and no tick gained a leaf
+    assert eng.recorder.span_value("decode.prefill") == \
+        pytest.approx(sum(p.duration() for p in prefills))
+    assert all([k.name for k in kids] == LEAVES
+               for _, kids in tick_rows(span_store))
+
+
+def test_each_program_is_named_for_what_it_runs(lm):
+    """A device trace names a module by its program: `jit_fn` is the
+    decode step alone, a ladder bucket's is `jit_prefill_<bucket>` and
+    the chunk program's `jit_prefill_chunk`."""
+    import re
+
+    def names(eng):
+        try:
+            return {key: re.match(r"HloModule (\w+)", prog.as_text())[1]
+                    for key, prog in eng._programs.items()}
+        finally:
+            eng.shutdown()
+
+    ladder = names(small_engine(lm))
+    buckets = sorted(b for kind, b in ladder if kind == "prefill")
+    assert buckets and ladder == {("decode", None): "jit_fn", **{
+        ("prefill", b): f"jit_prefill_{b}" for b in buckets}}
+    chunked = names(small_engine(lm, max_prompt=24, prefill_chunk=8))
+    assert chunked == {("decode", None): "jit_fn",
+                       ("chunk", None): "jit_prefill_chunk"}
 
 
 def test_disabled_recorder_engine_leaves_no_spans(lm, span_store):
