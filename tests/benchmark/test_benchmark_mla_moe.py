@@ -184,6 +184,29 @@ def test_the_rate_is_the_issues_share_and_the_draw_is_the_rules():
         5700, rel=0.05)
 
 
+def test_the_draw_is_replayed_and_the_slice_holds_chunks():
+    """Every seed offers the draw's own timeline (the seed draws the token
+    ids alone), so which chunk meets how many live slots repeats; and
+    requests of at least 8 chunks are due in and just before the traced
+    seconds, which start half-way through the window, so that every
+    traced run reads the chunk's device metrics."""
+    from benchmarks import loadgen
+    cell = harness.Cell(CELL)
+    assert cell.traffic["arrivals"]["replay"] is True
+    seconds = float(cell.spec["run_seconds"])
+    chunk = cell.traffic["engine"]["prefill_chunk"]
+    timelines = [[(due, len(p), n) for due, p, n in loadgen.make_schedule(
+        cell.traffic, seed, seconds, cell.config["vocab_size"])]
+        for seed in (1, 2 ** 31 + 99)]
+    assert timelines[0] == timelines[1]
+    assert len(timelines[0]) == 14
+    assert sum(p for _, p, _ in timelines[0]) == 76969
+    start = seconds / 2.0
+    due_in_slice = [p for due, p, _ in timelines[0]
+                    if start - 0.5 <= due <= start + harness.TRACE_LENGTH_S]
+    assert sum(-(-p // chunk) for p in due_in_slice) >= 8
+
+
 def test_runner_facts_counters_and_the_joined_readers(served):
     cell, r, f = served
     assert f["attn_route"] == f["chunk_attn_route"] == "latent"
